@@ -183,11 +183,15 @@ def main():
         os.environ.setdefault("SERVE_PAGE", "16")
         os.environ.setdefault("SERVE_CHUNK", "64")
         os.environ.setdefault("SERVE_RATE", "5000")
-        if "tpu" not in os.environ.get("JAX_PLATFORMS", ""):
-            jax.config.update("jax_platforms", "cpu")
+    # --smoke runs on whatever platform JAX resolves: a CPU rehearsal is
+    # asked for from outside (JAX_PLATFORMS=cpu), never chosen here
     import jax.numpy as jnp
 
-    import thunder_tpu as tt  # noqa: F401  (registers executors)
+    import thunder_tpu as tt
+
+    print(f"device: {jax.devices()[0].platform} {jax.devices()[0].device_kind} "
+          f"x{len(jax.devices())}; compile cache: "
+          f"{tt.enable_compilation_cache()}", file=sys.stderr)
     from bench import METRICS_SCHEMA
     from thunder_tpu import observe
     from thunder_tpu.data import LengthBucketer
@@ -900,3 +904,7 @@ def main():
 
 if __name__ == "__main__":
     main()
+    # what was timed is the program the planner chose, not a degraded one
+    from thunder_tpu.runtime import quarantine
+
+    quarantine.assert_clean()

@@ -375,7 +375,8 @@ def test_quarantined_megakernel_recompiles_to_per_op_fallback():
     ref = np.asarray(tt.jit(_chain, block_fusion=False)(*args))
 
     jf = tt.jit(_chain, executors=["pallas", "xla"], block_fusion=True)
-    with faults.active(FaultPlan([FaultSpec("kernel:pallas.mlp_subblock")])):
+    with quarantine.containment(), \
+            faults.active(FaultPlan([FaultSpec("kernel:pallas.mlp_subblock")])):
         out = jf(*args)  # kernel dies at trace -> quarantine -> recompile
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5, rtol=1e-5)
     assert quarantine.is_quarantined("pallas.mlp_subblock")
@@ -440,7 +441,7 @@ def test_slab_persistent_update_matches_fused_path():
     """End-to-end traced updates: slab-persistent vs pack-per-step fused
     AdamW track each other at final-bit ULPs over multiple steps (strict
     bit-identity across two different XLA programs is ill-defined — FMA
-    contraction differs per program; see PERF_R6 — the kernel-level test
+    contraction differs per program; see KERNELS.md — the kernel-level test
     above pins the bit-exact contract), the composite is claimed, and the
     bucket verdict carries the zeroed pack-bytes term."""
     AdamW, params, grads = _slab_fixture()

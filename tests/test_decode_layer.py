@@ -518,7 +518,8 @@ def test_quarantined_decode_layer_falls_back_to_subblocks(gqa_model):
     try:
         eng = _engine(params, cfg, n_layers=2)
         req = eng.submit(p, 6)
-        with faults.active(FaultPlan([FaultSpec("kernel:pallas.decode_layer")])):
+        with quarantine.containment(), \
+                faults.active(FaultPlan([FaultSpec("kernel:pallas.decode_layer")])):
             eng.drain()
         snap = observe.snapshot()
     finally:
@@ -547,7 +548,8 @@ def test_quarantining_every_megakernel_reaches_per_op(gqa_model):
     ref = _refs(params, cfg, [p], 5, 2)[0]
     eng = _engine(params, cfg, n_layers=2)
     req = eng.submit(p, 5)
-    with faults.active(FaultPlan([FaultSpec("kernel:pallas.decode_layer"),
+    with quarantine.containment(), \
+            faults.active(FaultPlan([FaultSpec("kernel:pallas.decode_layer"),
                                   FaultSpec("kernel:pallas.attn_subblock"),
                                   FaultSpec("kernel:pallas.mlp_subblock")])):
         eng.drain()

@@ -155,14 +155,8 @@ def test_collective_prims_lower_to_lax(eight_devices):
         r = rs(g, "x", 0, N)
         return g, s, r
 
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        f = sm(body, mesh=mesh, in_specs=(P("x"),), out_specs=(P(), P("x"), P("x")), check_vma=False)
-    except TypeError:
-        f = sm(body, mesh=mesh, in_specs=(P("x"),), out_specs=(P(), P("x"), P("x")), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P("x"),),
+                      out_specs=(P(), P("x"), P("x")), check_vma=False)
     g, s, r = f(x)
     np.testing.assert_allclose(np.asarray(g), x)  # gather reassembles
     np.testing.assert_allclose(np.asarray(s), np.broadcast_to(x.sum(0, keepdims=True), (N, 4)))
@@ -577,28 +571,16 @@ def test_broadcast_collective_delivers_src_value(eight_devices):
     from thunder_tpu.distributed.prims import DistPrimIDs
     from thunder_tpu.executors.eagerjax import _impls
 
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-
+    sm = jax.shard_map
     bimpl = _impls[DistPrimIDs.BROADCAST]
     mesh = Mesh(np.array(jax.devices()[:8]), ("r",))
-    try:
-        f = jax.jit(sm(lambda x: bimpl(x[0], "r", 3)[None], mesh=mesh,
-                       in_specs=P("r"), out_specs=P("r"), check_vma=False))
-    except TypeError:
-        f = jax.jit(sm(lambda x: bimpl(x[0], "r", 3)[None], mesh=mesh,
-                       in_specs=P("r"), out_specs=P("r"), check_rep=False))
+    f = jax.jit(sm(lambda x: bimpl(x[0], "r", 3)[None], mesh=mesh,
+                   in_specs=P("r"), out_specs=P("r"), check_vma=False))
     out = f(jnp.arange(8.0))
     np.testing.assert_allclose(np.asarray(out), np.full(8, 3.0))
     # a different source index
-    try:
-        f5 = jax.jit(sm(lambda x: bimpl(x[0], "r", 5)[None], mesh=mesh,
-                        in_specs=P("r"), out_specs=P("r"), check_vma=False))
-    except TypeError:
-        f5 = jax.jit(sm(lambda x: bimpl(x[0], "r", 5)[None], mesh=mesh,
-                        in_specs=P("r"), out_specs=P("r"), check_rep=False))
+    f5 = jax.jit(sm(lambda x: bimpl(x[0], "r", 5)[None], mesh=mesh,
+                    in_specs=P("r"), out_specs=P("r"), check_vma=False))
     np.testing.assert_allclose(np.asarray(f5(jnp.arange(8.0))), np.full(8, 5.0))
 
 

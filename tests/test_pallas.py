@@ -492,6 +492,30 @@ def test_fused_adamw_parity_no_weight_decay():
     _assert_update_parity(AdamW(lr=1e-2, weight_decay=0.0), params, grads)
 
 
+def test_fused_adamw_updates_aligned_matrices_in_place():
+    """Tile-aligned matrices take the in-place form (their own layout, p/m/v
+    aliased, one launch each) and only the unaligned remainder is packed —
+    same kernel body, so the 4-ULP parity with the unfused chains holds for
+    both, in one bucket, with bf16 first moments."""
+    import jax.numpy as jnp
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import pallasex
+    from thunder_tpu.optim import AdamW
+
+    rng = np.random.RandomState(34)
+    mk = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32))
+    shapes = {"w_big": (32, 256), "w_wide": (16, 384), "stack": (2, 16, 128),
+              "norm": (256,), "odd": (17, 9)}
+    assert {k: pallasex._adamw_inplace_view(s) is not None
+            for k, s in shapes.items()} == {
+        "w_big": True, "w_wide": True, "stack": True, "norm": False,
+        "odd": False}
+    params = {k: mk(*s) for k, s in shapes.items()}
+    grads = {k: mk(*s) * 0.1 for k, s in shapes.items()}
+    _assert_update_parity(AdamW(lr=1e-2, state_dtype=dtypes.bfloat16),
+                          params, grads)
+
+
 def test_fused_adamw_parity_mixed_dtype_tree():
     """Mixed f32/bf16 parameter tree exercises the dtype bucketing: two
     fused calls (one slab set per dtype), still bit-identical."""

@@ -256,6 +256,31 @@ def _rms_inputs():
 
 
 @pytest.mark.chaos
+def test_kernel_fault_is_loud_unless_containment_was_asked_for(interpret):
+    """Strict by default: a claimed kernel that dies while being traced
+    raises KernelExecutionError (with the cause chained) and quarantines
+    NOTHING — only ``quarantine.containment()`` (the supervisors' opt-in)
+    turns it into the XLA fallback the tests below exercise."""
+    x, w = _rms_inputs()
+    jf = _rms_jit()
+    with faults.active(FaultPlan([FaultSpec("kernel:pallas.rms_norm")])):
+        with pytest.raises(KernelExecutionError) as exc:
+            jf(x, w)
+    assert exc.value.claim_id == "pallas.rms_norm"
+    assert isinstance(exc.value.__cause__, faults.InjectedFault)
+    assert not quarantine.is_quarantined("pallas.rms_norm")
+    assert len(quarantine.get_quarantine()) == 0
+    quarantine.assert_clean()
+    # and the same call inside containment() degrades instead
+    with quarantine.containment(), \
+            faults.active(FaultPlan([FaultSpec("kernel:pallas.rms_norm")])):
+        jf(x, w)
+    assert quarantine.is_quarantined("pallas.rms_norm")
+    with pytest.raises(RuntimeError, match="kernel fallback"):
+        quarantine.assert_clean()
+
+
+@pytest.mark.chaos
 def test_compile_time_kernel_fault_degrades_to_xla(interpret):
     x, w = _rms_inputs()
     observe.enable(clear=True)
@@ -266,7 +291,8 @@ def test_compile_time_kernel_fault_degrades_to_xla(interpret):
     assert "pallas_rms_norm" in str(tt.last_execution_trace(jclean))
 
     jf = _rms_jit()
-    with faults.active(FaultPlan([FaultSpec("kernel:pallas.rms_norm")])):
+    with quarantine.containment(), \
+            faults.active(FaultPlan([FaultSpec("kernel:pallas.rms_norm")])):
         out = jf(x, w)  # kernel dies while traced -> quarantine -> recompile
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-6)
     # the claim is quarantined and the recompiled trace has no pallas kernel
@@ -289,7 +315,7 @@ def test_runtime_kernel_fault_degrades_mid_serving(interpret):
     ref = np.asarray(_rms_jit()(x, w))
     jf = _rms_jit(whole_program_jit=False)
     plan = FaultPlan([FaultSpec("kernel:pallas.rms_norm", every_n=2)])
-    with faults.active(plan):
+    with quarantine.containment(), faults.active(plan):
         out1 = jf(x, w)  # healthy call through the pallas claim
         np.testing.assert_allclose(np.asarray(out1), ref, atol=1e-6)
         out2 = jf(x, w)  # the kernel dies at runtime -> degrade in-place
@@ -304,7 +330,8 @@ def test_quarantine_persists_across_process_restart(interpret, tmp_path):
     ref = np.asarray(_rms_jit()(x, w))
     quarantine.configure(str(tmp_path))
     jf = _rms_jit()
-    with faults.active(FaultPlan([FaultSpec("kernel:pallas.rms_norm")])):
+    with quarantine.containment(), \
+            faults.active(FaultPlan([FaultSpec("kernel:pallas.rms_norm")])):
         jf(x, w)
     qfile = quarantine.get_quarantine().path
     assert qfile and os.path.exists(qfile)
@@ -368,7 +395,8 @@ def test_runtime_metrics_reach_the_exporters(interpret):
     observe.enable(clear=True)
     x, w = _rms_inputs()
     jf = _rms_jit()
-    with faults.active(FaultPlan([FaultSpec("kernel:pallas.rms_norm")])):
+    with quarantine.containment(), \
+            faults.active(FaultPlan([FaultSpec("kernel:pallas.rms_norm")])):
         jf(x, w)
     snap = observe.snapshot()
     assert snap["counters"]["runtime.faults_injected"] >= 1

@@ -233,7 +233,8 @@ def test_paged_decode_quarantine_falls_back_per_op(monkeypatch):
     q, kp, vp, bt, ln = _paged_inputs(np.float32, seed=3)
     ref = np.asarray(tt.jit(_paged_fn, executors=["xla"])(q, kp, vp, bt, ln))
     jf = tt.jit(_paged_fn)
-    with faults.active(FaultPlan(
+    with quarantine.containment(), \
+            faults.active(FaultPlan(
             [FaultSpec("kernel:pallas.paged_decode_attention")])):
         out = jf(q, kp, vp, bt, ln)             # dies -> quarantine -> XLA
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-6, rtol=1e-6)
@@ -530,7 +531,8 @@ class TestServingEngine:
         ref = self._references(params, cfg, [p], 6)[0]
         eng = _tiny_engine(params, cfg)
         req = eng.submit(p, 6)
-        with faults.active(FaultPlan(
+        with quarantine.containment(), \
+                faults.active(FaultPlan(
                 [FaultSpec("kernel:pallas.decode_layer")])):
             eng.drain()
         assert req.done

@@ -413,6 +413,50 @@ def test_mesh_caps_megakernel_one_rung(monkeypatch):
         "tp_degree"] == 2
 
 
+@pytest.mark.parametrize("block_fusion", [None, False],
+                         ids=["subblocks", "paged-attention"])
+def test_pallas_claims_run_under_their_partitioning_plans(monkeypatch,
+                                                          gqa_model,
+                                                          block_fusion):
+    """A REAL tp=2 mesh with Pallas claims on (interpret mode): Mosaic
+    kernels cannot be auto-partitioned, so inside the GSPMD program the
+    planned impls run in a shard_map under the Megatron layout — the
+    attention and MLP sub-blocks by default, the standalone paged-attention
+    kernel with the planner off — token-identical to ``generate`` and
+    still 2 all-reduces per layer with the pool never gathered."""
+    monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+    cfg, params = gqa_model
+    rng = np.random.RandomState(21)
+    prompts = [rng.randint(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (5, 11)]
+    refs = _refs(params, cfg, prompts, 4, 1)
+    eng = _engine(params, cfg, n_layers=1, mesh=2, block_fusion=block_fusion)
+    reqs = [eng.submit(p, 4) for p in prompts]
+    eng.drain()
+    for r, ref in zip(reqs, refs):
+        np.testing.assert_array_equal(r.output(), ref)
+    names = set()
+
+    def walk(bsyms):
+        for b in bsyms:
+            names.add(b.sym.codegen_name())
+            walk(b.subsymbols)
+
+    walk(tt.last_execution_trace(eng.runner.decode_jit).bound_symbols)
+    if block_fusion is None:
+        assert {"pallas_attn_subblock", "pallas_mlp_subblock"} <= names
+    else:
+        assert "pallas_paged_decode_attention" in names
+        # plan-less kernels do not claim inside a meshed program
+        assert "pallas_linear_act" not in names
+    c = tt.hlo_census(eng.runner.decode_jit)
+    kinds = {k: v["count"] for k, v in c["collectives"]["per_kind"].items()
+             if v["count"]}
+    assert kinds == {"all-reduce": 2}, kinds
+    assert _spec_axes(_pool_sharding(eng)) == ("tp",)
+    assert len(quarantine.get_quarantine()) == 0
+
+
 # ---------------------------------------------------------------------------
 # shard_params geometry checks
 # ---------------------------------------------------------------------------

@@ -504,23 +504,41 @@ def test_last_hlo_distributed_shows_collectives(eight_devices):
         tt.last_jaxpr(js)  # per-shard jaxpr is not well-formed standalone
 
 
-def test_compilation_cache_persists(tmp_path):
+def test_compilation_cache_persists(tmp_path, monkeypatch):
     """tt.enable_compilation_cache writes XLA executables to disk (the
     ENABLE_NVFUSER_SERIALIZATION analog; kills the 20-40s TPU first-compile
-    on warm starts)."""
+    on warm starts) — and returns the directory in use."""
     import os
+    import jax
     import thunder_tpu as tt
     from thunder_tpu import ops
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
     cache = tmp_path / "xla-cache"
-    tt.enable_compilation_cache(str(cache), min_compile_secs=0.0)
+    assert tt.enable_compilation_cache(str(cache), min_compile_secs=0.0) == str(cache)
     try:
         jf = tt.jit(lambda a: tt.ops.sum(ops.matmul(a, a)))
         jf(np.random.rand(256, 256).astype(np.float32))
         assert len(os.listdir(cache)) >= 1
+        # nothing but executables rides there: no quarantine set, no overlay
+        assert not [f for f in os.listdir(cache) if f.endswith(".json")]
     finally:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", None)
+        # back to the suite's own directory for every later test
+        tt.enable_compilation_cache(before)
+
+
+def test_compilation_cache_is_placed_from_outside(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the helper returns it and configures
+    NOTHING — JAX's own handling owns the cache."""
+    import jax
+    import thunder_tpu as tt
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+    assert tt.enable_compilation_cache(str(tmp_path / "ignored")) == \
+        str(tmp_path / "outside")
+    assert jax.config.jax_compilation_cache_dir == before
 
 
 def test_examine_torch_lists_all_unmapped_ops():

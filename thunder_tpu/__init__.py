@@ -66,51 +66,45 @@ _CACHE_OPTIONS = ("constant values", "symbolic values", "no caching")
 _rng_state: dict[str, Any] = {"key": None}
 
 
-def enable_compilation_cache(directory: str, *, min_compile_secs: float = 1.0) -> None:
-    """Persist XLA executables across processes (the reference's analog is
-    nvFuser's ``ENABLE_NVFUSER_SERIALIZATION``; on TPU first-compiles run
-    20-40s, so a warm on-disk cache removes them entirely). Honored
-    automatically when ``THUNDER_TPU_COMPILATION_CACHE`` is set in the
-    environment (read at import)."""
+# the one in-checkout default for JAX's persistent compilation cache
+# (gitignored). The path is part of the cache key, so it never moves.
+_DEFAULT_COMPILATION_CACHE = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))), ".jax_cache")
+
+
+def enable_compilation_cache(directory: str | None = None, *,
+                             min_compile_secs: float = 1.0) -> str:
+    """Place JAX's persistent compilation cache and return the directory in
+    use — the ONE helper every entry point (chip_smoke.py, the bench
+    scripts, tests/conftest.py, ``ElasticTrainer``) shares.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set in the environment: JAX's own handling
+    owns the cache — nothing is configured here, whatever ``directory``
+    says (the cache is placed from OUTSIDE the program). Not set:
+    ``directory``, or one fixed ``.jax_cache`` at the checkout root.
+
+    Only XLA executables live there. The kernel-quarantine set and the
+    cost-model calibration overlay change WHAT is compiled, so they follow
+    their own settings (``THUNDER_TPU_QUARANTINE_DIR`` /
+    ``THUNDER_TPU_CALIBRATION_DIR`` or an explicit ``configure()``) and
+    never ride in a cache directory a driver reuses across commits."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-    jax.config.update("jax_compilation_cache_dir", str(directory))
-    # jax initializes its persistent cache object once per process and then
-    # ignores jax_compilation_cache_dir updates; reset so the new directory
-    # takes effect even after earlier compiles in this process
-    try:
-        from jax.experimental.compilation_cache import compilation_cache as _cc
-
+    env = _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    directory = str(directory) if directory is not None \
+        else _DEFAULT_COMPILATION_CACHE
+    if jax.config.jax_compilation_cache_dir != directory:
+        jax.config.update("jax_compilation_cache_dir", directory)
+        # jax binds its cache object to the directory at first use and then
+        # ignores updates; reset so a changed directory takes effect even
+        # after earlier compiles in this process
         _cc.reset_cache()
-    except Exception:
-        pass
-    for opt in ("jax_persistent_cache_min_compile_time_secs",
-                "jax_compilation_cache_min_compile_time_secs"):  # older spelling
-        try:
-            jax.config.update(opt, float(min_compile_secs))
-            break
-        except AttributeError:
-            continue
-    else:
-        import warnings
-
-        warnings.warn("could not set the persistent-cache compile-time threshold; "
-                      "jax's default (1s) applies — sub-second compiles won't persist")
-    # the kernel-quarantine set persists next to the cached executables: a
-    # warm restart skips known-bad kernels BEFORE paying a doomed compile
-    from thunder_tpu.runtime import quarantine as _rt_quarantine
-
-    _rt_quarantine.configure(str(directory))
-    # fitted cost-model constants persist there too: a warm restart applies
-    # this platform's calibration overlay before the first verdict (every
-    # affected decision records a typed ``calibrated[...]`` reason)
-    from thunder_tpu.observe import calibrate as _obs_calibrate
-
-    _obs_calibrate.configure(str(directory))
-
-
-if _os.environ.get("THUNDER_TPU_COMPILATION_CACHE"):
-    enable_compilation_cache(_os.environ["THUNDER_TPU_COMPILATION_CACHE"])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_secs))
+    return directory
 
 
 def manual_seed(seed: int) -> None:
@@ -433,13 +427,17 @@ class ThunderTPUFunction:
         return self._run_contained(entry.run_fn, inps, args, kwargs)
 
     def _run_contained(self, run_fn, inps, args, kwargs):
-        """Run a compiled entry with the two containment paths armed: a
-        claimed-kernel crash quarantines and recompiles; a sentinel
-        silent-fault escalation bisects. Shared by ``__call__`` and the
-        ``bind()`` fast path so the dispatch can never drift between them."""
+        """Run a compiled entry with the two containment paths: inside
+        ``runtime.quarantine.containment()`` (the supervisors' opt-in) a
+        claimed-kernel crash quarantines and recompiles — anywhere else it
+        raises; a sentinel silent-fault escalation bisects. Shared by
+        ``__call__`` and the ``bind()`` fast path so the dispatch can never
+        drift between them."""
         try:
             return run_fn(*inps)
         except KernelExecutionError as err:
+            if not _quarantine.containment_enabled():
+                raise
             return self._quarantine_and_rerun(err, args, kwargs)
         except _sentinel.SilentNumericsFault as err:
             return self._bisect_and_rerun(err, args, kwargs)
@@ -853,16 +851,34 @@ class ThunderTPUFunction:
             donate = tuple(
                 j for j, fi in enumerate(entry.tensor_indices)
                 if entry.arg_of_flat.get(fi) in donate_args)
-        entry.run_fn = jax.jit(entry.computation_fn, donate_argnums=donate)
-        entry.jit_obj = entry.run_fn
         # GSPMD: when any input is committed to a multi-device mesh the jit
         # compiles one SPMD program over it — record the device count so the
         # census ring model and budget gates divide by the right n
+        gspmd_mesh = None
         for leaf in flat:
             sh = getattr(leaf, "sharding", None)
             if (isinstance(sh, jax.sharding.NamedSharding)
                     and sh.mesh.size > getattr(entry, "n_dev", 1)):
                 entry.n_dev = sh.mesh.size
+                gspmd_mesh = sh.mesh
+        program = entry.computation_fn
+        if gspmd_mesh is not None:
+            # Mosaic kernels cannot be auto-partitioned: claimed Pallas
+            # impls must know, WHENEVER this program is traced (first run,
+            # census lowering), which mesh it compiles over, so they wrap
+            # themselves in a shard_map with their partitioning plan
+            from thunder_tpu.executors.pallasex import gspmd_mesh as _scope
+
+            def program(*inps, _fn=entry.computation_fn, _mesh=gspmd_mesh):
+                with _scope(_mesh):
+                    return _fn(*inps)
+
+            # same jit name -> same HLO module name -> same persistent-cache
+            # key as the unwrapped program
+            program.__name__ = entry.computation_fn.__name__
+
+        entry.run_fn = jax.jit(program, donate_argnums=donate)
+        entry.jit_obj = entry.run_fn
 
     @property
     def _extra_cache_key(self):

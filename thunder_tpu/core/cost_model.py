@@ -26,22 +26,39 @@ need to rank alternatives, not predict runtimes.
 
 from __future__ import annotations
 
+from thunder_tpu.core.devices import CHIP_SPECS
 from thunder_tpu.core.prims import OpTags, PrimIDs
 from thunder_tpu.core.proxies import TensorProxy
 from thunder_tpu.core.symbol import BoundSymbol
 from thunder_tpu.core.utils import consumed_vars, produced_vars
 
-# v5e bf16 peak over HBM bandwidth; the ridge point of the roofline.
-TPU_RIDGE_FLOPS_PER_BYTE = 240.0
+# The model prices ONE chip: the v5e row of the device table
+# (core/devices.py::CHIP_SPECS — peaks and budgets live there, with their
+# source). CPU runs rehearse this same plan with interpret-mode kernels; on
+# an accelerator the Pallas executor refuses to claim unless the attached
+# chip's device_kind IS this row (executors/pallasex.py::_on_tpu), so these
+# figures are never silently applied to a different device.
+MODELED_DEVICE_KIND = "TPU v5 lite"
+_CHIP = CHIP_SPECS[MODELED_DEVICE_KIND]
 
-# v5e scoped-VMEM budget a single Pallas kernel invocation can stage (the
-# chip holds ~16 MiB usable after Mosaic's own reservations; the r5 combined
-# attention backward measured the hard error at ~17.6 MB). Block-planner
-# feasibility checks model against this.
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+# peak matmul rate (bf16) — shared by the sub-block model below.
+TPU_PEAK_FLOPS = _CHIP.peak_bf16_flops
 
-# v5e peak matmul rate (bf16) — shared by the sub-block model below.
-TPU_PEAK_FLOPS = 197e12
+# bf16 peak over HBM bandwidth; the ridge point of the roofline (~240).
+TPU_RIDGE_FLOPS_PER_BYTE = _CHIP.peak_bf16_flops / _CHIP.hbm_bytes_per_s
+
+# VMEM a single Pallas kernel invocation is PLANNED against: Mosaic's default
+# scoped limit on this chip (the r5 combined attention backward measured the
+# hard error at ~17.6 MB). Block-planner feasibility checks model against it.
+VMEM_BUDGET_BYTES = _CHIP.scoped_vmem_default_bytes
+
+# ...and what the planned megakernels are COMPILED with (vmem_limit_bytes).
+# The staging models below leave out Mosaic's own scratch, layout padding
+# and semaphores — the bench-geometry MLP sub-block models 15.7 MB and the
+# compiler allocates 16.01 MiB — so the compiler's limit sits at twice the
+# planner's budget: a quarter of this chip's physical VMEM.
+VMEM_LIMIT_BYTES = 2 * VMEM_BUDGET_BYTES
+assert VMEM_LIMIT_BYTES <= _CHIP.vmem_bytes
 
 # Below this many bytes of traffic a dedicated kernel launch can't amortize
 # its dispatch + pipeline-fill overhead against XLA's fused code (~1 MiB is
@@ -219,14 +236,15 @@ def claim_worthwhile(bsym: BoundSymbol) -> bool:
 
 # --- fused multi-tensor optimizer model -----------------------------------
 # The AdamW update is pure HBM-bound pointwise: read g,p,m,v + write p,m,v.
-# PERF_R5 measured the per-parameter fused chains at ~45% of nominal HBM
-# bandwidth at the bench scale (34 ms against a 14.7 ms roofline; a
-# hand-written pure-jax layout measured the same, so the inefficiency is the
-# per-fusion 7-stream access pattern, not framework overhead). A single
-# flattened multi-tensor kernel walks one contiguous slab per operand with
-# full-tile DMAs — modeled at 85% — and replaces n dispatches with one.
+# The r5 breakdown (builder run, 2026-08) measured the per-parameter fused
+# chains at ~45% of nominal HBM bandwidth at the bench scale (34 ms against a
+# 14.7 ms roofline; a hand-written pure-jax layout measured the same, so the
+# inefficiency is the per-fusion 7-stream access pattern, not framework
+# overhead). The multi-tensor kernel walks full (rows, lanes) tiles of each
+# stream — modeled at 85% — and replaces n fusions with one launch per
+# aligned matrix plus one for the remainder.
 ADAMW_LAUNCH_OVERHEAD_US = 8.0   # per-fusion dispatch + pipeline fill, v5e
-ADAMW_HBM_GBPS = 819.0           # v5e nominal HBM bandwidth
+ADAMW_HBM_GBPS = _CHIP.hbm_bytes_per_s / 1e9   # nominal HBM bandwidth
 ADAMW_CHAIN_EFFICIENCY = 0.45    # measured: per-param fused pointwise chains
 ADAMW_FUSED_EFFICIENCY = 0.85    # modeled: one contiguous slab per operand
 
@@ -239,31 +257,26 @@ def fused_adamw_cost(n_tensors: int, total_bytes: int,
     writes, in their stored dtypes). Returned dict feeds the decision log
     (``observe.explain`` shows why each bucket did or didn't fuse).
 
-    STATED ASSUMPTION: the slab pack/unpack around the kernel (the impl
-    ravels+concatenates the inputs and slices the outputs back) is NOT
-    charged to the fused path — the model assumes XLA's concatenate fusion
-    absorbs the packs into the gradient producers and the unpacks into the
-    donated-output consumers. If that fails on chip, the un-absorbed
-    traffic is another ~2× ``total_bytes`` (one staging read+write per
-    stream) and fusing large buckets is a net LOSS; the figure is surfaced
-    as ``pack_bytes_if_unabsorbed`` so the decision log carries the risk,
-    and PERF_R6 §4's interleaved A/B is the validation that decides it.
-    The same staging also defeats in-place donation aliasing for the
-    bucketed p/m/v (the slabs are fresh buffers), so peak optimizer-state
-    residency transiently grows by the bucket size during the update —
-    time, not residency, is what this model ranks; near the HBM capacity
-    limit pass ``fused_optimizer=False`` (or rely on the depth configs'
-    remat headroom) until slab-persistent state lands.
+    PACKING: no staging is charged to the fused path, and since PR 21 none
+    exists for the bytes that matter — the Pallas claim updates every
+    tile-aligned matrix of the bucket in place in its own layout (p/m/v
+    alias their outputs) and packs only the unaligned remainder (norm
+    vectors, biases) into a slab. The first implementation packed EVERY
+    tensor on the assumption that XLA would absorb the concatenates; it
+    cannot fuse them into a Mosaic custom call, and AOT-compiled for a v5e
+    the packs were 5.0 GiB of temporaries for one 7B-geometry layer (the
+    2-layer bench bucket did not fit 16 GB). ``pack_bytes_if_unabsorbed``
+    stays in the dict as the UPPER bound — what a bucket of nothing but
+    unaligned tensors would stage.
 
     ``slab_persistent=True`` (``optim.AdamW(slab_persistent=True)``): m/v
     live packed in per-dtype-bucket ``(rows, 128)`` slabs BETWEEN steps —
     the m/v pack/unpack around the kernel no longer exists (the kernel
     reads and writes the persistent slabs directly), so the
     ``pack_bytes_if_unabsorbed`` downside is zero BY CONSTRUCTION for the
-    state streams (only the p/g pack remains exposed to XLA's concatenate
-    fusion, ~1/3 of the staging risk the r6 note recorded). The dict says
-    which layout the verdict was computed under so the decision log and
-    PERF_R6's risk note can never silently disagree."""
+    state streams (the p/g pack remains: on this path p and g are still
+    staged in full every step). The dict says which layout the verdict was
+    computed under."""
     launch = constant("ADAMW_LAUNCH_OVERHEAD_US")
     stream_us = total_bytes / (constant("ADAMW_HBM_GBPS") * 1e3)
     unfused = stream_us / constant("ADAMW_CHAIN_EFFICIENCY") + n_tensors * launch

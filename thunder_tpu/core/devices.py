@@ -83,15 +83,72 @@ def to_device(x: Any) -> Device:
         return default_device()
     # jax.Device
     if hasattr(x, "platform"):
-        return Device(_KNOWN.get(x.platform, DeviceType.CPU), getattr(x, "id", 0))
+        return Device(_platform_type(x.platform), getattr(x, "id", 0))
     raise TypeError(f"cannot interpret {x!r} as a Device")
+
+
+def _platform_type(platform: str) -> DeviceType:
+    """A jax platform name -> DeviceType. An unknown platform is an error:
+    guessing CPU or TPU from the name would run the wrong plan silently."""
+    if platform not in _KNOWN:
+        raise ValueError(f"unknown jax platform {platform!r}; "
+                         f"known: {sorted(_KNOWN)}")
+    return _KNOWN[platform]
 
 
 def default_device() -> Device:
     import jax
 
     d = jax.devices()[0]
-    return Device(_KNOWN.get(d.platform, DeviceType.TPU if "tpu" in d.platform else DeviceType.CPU), d.id)
+    return Device(_platform_type(d.platform), d.id)
+
+
+@dataclass(frozen=True)
+class ChipSpec:
+    """Published figures of one accelerator chip. ``name`` is the short key
+    the calibration store and the budget files use (``tpu-<name>``)."""
+
+    name: str
+    peak_bf16_flops: float      # FLOP/s
+    hbm_bytes_per_s: float
+    hbm_bytes: int
+    vmem_bytes: int             # physical VMEM of one core
+    scoped_vmem_default_bytes: int  # Mosaic's per-kernel limit when a
+    #                                 pallas_call passes no vmem_limit_bytes
+    source: str
+
+
+# THE device table, keyed by ``jax.devices()[0].device_kind`` exactly as the
+# chip prints it. Every peak, bandwidth and budget in the tree (cost model,
+# MFU arithmetic, calibration platform key) reads from here; a kind that is
+# not listed is an error, never a default.
+CHIP_SPECS: dict[str, ChipSpec] = {
+    "TPU v5 lite": ChipSpec(
+        name="v5e", peak_bf16_flops=197e12, hbm_bytes_per_s=819e9,
+        hbm_bytes=16 * 1024 ** 3,
+        # VMEM figures are the compiler's own (libtpu 0.0.34, AOT compile
+        # for this chip): a kernel staging 16.01 MiB is refused "limit
+        # 16.00M" by default and a 64 MiB scoped allocation is accepted
+        # once vmem_limit_bytes asks for it
+        vmem_bytes=128 * 1024 * 1024,
+        scoped_vmem_default_bytes=16 * 1024 * 1024,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def chip_spec(device_kind: str | None = None) -> ChipSpec:
+    """The table row for ``device_kind`` (default: the first attached
+    device's). Raises for a kind the table does not list."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in CHIP_SPECS:
+        raise RuntimeError(
+            f"device_kind {device_kind!r} is not in thunder_tpu.core.devices."
+            f"CHIP_SPECS (known: {sorted(CHIP_SPECS)}) — add its published "
+            f"figures with their source before running on it")
+    return CHIP_SPECS[device_kind]
 
 
 cpu = Device(DeviceType.CPU, 0)

@@ -36,15 +36,6 @@ from thunder_tpu.core.pytree import tree_flatten, tree_map
 from thunder_tpu.core.transform_common import Transform
 
 
-def _shard_map():
-    try:
-        return jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm
-
-
 def _P(*args):
     from jax.sharding import PartitionSpec
 
@@ -505,13 +496,9 @@ class DistributedFunction(ThunderTPUFunction):
             fallback=lambda leaf: out_fallback_by_id.get(id(leaf)),
             axis_sizes=dict(zip(self.mesh_spec.axis_names, self.mesh_spec.axis_sizes)))
 
-        sm = _shard_map()
-        try:
-            smapped = sm(entry.computation_fn, mesh=self._mesh, in_specs=tuple(in_specs),
-                         out_specs=out_specs, check_vma=False)
-        except TypeError:
-            smapped = sm(entry.computation_fn, mesh=self._mesh, in_specs=tuple(in_specs),
-                         out_specs=out_specs, check_rep=False)
+        smapped = jax.shard_map(entry.computation_fn, mesh=self._mesh,
+                                in_specs=tuple(in_specs), out_specs=out_specs,
+                                check_vma=False)
         from thunder_tpu.distributed import use_mesh
 
         jitted = jax.jit(smapped)

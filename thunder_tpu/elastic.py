@@ -43,6 +43,7 @@ from typing import Any, Callable
 from thunder_tpu.checkpoint import (load_checkpoint, save_checkpoint,
                                     wait_for_checkpoints)
 from thunder_tpu.observe import registry as _observe
+from thunder_tpu.runtime import quarantine as _quarantine
 from thunder_tpu.runtime import retry as _retry
 from thunder_tpu.runtime import sentinel as _sentinel
 from thunder_tpu.runtime.faults import FaultPlan
@@ -457,11 +458,14 @@ class ElasticTrainer:
     # -- run ----------------------------------------------------------------
     def run(self, state: Any, data_fn: Callable[[int], Any], n_steps: int) -> Any:
         if self.compile_cache_dir is not None:
-            # warm restart: executables (and the kernel-quarantine set) come
-            # from disk, so the post-crash replay compiles in seconds
+            # warm restart: executables come from disk, so the post-crash
+            # replay compiles in seconds — and this supervisor ASKS for the
+            # kernel-quarantine set to persist beside them, so the restarted
+            # process skips a known-bad kernel before a doomed compile
             import thunder_tpu as tt
 
             tt.enable_compilation_cache(self.compile_cache_dir)
+            _quarantine.configure(self.compile_cache_dir)
         installed: dict[int, Any] = {}
         if self.handle_preemption:
             def _on_signal(signum, frame):
@@ -526,7 +530,10 @@ class ElasticTrainer:
                     self.fault_plan.maybe_fail("step", step=step)
                 if self.fault_injector is not None:
                     self.fault_injector.maybe_fail(step)
-                state = self.step_fn(state, data_fn(step))
+                # supervised production opts in to kernel-fault containment
+                # (quarantine + recompile on the XLA decomposition)
+                with _quarantine.containment():
+                    state = self.step_fn(state, data_fn(step))
                 step += 1
                 consecutive_failures = 0
                 if self.heartbeat is not None:

@@ -55,11 +55,10 @@ class Executor:
         impl = self.implmap.get(bsym.sym.id)
         if impl is None:
             return False
+        # a checker that RAISES is a bug, not a "no": it propagates, so a
+        # kernel never silently stops claiming
         if impl.checker is not None:
-            try:
-                return bool(impl.checker(*bsym.args, **bsym.kwargs))
-            except Exception:
-                return False
+            return bool(impl.checker(*bsym.args, **bsym.kwargs))
         return True
 
     def get_impl(self, bsym: BoundSymbol) -> ImplInfo | None:
@@ -214,9 +213,4 @@ def _ensure_builtin_executors():
     if _builtins_loaded:
         return
     _builtins_loaded = True
-    from thunder_tpu.executors import eagerjax, xla  # noqa: F401
-
-    try:
-        from thunder_tpu.executors import pallasex  # noqa: F401
-    except Exception:
-        pass
+    from thunder_tpu.executors import eagerjax, pallasex, xla  # noqa: F401

@@ -78,30 +78,19 @@ def claim_bsym(bsym: BoundSymbol, executors, trc: TraceCtx) -> list[BoundSymbol]
             continue
         # cost-model gate: a legal claim may still lose to leaving the op
         # inside an XLA fusion region (memory-bound op, tiny working set).
-        # Exceptions fail CLOSED (no claim), mirroring the checker path —
-        # a broken cost model must not silently disable the gate
-        if impl.profitable is not None:
-            try:
-                profitable = bool(impl.profitable(bsym))
-            except Exception:
-                profitable = False
-            if not profitable:
-                if log:
-                    from thunder_tpu.core import cost_model
+        # A gate that RAISES is a bug in the cost model and propagates —
+        # it must not read as "don't claim"
+        if impl.profitable is not None and not impl.profitable(bsym):
+            if log:
+                from thunder_tpu.core import cost_model
 
-                    # a broken cost model fails the claim CLOSED (above);
-                    # logging its numbers must not resurrect the exception
-                    try:
-                        flops, nbytes = cost_model.bsym_cost(bsym)
-                        cost = {"flops": flops, "bytes": nbytes,
-                                "min_claim_bytes": cost_model.MIN_CLAIM_BYTES}
-                    except Exception:
-                        cost = None
-                    _decisions.record(
-                        "claim", bsym.sym.name, ex.name, "rejected",
-                        "cost model: claim loses to XLA region fusion",
-                        cost=cost)
-                continue
+                flops, nbytes = cost_model.bsym_cost(bsym)
+                _decisions.record(
+                    "claim", bsym.sym.name, ex.name, "rejected",
+                    "cost model: claim loses to XLA region fusion",
+                    cost={"flops": flops, "bytes": nbytes,
+                          "min_claim_bytes": cost_model.MIN_CLAIM_BYTES})
+            continue
         if not getattr(ex, "get_fuel", lambda *_: True)():
             if log:
                 _decisions.record("claim", bsym.sym.name, ex.name, "rejected",

@@ -526,9 +526,9 @@ def generate_fused(params, cfg: LlamaConfig, prompt, max_new_tokens: int,
                    max_len: int | None = None, n_layers: int | None = None):
     """Greedy decoding with the WHOLE decode loop compiled as one XLA
     program: ``lax.scan`` over the framework-traced step, so generation is
-    a single device dispatch — no per-token host round-trips (on a
-    tunneled/remote chip the per-step ``generate`` loop pays one RTT per
-    token; this pays one total). The scanned body IS the compiled entry's
+    a single device dispatch — no per-token host round-trips (the per-step
+    ``generate`` loop pays one dispatch + one token fetch per token; this
+    pays one total). The scanned body IS the compiled entry's
     computation (same trace, same executors) — not a reimplementation.
     Reference analog: litgpt's generate is a per-step Python loop; this is
     the TPU-native replacement."""

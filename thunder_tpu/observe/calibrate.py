@@ -14,10 +14,12 @@ predicted and what a profiled window measured — this module closes the loop:
   so two accumulated records per family already determine both constants;
   more records over-determine and the normal equations average the noise.
 - **Persist** (:func:`save` / :func:`configure`): fitted constants land in
-  schema-versioned ``cost_calibration.json`` next to the persistent compile
-  cache and the kernel-quarantine set (same atomic tmp+replace write, same
-  ``enable_compilation_cache`` wiring, ``THUNDER_TPU_CALIBRATION_DIR`` env
-  override), keyed by platform — a v5e fit never leaks onto v5p.
+  schema-versioned ``cost_calibration.json`` under the directory an explicit
+  ``configure()`` or ``THUNDER_TPU_CALIBRATION_DIR`` names (same atomic
+  tmp+replace write as the kernel-quarantine set; never the compile-cache
+  directory by default — an overlay that changes verdicts must not travel
+  with a cache reused across commits), keyed by platform — a v5e fit never
+  leaks onto v5p.
 - **Apply**: :func:`configure`/:func:`activate` install the CURRENT
   platform's constants into ``cost_model``'s overlay, so every later cost
   dict is stamped ``"calibration": <platform>`` and every affected verdict
@@ -51,19 +53,19 @@ _BANDWIDTH_BOUNDS = (1e3, 1e13)    # bytes/s
 
 
 def platform() -> str:
-    """The calibration platform key for this process: the JAX backend,
-    refined by TPU generation (``tpu-v5e`` vs ``tpu-v5p`` fit different
-    constants; every CPU host shares ``cpu-interpret``)."""
+    """The calibration platform key for this process: ``tpu-<chip>`` from
+    the device table (``core.devices.chip_spec`` — a device_kind the table
+    lacks raises), or ``cpu-interpret`` for every CPU host."""
     import jax
 
+    from thunder_tpu.core.devices import chip_spec
+
     backend = jax.default_backend()
+    if backend == "cpu":
+        return "cpu-interpret"
     if backend != "tpu":
-        return f"{backend}-interpret" if backend == "cpu" else backend
-    kind = getattr(jax.devices()[0], "device_kind", "tpu").lower()
-    for tag in ("v5e", "v5p", "v5litepod", "v6e", "v4", "v3"):
-        if tag in kind:
-            return "tpu-" + ("v5e" if tag == "v5litepod" else tag)
-    return "tpu"
+        return backend
+    return "tpu-" + chip_spec().name
 
 
 # ---------------------------------------------------------------------------
@@ -291,9 +293,8 @@ def activate(plat: str | None = None) -> bool:
 
 
 def configure(directory: str) -> bool:
-    """Persist calibrations under ``directory`` (next to the compile cache
-    and the quarantine set — ``enable_compilation_cache`` wires this), then
-    activate the current platform's constants if any were ever fitted."""
+    """Persist calibrations under ``directory``, then activate the current
+    platform's constants if any were ever fitted."""
     _store.attach(os.path.join(str(directory), _FILENAME))
     if not _store.platforms():
         return False  # nothing ever fitted: don't touch the jax backend

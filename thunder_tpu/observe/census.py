@@ -213,25 +213,23 @@ def trace_census(exec_trc, n_dev: int = 1) -> dict:
     """Launch/fusion shape of an execution trace plus the collective counts
     the TRACE expects. One owner for the claimed-launch walk: the serving
     runner's ``serving.decode_pallas_launches`` gauges are fed from here."""
-    launches = 0
-    decode_layers = 0
+    claims: dict[str, int] = {}     # claimed symbol id -> occurrences
 
     def walk(bsyms):
-        nonlocal launches, decode_layers
         for b in bsyms:
             ex = b.sym.executor
             if ex is not None and ex.name == "pallas":
                 # one claimed kernel = one launch; its subsymbols are the
                 # decomposition (never dispatched), don't recurse
-                launches += 1
-                if b.sym.name == "decode_layer":
-                    decode_layers += 1
+                claims[b.sym.id] = claims.get(b.sym.id, 0) + 1
                 continue
             # XLA regions ABSORB claimed pallas calls (Fusion 2.0); the
             # launches live one level down
             walk(b.subsymbols)
 
     walk(exec_trc.bound_symbols)
+    launches = sum(claims.values())
+    decode_layers = claims.get("pallas.decode_layer", 0)
     regions = sum(1 for b in exec_trc.bound_symbols
                   if str(b.sym.id).startswith("xla.fusion"))
     expected: dict[str, int] = {}
@@ -251,7 +249,7 @@ def trace_census(exec_trc, n_dev: int = 1) -> dict:
         # counted (census['errors']), never swallowed
         errors.append(f"comm_report: {e!r}")
     return {"pallas_launches": launches, "decode_layer_fusions": decode_layers,
-            "xla_regions": regions, "expected_collectives": expected,
+            "pallas_claims": claims, "xla_regions": regions, "expected_collectives": expected,
             "expected_collective_count": total_expected,
             "expected_recv_bytes_per_device": expected_recv, "errors": errors}
 

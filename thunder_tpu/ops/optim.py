@@ -115,7 +115,7 @@ def fused_adamw_slab(params, grads, m_slab, v_slab, bc1, bc2, *,
 
     The Pallas claim (``executors/pallasex.py::pallas_fused_adamw_slab``)
     reads/writes the slabs directly — the m/v pack/unpack around the kernel
-    (the ``pack_bytes_if_unabsorbed`` risk PERF_R6 recorded) does not exist
+    (the ``pack_bytes_if_unabsorbed`` risk the r6 cost model recorded) does not exist
     on this path. Unclaimed, this decomposition unpacks each parameter's
     moment rows from the slab, runs the exact per-parameter ``adamw_step``
     chain, and repacks — numerics are identical either way. The slab's

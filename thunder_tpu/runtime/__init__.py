@@ -14,12 +14,14 @@ SURVEY §5 "Failure detection / elastic recovery: Absent" in the reference):
   exponential backoff, deadline budgets, a sliding-window
   :class:`RestartBudget`, and an exception classifier
   (retryable / fatal / degradable).
-- ``quarantine``: when a claimed kernel fails at compile or at runtime the
-  dispatch layer quarantines that claim id, recompiles the trace with the
-  claim disabled (the op falls back to the XLA executor), and persists the
-  quarantine set next to the persistent compile cache so restarts skip the
-  known-bad kernel. Every fallback lands in ``CompileStats.last_decisions``
-  (visible in ``observe.explain()``) and the ``runtime.fallbacks`` counter.
+- ``quarantine``: a claimed kernel that fails at compile or at runtime is
+  an ERROR by default; inside ``quarantine.containment()`` (the supervisors'
+  opt-in) the dispatch layer instead quarantines that claim id, recompiles
+  the trace with the claim disabled (the op falls back to the XLA executor),
+  and persists the quarantine set where ``configure()`` /
+  ``THUNDER_TPU_QUARANTINE_DIR`` points so restarts skip the known-bad
+  kernel. Every fallback lands in ``CompileStats.last_decisions`` (visible
+  in ``observe.explain()``) and the ``runtime.fallbacks`` counter.
 
 - ``sentinel``: the numerical-integrity side of the fault taxonomy — silent
   data faults (NaN/Inf grads, loss spikes, numerically corrupt claimed

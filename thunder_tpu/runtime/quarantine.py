@@ -9,12 +9,20 @@ the quarantined claim is rejected with a ``"quarantined: ..."`` decision
 record (visible in ``observe.explain()``) and the op falls through to the
 XLA executor's lowering — graceful degradation instead of a dead job.
 
-Persistence: :func:`configure` points the quarantine at a directory (by
-default the persistent compile cache directory, wired through
-``thunder_tpu.enable_compilation_cache``); the set is written as JSON next
-to the cached executables, so a restarted process skips the known-bad
-kernel *before* paying a doomed compile. ``THUNDER_TPU_QUARANTINE_DIR``
-configures it from the environment.
+Containment is OPT-IN (:func:`containment`): by default a claimed kernel
+that raises while being traced, compiled or run is an ERROR — a bring-up, a
+benchmark or a test that asserts a kernel is present must see the
+exception, not a program that quietly served through the XLA decomposition.
+The supervisors (``serving.EngineSupervisor``, ``elastic.ElasticTrainer``)
+run their steps inside ``containment()``; measurement scripts finish with
+:func:`assert_clean`.
+
+Persistence: :func:`configure` points the quarantine at a directory (an
+explicit call, or ``THUNDER_TPU_QUARANTINE_DIR`` from the environment); the
+set is written as JSON there, so a restarted process skips the known-bad
+kernel *before* paying a doomed compile. It never rides in the compile-cache
+directory by default: a set that changes WHAT is compiled must not travel
+with a cache a driver reuses across commits.
 
 Every mutation bumps a process-wide *epoch* that joins the dispatch cache
 key, so entries compiled before a quarantine event can never serve after it.
@@ -157,8 +165,8 @@ def get_quarantine() -> KernelQuarantine:
 
 
 def configure(directory: str) -> KernelQuarantine:
-    """Persist the quarantine set under ``directory`` (next to the compile
-    cache): loads claim ids a previous process recorded there."""
+    """Persist the quarantine set under ``directory``: loads claim ids a
+    previous process recorded there."""
     _active.attach(os.path.join(str(directory), _FILENAME))
     return _active
 
@@ -175,6 +183,41 @@ def reset(path: str | None = None) -> KernelQuarantine:
 
 def is_quarantined(claim_id: str) -> bool:
     return claim_id in _active
+
+
+def assert_clean() -> None:
+    """Raise unless no kernel is quarantined and no fallback ran — the
+    last line of a bring-up or a measurement: whatever they timed or
+    checked was the program the planner chose, not a degraded one."""
+    fallbacks = _observe.get_registry().counters.get("runtime.fallbacks", 0)
+    if len(_active) or fallbacks:
+        raise RuntimeError(
+            f"kernel fallback on a measured path: runtime.fallbacks="
+            f"{fallbacks}, quarantined="
+            f"{ {k: _active.reason(k) for k in _active.ids()} }")
+
+
+# containment (quarantine -> recompile on the XLA decomposition -> re-run) is
+# recovery for SUPERVISED production; everywhere else a failing kernel is an
+# error. A ContextVar like the suppression set below: the opt-in is visible
+# only to the supervisor's own call chain.
+_containment: ContextVar[bool] = ContextVar("kernel_fault_containment",
+                                            default=False)
+
+
+def containment_enabled() -> bool:
+    return _containment.get()
+
+
+@contextmanager
+def containment():
+    """Within this block a claimed kernel that fails is quarantined and the
+    step re-runs on the XLA decomposition instead of raising."""
+    tok = _containment.set(True)
+    try:
+        yield
+    finally:
+        _containment.reset(tok)
 
 
 # temporary (non-persisted) claim disables: the numerics bisection recompiles

@@ -324,6 +324,7 @@ class ServingEngine:
             max_attempts=3, base_delay_s=0.05, max_delay_s=1.0)
         self._decode_bound = None
         self._bound_epoch = -1
+        self.last_decode_logits = None  # (S, V) device array of the last step
         # persistent decode-step input buffers: rebuilt rows only for slots
         # whose state changed (the block-table row is cached per request) —
         # per-step host work stays O(active), not O(slots * table width)
@@ -1090,12 +1091,13 @@ class ServingEngine:
                                       temps, topk, topp, rng)
 
         t0_us = _observe._now_us()
-        tok_ids, _logits, pools = \
+        tok_ids, self.last_decode_logits, pools = \
             self._dispatch_guarded(dispatch, "serving:decode")
         self.cache.update_pools(pools)
         # tokens were sampled IN-GRAPH; fetching the (S,) id vector is the
         # host sync that makes the span below an honest device-step bound
-        # (the (S, V) logits output stays on device, unread)
+        # (the (S, V) logits output stays on device, unread — the handle is
+        # kept for parity checks: chip_smoke.py reads one slot's row)
         toks = np.asarray(tok_ids)
         # the dispatch half of the iteration, on the scheduler track
         self.obs.record_span("decode_dispatch", "serving:sched", t0_us,

@@ -52,6 +52,7 @@ import time
 from typing import Callable
 
 from thunder_tpu.observe import registry as _observe
+from thunder_tpu.runtime import quarantine as _quarantine
 from thunder_tpu.runtime import retry as _retry
 from thunder_tpu.serving.errors import (
     EngineFault,
@@ -144,7 +145,11 @@ class EngineSupervisor:
         if self.statusz is not None:
             self.statusz.maybe_write(self.status_payload())
         try:
-            worked = self.engine.step()
+            # supervised production opts in to kernel-fault containment: a
+            # claimed kernel that dies is quarantined and the step re-runs
+            # on its XLA decomposition (unsupervised, it raises)
+            with _quarantine.containment():
+                worked = self.engine.step()
         except EngineFault as e:
             # black box FIRST, while the engine still shows the crashed
             # state (consumed pools, stranded residents) — then recover
