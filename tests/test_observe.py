@@ -68,19 +68,23 @@ def test_enabled_counters_gauges_histograms_events():
 
 def test_enabled_span_is_one_ring_record_plus_registry_histogram():
     """An enabled span must not double into the flight ring: the span edge
-    IS the black-box record; the derived ``.ms`` histogram sample goes to
-    the registry only (doubling would halve the ring's usable history)."""
+    IS the black-box record; the histogram a span names goes to the
+    registry only (doubling would halve the ring's usable history). A span
+    that names none feeds none: the span record holds its duration."""
     from thunder_tpu.observe import flight
 
     observe.enable(clear=True)
     try:
-        with observe.span("solo", cat="test"):
+        with observe.span("solo", cat="test", histogram="test.solo.ms"):
+            pass
+        with observe.span("plain", cat="test"):
             pass
         recs = [r for r in flight.snapshot()
                 if r.get("name") in ("solo", "test.solo.ms")]
         assert [r["type"] for r in recs] == ["span"]
-        h = observe.snapshot()["histograms"]["test.solo.ms"]
-        assert h["count"] == 1
+        hists = observe.snapshot()["histograms"]
+        assert hists["test.solo.ms"]["count"] == 1
+        assert not [h for h in hists if "plain" in h]
     finally:
         observe.disable()
 
@@ -170,6 +174,9 @@ def test_compile_stats_surfaces_interpret_and_transform_times():
 # ---------------------------------------------------------------------------
 
 def test_step_metrics_recorded_per_call():
+    from thunder_tpu.observe import flight
+
+    flight.clear()
     observe.enable(clear=True)
     jf = tt.jit(lambda a: ops.mul(a, 3.0).sum())
     x = np.ones((64, 64), np.float32)
@@ -181,12 +188,14 @@ def test_step_metrics_recorded_per_call():
     # walltime histogram (recorded as step.first_call_ms instead)
     assert snap["histograms"]["step.walltime_ms"]["count"] == 2
     assert snap["histograms"]["step.first_call_ms"]["count"] == 1
+    # static per entry: published once, at compile, not on every step
     assert snap["gauges"]["step.est_live_bytes"] > 0
-    step_spans = [s for s in snap["spans"] if s["cat"] == "step"]
+    assert len([r for r in flight.snapshot()
+                if r.get("name") == "step.est_live_bytes"]) == 1
+    step_spans = [s for s in snap["spans"] if s["name"].startswith("step:")]
     assert len(step_spans) == 3
-    assert step_spans[0]["args"]["first_call"] is True
-    assert step_spans[1]["args"]["first_call"] is False
-    assert step_spans[0]["args"]["est_live_bytes"] > 0
+    assert step_spans[0]["args"] == {"first_call": True}
+    assert step_spans[1]["args"] == {"first_call": False}
 
 
 def test_step_metrics_off_when_disabled():
@@ -507,10 +516,11 @@ def test_labeled_span_records_histogram_and_ring_edge():
     try:
         observe.enable(clear=True)
         rec = observe.labeled(engine="e0")
-        with rec.span("schedule", cat="serving:sched", args={"n": 2}):
+        with rec.span("schedule", cat="serving:sched", args={"n": 2},
+                      histogram="serving.schedule_ms"):
             pass
         s = rec.snapshot()
-        assert s["histograms"]["serving:sched.schedule.ms"]["count"] == 1
+        assert s["histograms"]["serving.schedule_ms"]["count"] == 1
         spans = observe.snapshot()["spans"]
         assert spans[0]["name"] == "schedule"
         assert spans[0]["labels"] == {"engine": "e0"}

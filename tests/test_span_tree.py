@@ -1,0 +1,476 @@
+"""One span tree (ISSUE 25): every span record has an identity and a cause;
+the serving iteration is a tree of leaves that cover it; a request's road to
+its first token adds up to its TTFT; a ``tt.jit`` call shows its guard and
+its dispatch, and a miss says why. All of it costs the flight ring nothing
+it did not cost before."""
+
+import importlib.util
+import os
+from collections import Counter
+
+import numpy as np
+import pytest
+
+import thunder_tpu as tt
+from thunder_tpu import observe, ops
+from thunder_tpu.models import llama
+from thunder_tpu.observe import flight
+from thunder_tpu.observe import registry as reg
+from thunder_tpu.serving import ServingEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+LEAVES = ("schedule", "decode_build", "decode_enqueue", "decode_wait",
+          "decode_deliver", "prefill_build", "prefill_chunk",
+          "prefill_deliver")
+
+
+@pytest.fixture(autouse=True)
+def _clean_registry():
+    observe.disable()
+    observe.reset()
+    yield
+    observe.disable()
+    observe.reset()
+
+
+def _spans(name=None):
+    return [s for s in observe.get_registry().spans
+            if name is None or s["name"] == name]
+
+
+def _one(name):
+    (s,) = _spans(name)
+    return s
+
+
+# ---------------------------------------------------------------------------
+# identity and cause
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("path", ["module", "labeled"])
+def test_ids_unique_and_parent_follows_nesting(path):
+    """Both span forms, module path and ``Labeled`` path alike: the
+    context-manager form nests by the block; a span handed over with its
+    timestamps hangs under the span that was open when it BEGAN."""
+    h = reg if path == "module" else observe.labeled(engine="e-test")
+    observe.enable(clear=True)
+    before = reg._now_us()
+    with h.span("outer", "test"):
+        with h.span("inner", "test"):
+            t0 = reg._now_us()
+            h.record_span("handed_inner", "test", t0, 1.0)
+        # began before "outer" opened: no span of this thread was open then
+        h.record_span("handed_early", "test", before, 1.0)
+        t1 = reg._now_us()
+    h.record_span("handed_late", "test", t1, 1.0)    # its parent has closed
+    spans = _spans()
+    ids = [s["id"] for s in spans]
+    assert len(set(ids)) == len(ids) == 5 and all(isinstance(i, int) for i in ids)
+    by = {s["name"]: s for s in spans}
+    assert by["outer"]["parent"] is None
+    assert by["inner"]["parent"] == by["outer"]["id"]
+    assert by["handed_inner"]["parent"] == by["inner"]["id"]
+    assert by["handed_early"]["parent"] is None
+    assert by["handed_late"]["parent"] is None
+    if path == "labeled":
+        assert all(s["labels"] == {"engine": "e-test"} for s in spans)
+    # the ring's copy of each record carries the same identity
+    ring = {r["name"]: r for r in flight.snapshot()
+            if r["type"] == "span" and r["id"] in ids}
+    assert {n: (r["id"], r["parent"]) for n, r in ring.items()} == \
+        {n: (s["id"], s["parent"]) for n, s in by.items()}
+
+
+def test_parent_is_per_thread():
+    """A span opened on another thread is not this thread's parent."""
+    import threading
+
+    observe.enable(clear=True)
+    with observe.span("main_outer", "test"):
+        t = threading.Thread(
+            target=lambda: observe.span("other", "test").__enter__()
+            .__exit__(None, None, None))
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    assert _one("other")["parent"] is None
+
+
+@pytest.mark.parametrize("path", ["module", "labeled"])
+def test_registry_only_span_costs_nothing_when_off(path):
+    """``ring=False``: with the registry off the span is THE shared no-op —
+    no record anywhere; with it on, a registry record and still no ring
+    record."""
+    h = reg if path == "module" else observe.labeled(engine="e-test")
+    flight.clear()
+    a, b = h.span("sub", "test", ring=False), h.span("sub", "test", ring=False)
+    assert a is b is reg._NO_SPAN and not a.live
+    with a as sp:
+        sp.cancel()
+    assert flight.snapshot() == [] and not _spans()
+    observe.enable(clear=True)
+    with h.span("sub", "test", {"step": 1}, ring=False) as sp:
+        assert sp.live
+    assert _one("sub")["args"] == {"step": 1}
+    assert not [r for r in flight.snapshot() if r.get("name") == "sub"]
+
+
+def test_cancelled_span_leaves_no_record():
+    flight.clear()
+    observe.enable(clear=True)
+    with observe.span("kept", "test"):
+        with observe.span("dropped", "test") as sp:
+            sp.cancel()
+            with observe.span("child", "test"):
+                pass
+    assert {s["name"] for s in _spans()} == {"kept", "child"}
+    assert "dropped" not in {r.get("name") for r in flight.snapshot()}
+    assert sp.dur_us >= 0
+
+
+def test_span_enters_a_trace_annotation_while_enabled(monkeypatch):
+    """An operator's profiler trace shows the program's spans: the
+    context-manager form enters a ``TraceAnnotation`` of the same name while
+    the registry is on, and none while it is off."""
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(reg, "_TraceAnnotation", Annotation)
+    with observe.span("off", "test"):
+        pass
+    assert seen == []
+    observe.enable(clear=True)
+    with observe.span("a", "test"):
+        with observe.labeled(engine="e").span("b", "test", ring=False):
+            pass
+    assert seen == [("enter", "a"), ("enter", "b"), ("exit", "b"), ("exit", "a")]
+
+
+def test_real_trace_annotation_is_entered():
+    import jax.profiler
+
+    observe.enable(clear=True)
+    with observe.span("annotated", "test"):
+        pass
+    assert reg._TraceAnnotation is jax.profiler.TraceAnnotation
+
+
+# ---------------------------------------------------------------------------
+# the serving iteration
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = llama.CONFIGS["tiny-gqa"]
+    return cfg, llama.init_params(cfg, seed=0)
+
+
+def _engine(model, **kw):
+    cfg, params = model
+    opts = dict(max_slots=3, page_size=16, max_context=64, n_layers=1,
+                prefill_chunk=32)
+    opts.update(kw)
+    return ServingEngine(params, cfg, **opts)
+
+
+def _prompt(n, seed=0):
+    return np.random.RandomState(seed).randint(1, 500, size=n).astype(np.int32)
+
+
+def _warm(eng):
+    """Every prefill rung and the decode program, compiled and bound."""
+    for n in (5, 20, 40):
+        eng.submit(_prompt(n), 2)
+    eng.drain()
+    eng.completed.clear()
+
+
+def test_engine_step_leaves_cover_it(model):
+    """Over N iterations: one ``engine_step`` root each; its leaves lie
+    inside it and do not overlap; together they cover at least 95% of the
+    iterations' time. (All four layers and a wide batch, so that a step is
+    long against the microseconds each record costs.)"""
+    eng = _engine(model, max_slots=16, max_context=512, n_layers=None)
+    _warm(eng)
+    observe.enable(clear=True)
+    for i, n in enumerate((9, 20, 40, 5, 33, 17, 60, 12)):
+        eng.submit(_prompt(n, seed=i), 12)
+    steps = 0
+    while not eng.idle:
+        eng.step()
+        steps += 1
+    eng.step()                      # an idle poll leaves no root
+    observe.disable()
+    roots = _spans("engine_step")
+    assert len(roots) == steps >= 12
+    by_id = {s["id"]: s for s in _spans()}
+    covered = total = 0.0
+    for root in roots:
+        assert set(root["args"]) == {"step", "decoding", "queued"}
+        mine = [s for s in _spans() if s["name"] in LEAVES
+                and (s["parent"] == root["id"]
+                     or by_id.get(s["parent"], {}).get("parent") == root["id"])]
+        assert mine, root
+        end = root["ts_us"]
+        for s in sorted(mine, key=lambda s: s["ts_us"]):
+            assert s["ts_us"] >= end - 0.5, (s["name"], "overlaps")
+            end = s["ts_us"] + s["dur_us"]
+        assert end <= root["ts_us"] + root["dur_us"] + 0.5
+        covered += sum(s["dur_us"] for s in mine)
+        total += root["dur_us"]
+        names = Counter(s["name"] for s in mine)
+        if root["args"]["decoding"]:
+            assert names["decode_enqueue"] == names["decode_wait"] == 1
+    assert covered / total >= 0.95, covered / total
+    # decode_dispatch stays, as the parent of its two parts
+    for d in _spans("decode_dispatch"):
+        kids = [s["name"] for s in _spans() if s["parent"] == d["id"]]
+        assert kids == ["decode_enqueue", "decode_wait"]
+    # the bound decode program has no guard: its dispatch hangs under
+    # decode_enqueue directly
+    for s in _spans("step:serving_decode"):
+        assert by_id[s["parent"]]["name"] == "decode_enqueue"
+    # every span of an iteration carries the step; every request's, its id
+    for s in _spans():
+        if s["name"] in LEAVES or s["name"] == "decode_dispatch":
+            assert "step" in s["args"], s["name"]
+        if s["cat"] == "serving:request" or s["name"].startswith("prefill_"):
+            assert "request" in s["args"], s["name"]
+
+
+def test_registry_off_ring_gets_what_it_got_before(model):
+    """The sub-phase spans are registry-only: with the registry off, a fixed
+    scenario leaves in the flight ring exactly the records it left before
+    this tree existed (pinned on the parent commit), and the registry
+    nothing."""
+    eng = _engine(model)
+    _warm(eng)
+    flight.clear()
+    eng.submit(_prompt(9), 4)
+    eng.submit(_prompt(40), 3)      # two chunks: 32 + 16
+    steps = 0
+    while not eng.idle:
+        eng.step()
+        steps += 1
+    eng.step()
+    eng.step()                      # idle polls add nothing
+    assert steps == 5
+    recs = flight.snapshot()
+    got = Counter((r["type"], r.get("kind") or r["name"].split(" ")[0])
+                  for r in recs)
+    assert got == Counter({
+        ("event", "serving_submitted"): 2, ("event", "serving_admitted"): 2,
+        ("event", "serving_prefill_chunk"): 3,
+        ("event", "serving_first_token"): 2, ("event", "serving_complete"): 2,
+        ("gauge", "serving.queue_depth"): 7,
+        ("gauge", "serving.active_requests"): 7,
+        ("gauge", "serving.kv_pages_free"): 7,
+        ("gauge", "serving.slo_attainment"): 7,
+        ("span", "schedule"): 5, ("span", "decode_dispatch"): 4,
+        ("span", "prefill_chunk"): 3, ("span", "queued"): 2,
+        ("span", "prefill"): 2, ("span", "decode"): 2, ("span", "request"): 2,
+    }), got
+    assert len(recs) == 59
+    assert not observe.get_registry().spans
+    assert not observe.get_registry().events
+
+
+# ---------------------------------------------------------------------------
+# a request's road to its first token
+# ---------------------------------------------------------------------------
+
+def _phases_reader():
+    """The benchmark's own reader of the phases: the test holds the program
+    and the reader to each other."""
+    path = os.path.join(ROOT, "benchmark", "readers", "request_phases.py")
+    spec = importlib.util.spec_from_file_location("bench_request_phases", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _check_phases_add_up(reqs):
+    rp = _phases_reader()
+    r = observe.get_registry()
+    got = rp.phases(list(r.spans), list(r.events))
+    ttft = {e["request"]: e["ttft_ms"] for e in r.events
+            if e["kind"] == "serving_first_token"}
+    assert set(got) == {q.request_id for q in reqs} == set(ttft)
+    for q in reqs:
+        p = got[q.request_id]
+        assert set(p) == set(rp.PHASES)
+        assert all(v >= 0 for v in p.values()), p
+        assert abs(sum(p.values()) - ttft[q.request_id]) < 1.0, (p, ttft)
+        assert abs(ttft[q.request_id] - q.ttft_s * 1e3) < 1e-2
+    return got
+
+
+def test_ttft_phases_add_up_for_every_request(model):
+    """More requests than slots, one of them chunked: for every completed
+    request the four phases are non-negative and add up to its
+    ``serving.ttft_ms`` sample within 1 ms."""
+    eng = _engine(model)
+    _warm(eng)
+    observe.enable(clear=True)
+    reqs = [eng.submit(_prompt(n, seed=n), 5) for n in (9, 40, 20, 5, 33)]
+    eng.drain()
+    observe.disable()
+    assert all(q.done for q in reqs)
+    got = _check_phases_add_up(reqs)
+    # the two that found no free slot waited in the queue for one
+    queue = [got[q.request_id]["queue"] for q in reqs]
+    assert min(queue[3:]) > max(queue[:3])
+
+
+def test_ttft_phases_of_a_preempted_request(model):
+    """A pool too small for full residency preempts: a preempted request
+    keeps one ``request`` id, and its phases still add up — time before its
+    last admission counts as ``queue``, the rest runs from that admission."""
+    eng = _engine(model, num_pages=6)
+    observe.enable(clear=True)
+    reqs = [eng.submit(_prompt(n, seed=n), 30) for n in (15, 14, 13)]
+    eng.drain()
+    observe.disable()
+    assert all(q.done for q in reqs) and any(q.preemptions for q in reqs)
+    _check_phases_add_up(reqs)
+    r = observe.get_registry()
+    firsts = Counter(e["request"] for e in r.events
+                     if e["kind"] == "serving_first_token")
+    assert set(firsts.values()) == {1}
+    victim = next(q for q in reqs if q.preemptions)
+    queued = [s for s in r.spans if s["name"] == "queued"
+              and s["args"]["request"] == victim.request_id]
+    assert len(queued) == 1 + victim.preemptions
+
+
+def test_first_token_event_carries_the_resident_instant(model):
+    eng = _engine(model)
+    _warm(eng)
+    observe.enable(clear=True)
+    q = eng.submit(_prompt(9), 2)
+    eng.drain()
+    (e,) = [e for e in observe.get_registry().events
+            if e["kind"] == "serving_first_token"]
+    prefill = next(s for s in _spans("prefill")
+                   if s["args"]["request"] == q.request_id)
+    assert e["request"] == q.request_id
+    assert e["resident_us"] == pytest.approx(
+        prefill["ts_us"] + prefill["dur_us"], abs=50)
+    assert e["resident_us"] < e["ts_us"]
+
+
+def test_prefill_ms_is_the_chunk_spans_length(model):
+    eng = _engine(model)
+    _warm(eng)
+    observe.enable(clear=True)
+    eng.submit(_prompt(40), 2)
+    eng.drain()
+    h = observe.snapshot()["histograms"]["serving.prefill_ms"]
+    chunks = _spans("prefill_chunk")
+    assert h["count"] == len(chunks) == 2
+    assert h["sum"] == pytest.approx(sum(s["dur_us"] for s in chunks) / 1e3)
+
+
+# ---------------------------------------------------------------------------
+# the tt.jit call
+# ---------------------------------------------------------------------------
+
+def _misses():
+    return [e for e in observe.get_registry().events if e["kind"] == "cache_miss"]
+
+
+def test_jit_call_holds_guard_and_dispatch_on_a_hit_only():
+    def scale(a):
+        return ops.mul(a, 3.0).sum()
+
+    jf = tt.jit(scale)
+    x = np.ones((8, 8), np.float32)
+    observe.enable(clear=True)
+    with observe.span("caller", "test"):
+        jf(x)                                   # a miss: compile spans
+    kids = lambda s: [k["name"] for k in _spans() if k["parent"] == s["id"]]
+    call = _one("jit_call")
+    assert call["parent"] == _one("caller")["id"]
+    assert call["args"] == {"fn": "scale"}
+    assert "jit_guard" not in kids(call)
+    assert {"compile", "step:scale"} <= set(kids(call))
+    observe.reset()
+    jf(x)                                       # a hit: guard, dispatch
+    call = _one("jit_call")
+    assert kids(call) == ["jit_guard", "step:scale"]
+    guard, step = _one("jit_guard"), _one("step:scale")
+    assert guard["ts_us"] + guard["dur_us"] <= step["ts_us"] + 0.5
+    assert call["dur_us"] >= guard["dur_us"] + step["dur_us"] - 0.5
+
+
+def test_jit_call_spans_are_registry_only():
+    jf = tt.jit(lambda a: ops.mul(a, 2.0))
+    x = np.ones((4,), np.float32)
+    jf(x)
+    flight.clear()
+    jf(x)                                       # registry off: nothing at all
+    assert flight.snapshot() == [] and not _spans()
+    observe.enable(clear=True)
+    jf(x)
+    names = {r.get("name") for r in flight.snapshot()}
+    assert "jit_call" not in names and "jit_guard" not in names
+    assert _spans("jit_call") and _spans("jit_guard")
+
+
+@pytest.mark.parametrize("change, reason", [
+    (lambda: (np.ones((2, 8), np.float32), 1.0), "leaf 0 shape (2, 4) -> (2, 8)"),
+    (lambda: (np.ones((2, 4), np.int32), 1.0), "leaf 0 dtype float32 -> int32"),
+    (lambda: (np.ones((2, 4), np.float32), 2.0), "leaf 1 value"),
+    (lambda: (np.ones((2, 4), np.float32), True), "leaf 1 kind N -> B"),
+    (lambda: (np.ones((2, 8), np.int32), 1.0), "leaf 0 shape (2, 4) -> (2, 8)"),
+    (lambda: ([np.ones((2, 4), np.float32)], 1.0), "treedef"),
+])
+def test_cache_miss_names_what_moved(change, reason):
+    jf = tt.jit(lambda a, k: ops.mul(a[0] if isinstance(a, list) else a, k))
+    observe.enable(clear=True)
+    jf(np.ones((2, 4), np.float32), 1.0)
+    assert [e["reason"] for e in _misses()] == ["first"]
+    jf(np.ones((2, 4), np.float32), 1.0)        # a hit: no event
+    assert len(_misses()) == 1
+    jf(*change())
+    assert _misses()[-1]["reason"] == reason and _misses()[-1]["fn"] == "<lambda>"
+
+
+def test_cache_miss_reason_is_against_the_nearest_entry():
+    """Two entries held; the call differs from one of them in one leaf and
+    from the other in two: the reason names the single difference."""
+    jf = tt.jit(lambda a, b: ops.add(a, b))
+    f32 = lambda *shape: np.ones(shape, np.float32)
+    observe.enable(clear=True)
+    jf(f32(2, 4), f32(2, 4))
+    jf(f32(8, 4), f32(8, 4))
+    jf(f32(8, 4), f32(1, 4))
+    assert [e["reason"] for e in _misses()] == [
+        "first", "leaf 0 shape (2, 4) -> (8, 4) (+1 more)",
+        "leaf 1 shape (8, 4) -> (1, 4)"]
+
+
+def test_cache_miss_names_the_quarantine_epoch():
+    from thunder_tpu.runtime import quarantine
+
+    jf = tt.jit(lambda a: ops.mul(a, 2.0))
+    x = np.ones((4,), np.float32)
+    observe.enable(clear=True)
+    jf(x)
+    q = quarantine.get_quarantine()
+    q.add("test.span_tree_claim", reason="test", phase="compile")
+    try:
+        jf(x)
+    finally:
+        q.remove("test.span_tree_claim")
+    assert [e["reason"] for e in _misses()] == ["first", "quarantine epoch"]

@@ -255,6 +255,40 @@ def _leaf_key(leaf):
     return ("O", type(leaf).__name__)
 
 
+_KEY_PARTS = ("treedef", "frontend specialization", "quarantine epoch",
+              "bisection suppression", "fault plan")
+
+
+def _miss_reason(key, cache: dict) -> str:
+    """Why a call missed the guard cache: which component of its key differs
+    from the NEAREST entry the cache holds (the one that agrees with it in
+    most components). Runs on a miss only, with a compile to follow."""
+    if key is None:
+        return "no caching"
+    if not cache:
+        return "first"
+
+    def differences(held):
+        diffs = [part for part, a, b in zip(_KEY_PARTS, key, held) if a != b]
+        mine, theirs = key[-1], held[-1]
+        if "treedef" in diffs or len(mine) != len(theirs):
+            return diffs or ["treedef"]     # leaves of two trees don't pair
+        for i, (a, b) in enumerate(zip(mine, theirs)):
+            if a == b:
+                continue
+            if a[0] == b[0] == "T":
+                what = f"shape {b[1]} -> {a[1]}" if a[1] != b[1] \
+                    else f"dtype {b[2]} -> {a[2]}"
+            else:
+                what = "value" if a[0] == b[0] else f"kind {b[0]} -> {a[0]}"
+            diffs.append(f"leaf {i} {what}")
+        return diffs
+
+    nearest = min((differences(held) for held in cache), key=len)
+    return nearest[0] if len(nearest) == 1 \
+        else f"{nearest[0]} (+{len(nearest) - 1} more)"
+
+
 class ThunderTPUFunction:
     """The compiled-function wrapper returned by ``thunder_tpu.jit``."""
 
@@ -277,6 +311,7 @@ class ThunderTPUFunction:
         self.enable_cse = enable_cse
         self.insert_dels = insert_dels
         self.fn_name = fn_name or getattr(fn, "__name__", "fn")
+        self._span_args = {"fn": self.fn_name}  # shared by every jit_* span
         self._cache: dict = {}
         self._stats = CompileStats()
         self._stats.fn_name = self.fn_name
@@ -380,30 +415,38 @@ class ThunderTPUFunction:
     def _entry_for(self, args, kwargs):
         """Single cache-lookup/compile path shared by __call__ and the
         compile-only entry point. Returns (entry, flat_inputs)."""
-        if self.seq_buckets is not None:
-            args, kwargs = self._pad_to_bucket(args, kwargs)
-        flat, treedef = tree_flatten((args, kwargs))
-        # the quarantine epoch joins the key (entries compiled before a
-        # kernel was quarantined embed that kernel and must never hit
-        # again), as does the context's bisection-suppression set (a probe
-        # entry only serves calls under that same probe configuration), and
-        # — only for plans with trace-time numerics:kernel specs — the
-        # active FaultPlan's identity (that corruption is baked into the
-        # executable, and must never serve after the plan is cleared;
-        # grads/loss poison rides runtime inputs, so ordinary plans and the
-        # production no-plan path add nothing to the key)
-        plan = _faults.active_plan()
-        plan_key = id(plan) if plan is not None and plan.affects_compile() \
-            else None
-        key = (treedef, self._extra_cache_key, _quarantine.epoch(),
-               _quarantine.suppression_key(), plan_key,
-               tuple(self._leaf_cache_key(l) for l in flat)) \
-            if self.cache_option != "no caching" else None
-        entry = self._cache.get(key) if key is not None else None
+        # the guard (pad, flatten, a key per leaf, lookup) as a span of its
+        # own on a hit; a miss leaves the compile spans in its place
+        with _observe.span("jit_guard", "step", self._span_args,
+                           record_pass_time=False, ring=False) as guard:
+            if self.seq_buckets is not None:
+                args, kwargs = self._pad_to_bucket(args, kwargs)
+            flat, treedef = tree_flatten((args, kwargs))
+            # the quarantine epoch joins the key (entries compiled before a
+            # kernel was quarantined embed that kernel and must never hit
+            # again), as does the context's bisection-suppression set (a
+            # probe entry only serves calls under that same probe
+            # configuration), and — only for plans with trace-time
+            # numerics:kernel specs — the active FaultPlan's identity (that
+            # corruption is baked into the executable, and must never serve
+            # after the plan is cleared; grads/loss poison rides runtime
+            # inputs, so ordinary plans and the production no-plan path add
+            # nothing to the key)
+            plan = _faults.active_plan()
+            plan_key = id(plan) if plan is not None and plan.affects_compile() \
+                else None
+            key = (treedef, self._extra_cache_key, _quarantine.epoch(),
+                   _quarantine.suppression_key(), plan_key,
+                   tuple(self._leaf_cache_key(l) for l in flat)) \
+                if self.cache_option != "no caching" else None
+            entry = self._cache.get(key) if key is not None else None
+            if entry is None:
+                guard.cancel()
         if entry is None:
             self._stats.cache_misses += 1
             _observe.inc("cache.misses")
-            _observe.event("cache_miss", fn=self.fn_name)
+            _observe.event("cache_miss", fn=self.fn_name,
+                           reason=_miss_reason(key, self._cache))
             entry = self._compile(flat, treedef, args, kwargs)
             if key is not None:
                 self._cache[key] = entry
@@ -420,11 +463,15 @@ class ThunderTPUFunction:
         return entry
 
     def __call__(self, *args, **kwargs):
-        entry, flat = self._entry_for(args, kwargs)
-        inps = [flat[i] for i in entry.tensor_indices]
-        if entry.uses_rng:
-            inps.append(_next_rng_key())
-        return self._run_contained(entry.run_fn, inps, args, kwargs)
+        # one call as a span (registry on): the guard and the dispatch
+        # (``step:<fn>``) nest under it, and it under the caller's span
+        with _observe.span("jit_call", "step", self._span_args,
+                           record_pass_time=False, ring=False):
+            entry, flat = self._entry_for(args, kwargs)
+            inps = [flat[i] for i in entry.tensor_indices]
+            if entry.uses_rng:
+                inps.append(_next_rng_key())
+            return self._run_contained(entry.run_fn, inps, args, kwargs)
 
     def _run_contained(self, run_fn, inps, args, kwargs):
         """Run a compiled entry with the two containment paths: inside

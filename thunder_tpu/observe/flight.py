@@ -31,8 +31,10 @@ Record shapes (all carry ``type`` and ``ts_us``):
   timestamps (the registry only keeps the latest gauge value; the ring
   keeps the recent time series, which is what the Perfetto counter tracks
   render).
-- ``{"type": "span", "name", "cat", "dur_us", "tid", "args"}`` — span
-  edges (request lifecycle phases, scheduler iterations, dispatches).
+- ``{"type": "span", "name", "cat", "dur_us", "tid", "id", "parent",
+  "args"}`` — span edges (request lifecycle phases, scheduler iterations,
+  dispatches). ``parent`` is the ``id`` of the span open on that thread
+  when this one began; the registry-only sub-phase spans are not here.
 
 Records emitted through a scoped ``observe.labeled(engine="e0")`` handle
 additionally carry ``"labels": {"engine": "e0"}`` — the exporters group

@@ -3,10 +3,10 @@
 The instrumentation contract for the whole compiler/runtime stack:
 
 - **Near-zero cost when disabled.** Every recording entry point checks one
-  module-level boolean first and returns immediately; ``span()`` hands back
-  a shared no-op context manager (no allocation, no clock read). The hot
-  paths (``CacheEntry.run_fn`` per step, ``claim_bsym`` per op per compile)
-  pay a single predictable branch.
+  module-level boolean first and returns immediately; a ``ring=False``
+  ``span()`` hands back a shared no-op context manager (no allocation, no
+  clock read). The hot paths (``CacheEntry.run_fn`` per step, ``claim_bsym``
+  per op per compile) pay a single predictable branch.
 - **Thread-safe when enabled.** Mutations take one lock; ``snapshot()``
   returns plain-dict copies so exporters never race recorders.
 - **Bounded.** Events and spans live in deques with a max length — a
@@ -19,7 +19,17 @@ The instrumentation contract for the whole compiler/runtime stack:
   samples stay out of the ring: ``inc`` is the per-call hot path, every
   counter-worthy incident also emits an event, and a histogram sample
   duplicates an edge the ring already holds as a span or event (the
-  aggregate lives in the registry).
+  aggregate lives in the registry). A span opened with ``ring=False`` (the
+  sub-phases of a serving iteration and of a ``tt.jit`` call) is the one
+  exception: it exists only while the registry is enabled, and is the
+  shared no-op otherwise, so a hot loop cannot push the last incident's
+  history out of the ring.
+- **One tree.** Every span record carries ``id`` (process-unique) and
+  ``parent`` (the ``id`` of the span open on the recording thread when this
+  one began, else ``None``), whichever way it was recorded. While the
+  registry is enabled a ``span()`` also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so a profiler trace taken by
+  an operator shows the program's spans over the device ops.
 
 Metric names are dotted (``cache.hits``, ``fusion.horizontal_merges``,
 ``step.walltime_ms``); exporters map them to their own conventions
@@ -38,8 +48,8 @@ the flight ring survives either, labels and all.
 
 from __future__ import annotations
 
+import itertools
 import threading
-import time
 from collections import deque
 from contextlib import contextmanager
 from contextvars import ContextVar
@@ -190,17 +200,48 @@ def event(kind: str, **fields: Any) -> None:
         _registry.events.append(rec)
 
 
-def record_span(name: str, cat: str, ts_us: float, dur_us: float,
-                args: dict | None = None) -> None:
+# span identity: ``id`` is process-unique (``next`` on a count is atomic);
+# the spans open on a thread, innermost last, give every record its parent
+_span_ids = itertools.count(1)
+_open_spans = threading.local()
+
+
+def _open_stack() -> list:
+    try:
+        return _open_spans.stack
+    except AttributeError:
+        stack = _open_spans.stack = []
+        return stack
+
+
+def _write_span(name, cat, ts_us, dur_us, args, labels, ring, sid, parent):
     rec = {"name": name, "cat": cat, "ts_us": ts_us, "dur_us": dur_us,
-           "tid": threading.get_ident(), "args": args or {}}
-    _flight.append({"type": "span", **rec})
+           "tid": threading.get_ident(), "id": sid, "parent": parent,
+           "args": args or {}}
+    if labels is not None:
+        rec["labels"] = dict(labels)
+    if ring:
+        _flight.append({"type": "span", **rec})
     # gate like every other write path (this wrote to the registry
     # unconditionally before — a disabled process accumulated spans)
     if not _enabled:
         return
     with _registry._lock:
         _registry.spans.append(rec)
+
+
+def _record_span(name, cat, ts_us, dur_us, args, labels) -> None:
+    # a span handed over with its timestamps began in the past: its parent
+    # is the innermost span of this thread that was open then and still is
+    parent = next((sid for sid, t0 in reversed(_open_stack())
+                   if t0 <= ts_us), None)
+    _write_span(name, cat, ts_us, dur_us, args, labels, True,
+                next(_span_ids), parent)
+
+
+def record_span(name: str, cat: str, ts_us: float, dur_us: float,
+                args: dict | None = None) -> None:
+    _record_span(name, cat, ts_us, dur_us, args, None)
 
 
 # ---------------------------------------------------------------------------
@@ -281,17 +322,15 @@ class Labeled:
 
     def record_span(self, name: str, cat: str, ts_us: float, dur_us: float,
                     args: dict | None = None) -> None:
-        rec = {"name": name, "cat": cat, "ts_us": ts_us, "dur_us": dur_us,
-               "tid": threading.get_ident(), "labels": dict(self._dict),
-               "args": args or {}}
-        _flight.append({"type": "span", **rec})
-        if not _enabled:
-            return
-        with _registry._lock:
-            _registry.spans.append(rec)
+        _record_span(name, cat, ts_us, dur_us, args, self._dict)
 
-    def span(self, name: str, cat: str = "serving", args: dict | None = None):
-        return _SpanCM(name, cat, args, None, rec=self)
+    def span(self, name: str, cat: str = "serving", args: dict | None = None,
+             *, ring: bool = True, histogram: str | None = None):
+        """:func:`span` under this handle's labels (no pass-time sink: the
+        labeled spans are runtime spans)."""
+        if not (ring or _enabled):
+            return _NO_SPAN
+        return _SpanCM(name, cat, args, None, self, ring, histogram)
 
     def snapshot(self) -> dict:
         """This label set's series only, keyed by bare metric name — the
@@ -355,47 +394,97 @@ def collect_pass_times(sink: dict):
         _pass_sink.reset(tok)
 
 
-class _SpanCM:
-    __slots__ = ("name", "cat", "args", "sink", "rec",
-                 "_t0", "_ts", "_key", "_tok")
+_TraceAnnotation = None     # jax.profiler's, imported at the first enabled span
 
-    def __init__(self, name, cat, args, sink, rec=None):
+
+class _SpanCM:
+    """One open span. Until the block ends ``args`` may be set and
+    ``cancel()`` called (``live`` says whether a record will be left);
+    ``dur_us`` is readable after it."""
+
+    __slots__ = ("name", "cat", "args", "sink", "rec", "ring", "histogram",
+                 "live", "id", "parent", "dur_us", "_ts", "_key", "_tok",
+                 "_ann")
+
+    def __init__(self, name, cat, args, sink, rec, ring, histogram):
         self.name = name
         self.cat = cat
         self.args = args
         self.sink = sink
         self.rec = rec  # a Labeled handle, or None for the module path
+        self.ring = ring
+        self.histogram = histogram
+        self.live = True
+
+    def cancel(self) -> None:
+        """Leave no record: the block turned out to have had nothing to do."""
+        self.live = False
 
     def __enter__(self):
+        global _TraceAnnotation
         if self.sink is not None:
             path = _span_path.get() + (self.name,)
             self._key = "/".join(path)
             self._tok = _span_path.set(path)
+        stack = _open_stack()
+        self.parent = stack[-1][0] if stack else None
+        self.id = next(_span_ids)
+        self._ann = None
+        if _enabled:
+            if _TraceAnnotation is None:
+                from jax.profiler import TraceAnnotation as _TraceAnnotation
+            self._ann = _TraceAnnotation(self.name)
+            self._ann.__enter__()
         self._ts = _now_us()
-        self._t0 = time.perf_counter_ns()
+        stack.append((self.id, self._ts))
         return self
 
     def __exit__(self, *exc):
-        dur_ns = time.perf_counter_ns() - self._t0
+        self.dur_us = dur_us = _now_us() - self._ts
+        _open_stack().pop()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         if self.sink is not None:
             _span_path.reset(self._tok)
-            self.sink[self._key] = self.sink.get(self._key, 0.0) + dur_ns / 1e6
-        # record_span is itself always-on (flight ring) and gates the
-        # registry write; the derived histogram sample is registry-only
-        # (observe_value doesn't ring-append — the ring already holds the
-        # span edge with its duration)
+            self.sink[self._key] = self.sink.get(self._key, 0.0) + dur_us / 1e3
+        if not self.live:
+            return False
         r = self.rec
-        if r is None:
-            record_span(self.name, self.cat, self._ts, dur_ns / 1e3, self.args)
-            observe_value(f"{self.cat}.{self.name}.ms", dur_ns / 1e6)
-        else:
-            r.record_span(self.name, self.cat, self._ts, dur_ns / 1e3, self.args)
-            r.observe_value(f"{self.cat}.{self.name}.ms", dur_ns / 1e6)
+        _write_span(self.name, self.cat, self._ts, dur_us, self.args,
+                    None if r is None else r._dict, self.ring, self.id,
+                    self.parent)
+        if self.histogram is not None:
+            # registry-only, like every histogram sample: the ring already
+            # holds the span edge with its duration
+            (observe_value if r is None else r.observe_value)(
+                self.histogram, dur_us / 1e3)
         return False
 
 
+class _NoSpan:
+    """What a ``ring=False`` span is while the registry is off: shared, no
+    clock read, no allocation, no record."""
+
+    __slots__ = ()
+    live = False
+    dur_us = 0.0
+
+    def cancel(self) -> None:
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
 def span(name: str, cat: str = "compile", args: dict | None = None,
-         record_pass_time: bool = True):
+         record_pass_time: bool = True, *, ring: bool = True,
+         histogram: str | None = None):
     """Timed span context manager. Records into the per-compile pass-time
     sink when one is active (always, during compilation; nested spans key
     as ``parent/child``), into the process registry when enabled, and into
@@ -403,9 +492,15 @@ def span(name: str, cat: str = "compile", args: dict | None = None,
     history, and span sites are compile-time paths where one deque append
     is noise. ``record_pass_time=False`` keeps a span out of the sink (the
     whole-compile umbrella span, which would otherwise parent — and
-    double-count against — every pass)."""
+    double-count against — every pass).
+
+    ``ring=False`` is for the sub-phases of a hot loop: the span exists only
+    in the registry, and while the registry is off it is a shared no-op.
+    ``histogram`` names the histogram that also takes the duration, in ms."""
+    if not (ring or _enabled):
+        return _NO_SPAN
     sink = _pass_sink.get() if record_pass_time else None
-    return _SpanCM(name, cat, args, sink)
+    return _SpanCM(name, cat, args, sink, None, ring, histogram)
 
 
 # ---------------------------------------------------------------------------

@@ -13,16 +13,19 @@ enabled it records:
   XLA compilation inside ``run_fn``; it is recorded separately as
   ``step.first_call_ms`` (and its span carries ``first_call: True``) so the
   walltime histogram reflects steady-state steps, not compiles.
-- a ``step`` span per call (Perfetto/chrome exporter material).
-- ``step.est_live_bytes`` — the trace-liveness peak-memory estimate
-  (``examine.estimate_memory``), computed once per entry, lazily.
-- ``step.collective_bytes`` — local collective payload of one step
-  (``examine.comm_report`` total in+out), computed once per entry, lazily.
+- a ``step:<fn>`` span per call (Perfetto/chrome exporter material). Its
+  parent is the span open around the call: ``jit_call`` on the guarded
+  path, the caller's own span (the serving engine's ``decode_enqueue``) on
+  the ``bind()`` path.
+
+What is static per entry is recorded ONCE, when an entry is compiled with
+the registry enabled, never per step: ``step.est_live_bytes`` (the
+trace-liveness peak-memory estimate, ``examine.estimate_memory``) and
+``step.collective_bytes`` (local collective payload of one step,
+``examine.comm_report`` total in+out).
 """
 
 from __future__ import annotations
-
-import time
 
 from thunder_tpu.observe import registry as _registry
 
@@ -34,10 +37,19 @@ def set_sync_steps(value: bool) -> None:
     _sync_steps = bool(value)
 
 
+def _publish_static_estimates(exec_trc) -> None:
+    from thunder_tpu.examine import comm_report, estimate_memory
+
+    _registry.set_gauge("step.est_live_bytes",
+                        estimate_memory(exec_trc)["peak_bytes"])
+    comm = comm_report(exec_trc)
+    _registry.set_gauge("step.collective_bytes",
+                        comm["total_in_bytes"] + comm["total_out_bytes"])
+
+
 def instrument_entry(entry, fn_name: str):
-    """Wrap ``entry.run_fn``; returns the wrapped callable. Static per-entry
-    estimates are computed lazily on the first *enabled* step so disabled
-    runs never pay for them."""
+    """Wrap ``entry.run_fn``; returns the wrapped callable. Called once per
+    entry, at compile time."""
     import itertools
 
     # the run_fn wrapper is the per-step chokepoint, so it also hosts the
@@ -46,28 +58,12 @@ def instrument_entry(entry, fn_name: str):
     from thunder_tpu.runtime import faults as _faults
 
     inner = entry.run_fn
-    exec_trc = entry.traces[-1] if entry.traces else None
-    estimates: dict | None = None
+    if _registry.is_enabled() and entry.traces:
+        _publish_static_estimates(entry.traces[-1])
+    span_name = f"step:{fn_name}"
     call_counter = itertools.count(1)  # next() is atomic: concurrent callers
     # (serving threads) each draw a distinct number, so exactly one call is
     # classified as the compile-paying first call
-
-    def _estimates() -> dict:
-        nonlocal estimates
-        if estimates is None:
-            est: dict = {"live_bytes": 0, "collective_bytes": 0}
-            if exec_trc is not None:
-                try:
-                    from thunder_tpu.examine import comm_report, estimate_memory
-
-                    est["live_bytes"] = estimate_memory(exec_trc)["peak_bytes"]
-                    comm = comm_report(exec_trc)
-                    est["collective_bytes"] = (comm["total_in_bytes"]
-                                               + comm["total_out_bytes"])
-                except Exception:
-                    pass
-            estimates = est
-        return estimates
 
     def run(*inps):
         _faults.maybe_fail("dispatch", site=fn_name)
@@ -76,7 +72,6 @@ def instrument_entry(entry, fn_name: str):
             return inner(*inps)
         first_call = n_call == 1  # lazy XLA compile happens inside this call
         ts = _registry._now_us()
-        t0 = time.perf_counter_ns()
         out = inner(*inps)
         if _sync_steps:
             try:
@@ -85,19 +80,13 @@ def instrument_entry(entry, fn_name: str):
                 jax.block_until_ready(out)
             except Exception:
                 pass
-        ms = (time.perf_counter_ns() - t0) / 1e6
-        est = _estimates()
-        _registry.record_span(f"step:{fn_name}", "step", ts, ms * 1e3,
-                              {"est_live_bytes": est["live_bytes"],
-                               "collective_bytes": est["collective_bytes"],
-                               "first_call": first_call})
+        us = _registry._now_us() - ts
+        _registry.record_span(span_name, "step", ts, us,
+                              {"first_call": first_call})
         _registry.inc("step.count")
-        if first_call:
-            _registry.observe_value("step.first_call_ms", ms)
-        else:
-            _registry.observe_value("step.walltime_ms", ms)
-        _registry.set_gauge("step.est_live_bytes", est["live_bytes"])
-        _registry.set_gauge("step.collective_bytes", est["collective_bytes"])
+        _registry.observe_value(
+            "step.first_call_ms" if first_call else "step.walltime_ms",
+            us / 1e3)
         return out
 
     run.__wrapped__ = inner

@@ -50,6 +50,37 @@ different sequence lengths served by ONE compiled decode program.
   leaves a black box. Each iteration also records scheduler spans
   (``schedule`` host work vs ``decode_dispatch``); the Perfetto exporter
   renders per-request tracks, a scheduler track, and counter tracks.
+- **Spans** (every record carries ``id`` and ``parent``; spans of one
+  iteration carry ``step``, spans of one request ``request``). With the
+  registry enabled, a busy iteration is one tree whose leaves do not
+  overlap and together cover it::
+
+      engine_step                 root; args step, decoding, queued
+        schedule                  deadlines, forks, admission
+        decode_build              page growth, the batch arrays
+        decode_dispatch
+          decode_enqueue          the call into the bound program until it
+                                  returns (``step:serving_decode`` inside)
+          decode_wait             the device wait and the token fetch
+        decode_deliver            tokens to requests, finishes, page release
+        prefill_build             one chunk's arrays
+        prefill_chunk             the chunk's dispatch (the device runs it
+                                  behind the call: the next ``decode_wait``
+                                  holds that time)
+        prefill_deliver           the request's progress, its move to decode
+                                  once resident, the admission that may free
+
+  ``schedule``, ``decode_dispatch`` and ``prefill_chunk`` feed the flight
+  ring as well; the others exist only while the registry is enabled and
+  cost nothing otherwise. A request's road to its first token reads from
+  records that all close by then: ``queue`` = submit → the last admission
+  before the token (every ``queued`` span adds up, and so does a residency
+  that a preemption or a restart threw away: it gave the request nothing);
+  ``prefill_wait`` = that admission → the start of its first
+  ``prefill_chunk``; ``prefill`` = that start → the prompt resident
+  (``resident_us`` of the ``serving_first_token`` event; a forked clone is
+  resident when it forks); ``first_decode`` = resident → the event. The
+  four add up to ``serving.ttft_ms``.
 
 - **In-graph sampling**: every request carries
   :class:`~thunder_tpu.serving.sampling.SamplingParams`; the compiled
@@ -314,6 +345,7 @@ class ServingEngine:
         self.admitting = True           # stop_admissions() flips this
         self._admits = itertools.count()
         self._step_count = 0
+        self._step_args = {"step": 0}
         self._slo_attained = 0          # on-time completions
         self._slo_total = 0             # terminal requests (done + shed)
         self._slo_resets = 0            # reset_slo_window() generation
@@ -461,38 +493,49 @@ class ServingEngine:
         per decode step."""
         self._step_count += 1
         busy = bool(self.queue) or self.active_requests > 0
-        t0_us = _observe._now_us()
-        worked = self._expire_deadlines()
-        # pending best-of forks take slots before fresh admissions (they
-        # are older traffic, and forking is cheaper than a prefill)
-        for r in self.slots:
-            if r is not None and r.fork_pending:
-                worked = self._materialize_forks(r) or worked
-        worked = self._admit() or worked
-        if busy:
-            # host-scheduling half of the iteration (deadlines + admission);
-            # the dispatch halves record their own spans. Idle polling steps
-            # stay out of the flight ring — a long idle stretch must not
-            # flush the last incident's history out of the bounded ring.
-            self.obs.record_span("schedule", "serving:sched", t0_us,
-                                 _observe._now_us() - t0_us,
-                                 {"step": self._step_count})
-        worked = self._decode_step() or worked
-        decoding = sum(1 for r in self.slots
-                       if r is not None and r.state == DECODE)
-        budget = 1 if decoding > self.max_slots // 2 else self.max_slots
-        for _ in range(budget):
-            if not self._prefill_one():
-                break
-            worked = True
-            self._admit()  # a completed prefill may free queue back-pressure
-        if busy or worked:
-            # gauges are unchanged on a no-op idle step, and set_gauge
-            # feeds the always-on flight ring — publishing them anyway
-            # would let an idle polling loop flush the last incident's
-            # history out of the bounded ring (same rule as the schedule
-            # span above; every real transition path publishes its own)
-            self._gauges()
+        # one dict for every span of this iteration that carries the step only
+        self._step_args = {"step": self._step_count}
+        # the iteration as a span tree (module docstring, "Spans"): one root
+        # over leaves that do not overlap and together cover it
+        with self.obs.span("engine_step", "serving:sched", ring=False) as root:
+            # host-scheduling part of the iteration: deadlines, forks,
+            # admission
+            with self.obs.span("schedule", "serving:sched",
+                               self._step_args) as sched:
+                worked = self._expire_deadlines()
+                # pending best-of forks take slots before fresh admissions
+                # (they are older traffic, and forking is cheaper than a
+                # prefill)
+                for r in self.slots:
+                    if r is not None and r.fork_pending:
+                        worked = self._materialize_forks(r) or worked
+                worked = self._admit() or worked
+                if not busy:
+                    # idle polling steps stay out of the flight ring — a
+                    # long idle stretch must not flush the last incident's
+                    # history out of the bounded ring
+                    sched.cancel()
+            worked = self._decode_step() or worked
+            decoding = sum(1 for r in self.slots
+                           if r is not None and r.state == DECODE)
+            budget = 1 if decoding > self.max_slots // 2 else self.max_slots
+            for _ in range(budget):
+                if not self._prefill_one():
+                    break
+                worked = True
+            if busy or worked:
+                # gauges are unchanged on a no-op idle step, and set_gauge
+                # feeds the always-on flight ring — publishing them anyway
+                # would let an idle polling loop flush the last incident's
+                # history out of the bounded ring (same rule as the schedule
+                # span above; every real transition path publishes its own)
+                self._gauges()
+                if root.live:
+                    root.args = {"step": self._step_count,
+                                 "decoding": decoding,
+                                 "queued": len(self.queue)}
+            else:
+                root.cancel()
         return worked
 
     def drain(self, max_steps: int = 1_000_000) -> list[Request]:
@@ -882,65 +925,73 @@ class ServingEngine:
                   key=lambda r: r.admit_seq, default=None)
         if req is None:
             return False
-        g = self.geom
-        wp = req.work_prompt
-        remaining = len(wp) - req.prefilled
-        C = self._chunk_size(remaining)
-        pos0 = req.prefilled                        # chunk/page aligned
-        need_total = (pos0 + C) // g.page_size
-        if len(req.pages) < need_total and \
-                not self._grow_pages(req, need_total - len(req.pages)):
-            return False                            # preempted or must wait
-        real = min(remaining, C)
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :real] = wp[pos0:pos0 + real]
-        lengths = np.asarray([pos0 + C], np.int32)
-        first_page = pos0 // g.page_size
-        page_writes = np.asarray(
-            [req.pages[first_page + i] * g.page_size for i in range(C // g.page_size)],
-            np.int32)
+        # the two registry-only leaves around the chunk's dispatch share
+        # their args, built only while the registry is on
+        leaf_args = {"step": self._step_count, "request": req.request_id} \
+            if _observe.is_enabled() else None
+        with self.obs.span("prefill_build", "serving:sched", leaf_args,
+                           ring=False):
+            g = self.geom
+            wp = req.work_prompt
+            remaining = len(wp) - req.prefilled
+            C = self._chunk_size(remaining)
+            pos0 = req.prefilled                    # chunk/page aligned
+            need_total = (pos0 + C) // g.page_size
+            if len(req.pages) < need_total and \
+                    not self._grow_pages(req, need_total - len(req.pages)):
+                return False                        # preempted or must wait
+            real = min(remaining, C)
+            chunk = np.zeros((1, C), np.int32)
+            chunk[0, :real] = wp[pos0:pos0 + real]
+            lengths = np.asarray([pos0 + C], np.int32)
+            first_page = pos0 // g.page_size
+            page_writes = np.asarray(
+                [req.pages[first_page + i] * g.page_size
+                 for i in range(C // g.page_size)], np.int32)
+            block_table = self._block_table(req)[None]
 
         def dispatch():
             # the fault hook fires BEFORE the device dispatch, so a retried
             # injected fault re-runs on unconsumed inputs
             _faults.maybe_fail("serving:prefill", step=self._step_count)
             return self.runner.prefill_jit(
-                self.params, chunk, self._block_table(req)[None], lengths,
-                page_writes, self.cache.pools)
+                self.params, chunk, block_table, lengths, page_writes,
+                self.cache.pools)
 
-        t0 = time.perf_counter()
-        t0_us = _observe._now_us()
-        pools = self._dispatch_guarded(dispatch, "serving:prefill")
-        self.cache.update_pools(pools)
-        dur_us = _observe._now_us() - t0_us
-        self.obs.observe_value("serving.prefill_ms",
-                               (time.perf_counter() - t0) * 1e3)
-        # the chunk dispatch on the request's own lifecycle track
-        self.obs.record_span("prefill_chunk", "serving:request", t0_us, dur_us,
-                             {"request": req.request_id, "chunk": C,
-                              "pos0": pos0})
-        req.prefill_chunks += 1
-        self.obs.event("serving_prefill_chunk", request=req.request_id,
-                       chunk=C, pos0=pos0, real=real)
-        req.prefilled += real
-        if req.prefilled == len(wp):                # prompt fully resident
-            # no logits left prefill: the FIRST token comes from the next
-            # batched decode step as a REPLAY — re-feed the last prompt
-            # token (its K/V row already exists; the write goes to the
-            # scratch page) and sample in-graph on the same program path
-            # as every later token
-            req.length = len(wp)
-            req.next_token = int(wp[-1])
-            req._replay = True
-            req.state = DECODE
-            self._phase_end(req)                    # close "prefill"
-            self._phase_begin(req, DECODE)
-            if req.decode_start_s is None:          # survive preempt-resume:
-                # decode_ms stays first-token -> completion, as documented
-                req.decode_start_s = time.perf_counter()
-            if req.fork_pending:
-                # the prompt is resident: best-of clones can fork it now
-                self._materialize_forks(req)
+        # the chunk's dispatch on the request's own lifecycle track (the
+        # device runs the chunk behind it: the next ``decode_wait`` holds
+        # that time); per-chunk ``serving.prefill_ms`` is this span's length
+        with self.obs.span("prefill_chunk", "serving:request",
+                           {"request": req.request_id, "chunk": C,
+                            "pos0": pos0, "step": self._step_count},
+                           histogram="serving.prefill_ms"):
+            pools = self._dispatch_guarded(dispatch, "serving:prefill")
+            self.cache.update_pools(pools)
+        with self.obs.span("prefill_deliver", "serving:sched", leaf_args,
+                           ring=False):
+            req.prefill_chunks += 1
+            self.obs.event("serving_prefill_chunk", request=req.request_id,
+                           chunk=C, pos0=pos0, real=real)
+            req.prefilled += real
+            if req.prefilled == len(wp):            # prompt fully resident
+                # no logits left prefill: the FIRST token comes from the
+                # next batched decode step as a REPLAY — re-feed the last
+                # prompt token (its K/V row already exists; the write goes
+                # to the scratch page) and sample in-graph on the same
+                # program path as every later token
+                req.length = len(wp)
+                req.next_token = int(wp[-1])
+                req._replay = True
+                req.state = DECODE
+                self._phase_end(req)                # close "prefill"
+                self._phase_begin(req, DECODE)
+                if req.decode_start_s is None:      # survive preempt-resume:
+                    # decode_ms stays first-token -> completion, as documented
+                    req.decode_start_s = time.perf_counter()
+                if req.fork_pending:
+                    # the prompt is resident: best-of clones can fork it now
+                    self._materialize_forks(req)
+            self._admit()  # a completed prefill may free queue back-pressure
         return True
 
     def _grow_pages(self, req: Request, n: int) -> bool:
@@ -983,68 +1034,72 @@ class ServingEngine:
 
     def _decode_step(self) -> bool:
         """One batched decode step over every resident DECODE request."""
-        g = self.geom
-        # page capacity first (may preempt, changing the active set)
-        for req in list(self.slots):
-            if req is None or req.state != DECODE:
-                continue
-            # a replay row writes nothing (scratch page): it only needs its
-            # existing context pages, not the next append page yet
-            need = (-(-req.length // g.page_size) if req._replay
-                    else req.length // g.page_size + 1)
-            if len(req.pages) < need:
-                self._grow_pages(req, need - len(req.pages))
-        active = [(i, r) for i, r in enumerate(self.slots)
-                  if r is not None and r.state == DECODE]
-        if not active:
-            return False
-        tokens, bt = self._np_tokens, self._np_bt
-        lengths, write_pos = self._np_len, self._np_wp
-        temps, topk = self._np_temp, self._np_topk
-        topp, rng = self._np_topp, self._np_rng
-        for i in range(self.max_slots):
-            r = self.slots[i]
-            if r is None or r.state != DECODE:
-                # idle slots attend + scribble on the reserved page 0 only
-                # (their block-table row is zeroed when the slot is
-                # released, so the documented invariant holds exactly:
-                # idle slots never read a live request's pages); their
-                # sampling row is greedy on the zero key
-                tokens[i, 0] = 0
-                lengths[i] = 1
-                write_pos[i] = 0
-                temps[i] = 0.0
-                topk[i] = 0
-                topp[i] = 1.0
-                rng[i] = 0
-                if self._bt_slot_version[i] is not None:
-                    bt[i] = 0
-                    self._bt_slot_version[i] = None
-        for i, r in active:
-            tokens[i, 0] = r.next_token
-            key = (r.request_id, r.pages_version)
-            if self._bt_slot_version[i] != key:     # pages changed (rare)
-                bt[i, :len(r.pages)] = r.pages
-                bt[i, len(r.pages):] = 0
-                self._bt_slot_version[i] = key
-            if r._replay:
-                # first-token replay: the fed token's K/V row already
-                # exists at position length-1 (prefill wrote it, or the
-                # fork copied it), so the context length is unchanged and
-                # the recomputed row is discarded on the scratch page —
-                # shared COW pages are never written
-                lengths[i] = r.length
-                write_pos[i] = 0
-            else:
-                lengths[i] = r.length + 1
-                write_pos[i] = (r.pages[r.length // g.page_size] * g.page_size
-                                + r.length % g.page_size)
-            sp = r.sampling
-            temps[i] = sp.temperature
-            topk[i] = sp.top_k
-            topp[i] = sp.top_p
-            rng[i, 0] = r.stream_seed
-            rng[i, 1] = len(r.generated)    # counter: tokens sampled so far
+        with self.obs.span("decode_build", "serving:sched", self._step_args,
+                           ring=False) as build:
+            g = self.geom
+            # page capacity first (may preempt, changing the active set)
+            for req in list(self.slots):
+                if req is None or req.state != DECODE:
+                    continue
+                # a replay row writes nothing (scratch page): it only needs its
+                # existing context pages, not the next append page yet
+                need = (-(-req.length // g.page_size) if req._replay
+                        else req.length // g.page_size + 1)
+                if len(req.pages) < need:
+                    self._grow_pages(req, need - len(req.pages))
+            active = [(i, r) for i, r in enumerate(self.slots)
+                      if r is not None and r.state == DECODE]
+            if not active:
+                build.cancel()
+                return False
+            tokens, bt = self._np_tokens, self._np_bt
+            lengths, write_pos = self._np_len, self._np_wp
+            temps, topk = self._np_temp, self._np_topk
+            topp, rng = self._np_topp, self._np_rng
+            for i in range(self.max_slots):
+                r = self.slots[i]
+                if r is None or r.state != DECODE:
+                    # idle slots attend + scribble on the reserved page 0 only
+                    # (their block-table row is zeroed when the slot is
+                    # released, so the documented invariant holds exactly:
+                    # idle slots never read a live request's pages); their
+                    # sampling row is greedy on the zero key
+                    tokens[i, 0] = 0
+                    lengths[i] = 1
+                    write_pos[i] = 0
+                    temps[i] = 0.0
+                    topk[i] = 0
+                    topp[i] = 1.0
+                    rng[i] = 0
+                    if self._bt_slot_version[i] is not None:
+                        bt[i] = 0
+                        self._bt_slot_version[i] = None
+            for i, r in active:
+                tokens[i, 0] = r.next_token
+                key = (r.request_id, r.pages_version)
+                if self._bt_slot_version[i] != key:     # pages changed (rare)
+                    bt[i, :len(r.pages)] = r.pages
+                    bt[i, len(r.pages):] = 0
+                    self._bt_slot_version[i] = key
+                if r._replay:
+                    # first-token replay: the fed token's K/V row already
+                    # exists at position length-1 (prefill wrote it, or the
+                    # fork copied it), so the context length is unchanged and
+                    # the recomputed row is discarded on the scratch page —
+                    # shared COW pages are never written
+                    lengths[i] = r.length
+                    write_pos[i] = 0
+                else:
+                    lengths[i] = r.length + 1
+                    write_pos[i] = (
+                        r.pages[r.length // g.page_size] * g.page_size
+                        + r.length % g.page_size)
+                sp = r.sampling
+                temps[i] = sp.temperature
+                topk[i] = sp.top_k
+                topp[i] = sp.top_p
+                rng[i, 0] = r.stream_seed
+                rng[i, 1] = len(r.generated)    # counter: tokens sampled so far
 
         def dispatch():
             # injected faults fire BEFORE the device dispatch, so a retried
@@ -1090,25 +1145,32 @@ class ServingEngine:
                                       write_pos, self.cache.pools,
                                       temps, topk, topp, rng)
 
-        t0_us = _observe._now_us()
-        tok_ids, self.last_decode_logits, pools = \
-            self._dispatch_guarded(dispatch, "serving:decode")
-        self.cache.update_pools(pools)
-        # tokens were sampled IN-GRAPH; fetching the (S,) id vector is the
-        # host sync that makes the span below an honest device-step bound
-        # (the (S, V) logits output stays on device, unread — the handle is
-        # kept for parity checks: chip_smoke.py reads one slot's row)
-        toks = np.asarray(tok_ids)
-        # the dispatch half of the iteration, on the scheduler track
-        self.obs.record_span("decode_dispatch", "serving:sched", t0_us,
-                             _observe._now_us() - t0_us,
-                             {"step": self._step_count, "batch": len(active)})
-        for i, r in active:
-            if r._replay:
-                r._replay = False   # context length unchanged; row existed
-            else:
-                r.length += 1
-            self._on_token(r, int(toks[i]))
+        # the dispatch half of the iteration, on the scheduler track, and its
+        # two parts: the call into the bound program until it returns, and
+        # the wait for the device
+        with self.obs.span("decode_dispatch", "serving:sched",
+                           {"step": self._step_count, "batch": len(active)}):
+            with self.obs.span("decode_enqueue", "serving:sched",
+                               self._step_args, ring=False):
+                tok_ids, self.last_decode_logits, pools = \
+                    self._dispatch_guarded(dispatch, "serving:decode")
+                self.cache.update_pools(pools)
+            # tokens were sampled IN-GRAPH; fetching the (S,) id vector is
+            # the host sync that makes ``decode_wait`` an honest bound on
+            # the device's part of the step (the (S, V) logits output stays
+            # on device, unread — the handle is kept for parity checks:
+            # chip_smoke.py reads one slot's row)
+            with self.obs.span("decode_wait", "serving:sched",
+                               self._step_args, ring=False):
+                toks = np.asarray(tok_ids)
+        with self.obs.span("decode_deliver", "serving:sched",
+                           self._step_args, ring=False):
+            for i, r in active:
+                if r._replay:
+                    r._replay = False   # context length unchanged; row existed
+                else:
+                    r.length += 1
+                self._on_token(r, int(toks[i]))
         return True
 
     def _on_token(self, req: Request, tok: int) -> None:
@@ -1117,8 +1179,12 @@ class ServingEngine:
         if req.ttft_s is None:
             req.ttft_s = time.perf_counter() - req.submitted_s
             self.obs.observe_value("serving.ttft_ms", req.ttft_s * 1e3)
+            # the open lifecycle phase is "decode", begun when the prompt
+            # became resident (after the last admission): with it a reader
+            # has the request's road to this token from `request` alone
             self.obs.event("serving_first_token", request=req.request_id,
-                           ttft_ms=round(req.ttft_s * 1e3, 3))
+                           ttft_ms=round(req.ttft_s * 1e3, 3),
+                           resident_us=req._phase_t0_us)
         if (len(req.generated) >= req.max_new_tokens
                 or (req.eos_id is not None and tok == req.eos_id)):
             self._finish(req)
