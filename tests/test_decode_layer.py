@@ -256,6 +256,163 @@ def test_engine_tokens_identical_to_generate(gqa_model):
 
 
 # ---------------------------------------------------------------------------
+# the page walk: a request's live pages only, many to a block (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+_WALK = dict(D=32, hd=8, ps=4, npg=8, ppb=2)     # window 32 tokens, block 8
+
+
+def _walk_lengths():
+    ps, ppb, npg = _WALK["ps"], _WALK["ppb"], _WALK["npg"]
+    return [0, 1, ps - 1, ps, ps + 1, ppb * ps - 1, ppb * ps, ppb * ps + 1,
+            npg * ps]
+
+
+def _walk_case(H, KV, lengths, npg=_WALK["npg"], seed=0, dtype=np.float32):
+    """attn_subblock operands for ``lengths``: block tables whose page ids
+    are not in order, and TWO pools — ``clean`` (dead pages zero) for the
+    decomposition, ``poisoned`` (every page past a request's length NaN) for
+    the kernel, which must never read one into its result."""
+    D, hd, ps = _WALK["D"], _WALK["hd"], _WALK["ps"]
+    S = len(lengths)
+    P = S * npg + 1
+    rng = np.random.RandomState(seed)
+    r = lambda *s: (rng.randn(*s) * 0.3).astype(np.float32)
+    bt = rng.permutation(np.arange(1, P)).astype(np.int32).reshape(S, npg)
+    ln = np.asarray(lengths, np.int32)
+    wp = np.asarray([bt[b, (n - 1) // ps] * ps + (n - 1) % ps if n else 0
+                     for b, n in enumerate(ln)], np.int32)
+    kp, vp = r(KV, P, ps, hd), r(KV, P, ps, hd)
+    dead = np.zeros(P, bool)
+    for b, n in enumerate(ln):
+        dead[bt[b, -(-int(n) // ps):]] = True
+    clean = [np.where(dead[None, :, None, None], 0.0, x).astype(dtype)
+             for x in (kp, vp)]
+    poisoned = [np.where(dead[None, :, None, None], np.nan, x).astype(dtype)
+                for x in (kp, vp)]
+    head = tuple(a.astype(dtype) for a in (
+        r(S, 1, D), 1 + 0.1 * rng.randn(D), r(H * hd, D), r(KV * hd, D),
+        r(KV * hd, D), r(D, H * hd), r(S, 1, 1, hd // 2),
+        r(S, 1, 1, hd // 2)))
+    return head, clean, poisoned, (bt, ln, wp), dead
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """A walk block of ``_WALK['ppb']`` pages at the test's tiny page (the
+    block is sized in bytes: shrink the bytes, not an option)."""
+    def at(dtype):
+        monkeypatch.setattr(
+            cost_model, "DECODE_KV_BLOCK_BYTES",
+            _WALK["ppb"] * _WALK["ps"] * _WALK["hd"] * np.dtype(dtype).itemsize)
+    return at
+
+
+@pytest.mark.parametrize("length", _walk_lengths())
+@pytest.mark.parametrize("H,KV", [(4, 2), (2, 2)], ids=["gqa", "mha"])
+def test_page_walk_parity_vs_decomposition(H, KV, length, small_blocks):
+    """The walk against the XLA decomposition: the case length first and
+    last of three slots (an idle slot before a live one and after one, a
+    first block started by the slot itself and by the slot before), the
+    fresh row on the first and on the last row of a page, dead pages NaN."""
+    from thunder_tpu.executors import pallasex as px
+
+    small_blocks(np.float32)
+    head, clean, poisoned, tail, dead = _walk_case(
+        H, KV, [length, 2 * _WALK["ps"] + 3, length], seed=length)
+    assert cost_model.decode_pages_per_block(
+        _WALK["ps"], _WALK["hd"], 4, _WALK["npg"]) == _WALK["ppb"]
+    ref = tt.jit(lambda *a: tnn.attn_subblock(*a), executors=["xla"])(
+        *head, *clean, *tail)
+    out = px.pallas_attn_subblock(*(jnp.asarray(a) for a in
+                                    (*head, *poisoned, *tail)))
+    live = np.asarray(tail[1]) > 0
+    o, o_ref = np.asarray(out[0]), np.asarray(ref[0])
+    assert np.isfinite(o).all()
+    np.testing.assert_allclose(o[live], o_ref[live], atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(o[~live], 0.0)    # an idle slot attends nothing
+    for got, want in zip(out[1:], ref[1:]):         # the appended rows
+        np.testing.assert_allclose(np.asarray(got)[:, ~dead],
+                                   np.asarray(want)[:, ~dead],
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kernel", ["attn_subblock", "decode_layer",
+                                    "paged_decode_attention"])
+def test_page_walk_every_rung_bf16(kernel, small_blocks):
+    """The three kernels that share the walk, bf16 pools, several blocks a
+    request: each against its own decomposition."""
+    from thunder_tpu.executors import pallasex as px
+
+    bf16 = np.dtype(jnp.bfloat16)
+    small_blocks(bf16)
+    ps, npg = _WALK["ps"], _WALK["npg"]
+    head, clean, poisoned, tail, _ = _walk_case(
+        4, 2, [npg * ps, 1, 2 * ps + 1, 5 * ps], seed=7, dtype=bf16)
+    D, F = _WALK["D"], 48
+    rng = np.random.RandomState(8)
+    mlp = tuple((rng.randn(*s) * 0.2).astype(bf16)
+                for s in ((D,), (F, D), (F, D), (D, F)))
+    xla = lambda f: tt.jit(f, executors=["xla"])
+    dev = lambda xs: tuple(jnp.asarray(a) for a in xs)
+    if kernel == "attn_subblock":
+        ref = xla(lambda *a: tnn.attn_subblock(*a))(*head, *clean, *tail)[0]
+        out = px.pallas_attn_subblock(*dev((*head, *poisoned, *tail)))[0]
+    elif kernel == "decode_layer":
+        ref = xla(lambda *a: tnn.decode_layer(*a))(
+            *head, *clean, *tail, *mlp)[0]
+        out = px.pallas_decode_layer(*dev((*head, *poisoned, *tail, *mlp)))[0]
+    else:       # the per-op rung: the pools already hold this token's row
+        S, H, hd = len(tail[1]), 4, _WALK["hd"]
+        q = (rng.randn(S, H, 1, hd) * 0.3).astype(bf16)
+        ref = xla(lambda *a: tnn.paged_decode_attention(*a))(
+            q, *clean, *tail[:2])
+        out = px.pallas_paged_decode_attention(*dev((q, *poisoned, *tail[:2])))
+    o = np.asarray(out, np.float32)
+    assert np.isfinite(o).all()
+    np.testing.assert_allclose(o, np.asarray(ref, np.float32),
+                               atol=3e-2, rtol=3e-2)
+
+
+def _pallas_grids(fn, *args):
+    """The grid of every pallas_call in ``fn``'s jaxpr."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+@pytest.mark.parametrize("kernel", ["attn_subblock", "paged_decode_attention"])
+def test_attention_grid_does_not_grow_with_the_window(kernel):
+    """A grid step a KV head, whatever ``npg``: the pages are walked by a
+    loop whose trip count is the request's own."""
+    from thunder_tpu.executors import pallasex as px
+
+    H, KV, hd = 4, 2, _WALK["hd"]
+    grids = {}
+    for npg in (8, 128):
+        head, clean, _, tail, _ = _walk_case(H, KV, [3, 9], npg=npg)
+        if kernel == "attn_subblock":
+            fn, args = px.pallas_attn_subblock, (*head, *clean, *tail)
+        else:
+            q = np.zeros((2, H, 1, hd), np.float32)
+            fn, args = px.pallas_paged_decode_attention, \
+                (q, *clean, *tail[:2])
+        grids[npg] = _pallas_grids(fn, *(jnp.asarray(a) for a in args))
+    assert grids[8] == grids[128]
+    steps = {"attn_subblock": H + 2 * KV + KV,      # qkv heads + a walk a head
+             "paged_decode_attention": KV}[kernel]
+    assert grids[8] == [(steps,)]
+
+
+# ---------------------------------------------------------------------------
 # planner verdicts (hand-built traces)
 # ---------------------------------------------------------------------------
 

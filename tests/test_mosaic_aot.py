@@ -120,12 +120,19 @@ def _decode_operands(H, KV, S=8, D=256, hd=128, ps=16, npg=4, F=384,
     return attn, mlp
 
 
-@pytest.mark.parametrize("H,KV", [(2, 2), (4, 2)], ids=["mha", "gqa"])
-def test_serving_kernels(v5e, H, KV):
-    attn, mlp = _decode_operands(H, KV)
+# the last case is mistral7b_serve_decode_sat's own shape (32 slots, GQA 8,
+# a 2,048-token window of 16-token pages, bf16): a VMEM or tiling refusal of
+# the cell's kernel shows here, before the chip
+_SAT = dict(S=32, D=4096, hd=128, ps=16, npg=128, F=14336)
+
+
+@pytest.mark.parametrize("H,KV,shape", [(2, 2, {}), (4, 2, {}), (32, 8, _SAT)],
+                         ids=["mha", "gqa", "sat-cell"])
+def test_serving_kernels(v5e, H, KV, shape):
+    attn, mlp = _decode_operands(H, KV, **shape)
     _compile(v5e, px.pallas_attn_subblock, *attn)
     _compile(v5e, px.pallas_decode_layer, *attn, *mlp)
-    q = sds((8, H, 1, 128))
+    q = sds((attn[0].shape[0], H, 1, 128))
     _compile(v5e, px.pallas_paged_decode_attention, q, attn[8], attn[9],
              attn[10], attn[11])
 

@@ -285,6 +285,35 @@ def test_registry_off_ring_gets_what_it_got_before(model):
     assert not observe.get_registry().events
 
 
+def test_decode_dispatch_counts_the_pages_the_walk_touches(model):
+    """``decode_dispatch`` says how much of the block-table window the
+    decode attention walks: every slot's live pages (an idle slot's one
+    scratch page too) of slots x pages a request. In the ring with the
+    registry off, and from there in ``explain()``'s request timeline."""
+    eng = _engine(model)            # 3 slots, 16-token pages, 4 a request
+    _warm(eng)
+    flight.clear()
+    eng.submit(_prompt(20), 3)      # contexts 20..22: two pages
+    eng.submit(_prompt(9), 3)       # contexts 9..11: one page
+    eng.drain()
+    walks = [r["args"] for r in flight.snapshot()
+             if r["type"] == "span" and r["name"] == "decode_dispatch"]
+    assert walks and not observe.get_registry().spans
+    for a in walks:
+        assert set(a) == {"step", "batch", "live_pages", "window_pages"}
+        assert a["window_pages"] == 3 * 4
+        # each decoding request's pages, and one for every other slot
+        assert a["live_pages"] in (3, 4)
+    assert max(a["live_pages"] for a in walks) == 2 + 1 + 1
+    from thunder_tpu.observe.explain import _request_timeline_lines
+
+    line = [ln for ln in _request_timeline_lines() if "decode walk" in ln]
+    live = sum(a["live_pages"] for a in walks)
+    assert line == [f"  decode walk: {live} live of {12 * len(walks)} window "
+                    f"pages ({100.0 * live / (12 * len(walks)):.1f}%) over "
+                    f"{len(walks)} steps"]
+
+
 # ---------------------------------------------------------------------------
 # a request's road to its first token
 # ---------------------------------------------------------------------------

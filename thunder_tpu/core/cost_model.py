@@ -503,20 +503,43 @@ def subblock_profitable(cost: dict) -> bool:
 DECODE_UNFUSED_LAUNCHES_ATTN = 6   # q/k/v GEMMs + paged attention + out-proj
                                    # + the rope/scatter pointwise region
 DECODE_UNFUSED_LAUNCHES_MLP = 4    # gate/up/down GEMMs + the pointwise glue
+# What one step of the decode megakernel's grid costs with next to nothing
+# in it — every operand's index map evaluated, every block's DMA decided —
+# on top of its bytes and FLOPs. Measured (ledger, PR 25): the attention
+# phase was S * KV * npg = 32,768 one-page steps a layer at 32 slots x 8 KV
+# heads x 128 pages and held 161 ms of a 182 ms decode step = 0.61 us a
+# step. The gate priced bytes, FLOPs and ONE launch, so the program that
+# lost on the chip (below) won here; the fused side now pays for its steps.
+DECODE_GRID_STEP_US = 0.6
 
 
-def decode_subblock_vmem_bytes(n_slots: int, d_model: int, n_heads: int,
-                               kv_heads: int, head_dim: int, page_size: int,
-                               d_ff: int, dtype_bytes: int) -> int:
-    """Modeled per-grid-step VMEM staging of the decode megakernel
-    (``d_ff = 0`` models the attention sub-block alone): the whole slot
-    batch's rows + rope tables + fresh K/V rows stay resident; the f32
-    scratch holds the normed rows, the residual accumulator and (with the
-    MLP chained) the second norm + down accumulator; the streamed tiles
-    (per-head qkv weights, the per-group out-proj slice, one K/V page pair,
-    the ``SUBBLOCK_FF_BLOCK`` MLP slices) are double-buffered. The kernel in
-    ``executors/pallasex.py`` imports the same tile budgets, so this gate
-    and the real staging cannot drift."""
+# The decode attention walk (executors/pallasex.py::_walk_live_pages) stages
+# a request's live K/V pages a BLOCK at a time: ``pages_per_block`` pages of
+# each pool, one DMA descriptor a page, double-buffered. A block is sized in
+# bytes, not pages — tens of KB amortize the loop's fixed cost (descriptor
+# issue, the semaphore waits, two small matmuls) without staging much past a
+# short request's live rows — and shrinks when the kernel's other operands
+# leave less VMEM than two pools x two buffers of it.
+DECODE_KV_BLOCK_BYTES = 128 * 1024
+
+
+def decode_pages_per_block(page_size: int, head_dim: int, dtype_bytes: int,
+                           pages_per_request: int,
+                           vmem_left: int | None = None) -> int:
+    """Pages of ONE pool the decode walk stages per buffer: from the page's
+    bytes (``page_size x head_dim x dtype_bytes``), the block-table window
+    and the VMEM the rest of the kernel leaves — nothing a caller tunes."""
+    page = page_size * head_dim * dtype_bytes
+    ppb = max(1, min(DECODE_KV_BLOCK_BYTES // page, int(pages_per_request)))
+    while vmem_left is not None and ppb > 1 and 4 * ppb * page > vmem_left:
+        ppb //= 2
+    return ppb
+
+
+def _decode_fixed_vmem_bytes(n_slots: int, d_model: int, n_heads: int,
+                             kv_heads: int, head_dim: int, d_ff: int,
+                             dtype_bytes: int) -> int:
+    """The decode megakernel's staging WITHOUT the K/V blocks of the walk."""
     f32 = 4
     g = max(n_heads // max(kv_heads, 1), 1)
     resident = (n_slots * d_model * dtype_bytes            # h rows
@@ -524,6 +547,8 @@ def decode_subblock_vmem_bytes(n_slots: int, d_model: int, n_heads: int,
                 + n_slots * d_model * dtype_bytes          # normed rows
                 + n_heads * n_slots * head_dim * dtype_bytes    # roped q
                 + 2 * kv_heads * n_slots * head_dim * dtype_bytes  # fresh k/v
+                + n_slots * g * head_dim * f32             # one head group's
+                #                                            attention rows
                 + n_slots * d_model * f32)                 # residual acc
     if d_ff:
         resident += 2 * n_slots * d_model * f32            # mlp norm + acc
@@ -536,9 +561,46 @@ def decode_subblock_vmem_bytes(n_slots: int, d_model: int, n_heads: int,
     # not, and the planner then keeps the two-launch form.
     tiles = (3 * head_dim * d_model                        # wq/wk/wv head tiles
              + d_model * g * head_dim                      # out-proj group tile
-             + 2 * page_size * head_dim                    # k + v page blocks
              + 3 * bf * d_model)                           # gate/up/down tiles
     return resident + 2 * tiles * dtype_bytes              # double-buffered
+
+
+def decode_subblock_pages_per_block(n_slots: int, d_model: int, n_heads: int,
+                                    kv_heads: int, head_dim: int,
+                                    page_size: int, d_ff: int,
+                                    dtype_bytes: int,
+                                    pages_per_request: int) -> int:
+    """``decode_pages_per_block`` for the decode megakernel: what its other
+    operands leave of the planning budget bounds the block."""
+    fixed = _decode_fixed_vmem_bytes(n_slots, d_model, n_heads, kv_heads,
+                                     head_dim, d_ff, dtype_bytes)
+    return decode_pages_per_block(page_size, head_dim, dtype_bytes,
+                                  pages_per_request,
+                                  vmem_left=VMEM_BUDGET_BYTES - fixed)
+
+
+def decode_subblock_vmem_bytes(n_slots: int, d_model: int, n_heads: int,
+                               kv_heads: int, head_dim: int, page_size: int,
+                               d_ff: int, dtype_bytes: int,
+                               pages_per_request: int) -> int:
+    """Modeled VMEM staging of the decode megakernel (``d_ff = 0`` models
+    the attention sub-block alone): the whole slot batch's rows + rope
+    tables + fresh K/V rows + one head group's attention rows stay resident;
+    the f32 scratch holds the residual accumulator and (with the MLP
+    chained) the second norm + down accumulator; the streamed tiles
+    (per-head qkv weights, the per-group out-proj slice, the
+    ``SUBBLOCK_FF_BLOCK`` MLP slices) are double-buffered, and so are the
+    K and V blocks the page walk stages: ``decode_pages_per_block`` pages of
+    each pool a buffer. The kernel in ``executors/pallasex.py`` takes its
+    block from the same function, so this gate and the real staging cannot
+    drift."""
+    ppb = decode_subblock_pages_per_block(
+        n_slots, d_model, n_heads, kv_heads, head_dim, page_size, d_ff,
+        dtype_bytes, pages_per_request)
+    return (_decode_fixed_vmem_bytes(n_slots, d_model, n_heads, kv_heads,
+                                     head_dim, d_ff, dtype_bytes)
+            # K and V, two buffers each
+            + 4 * ppb * page_size * head_dim * dtype_bytes)
 
 
 def attn_subblock_cost(n_slots: int, d_model: int, n_heads: int,
@@ -548,7 +610,21 @@ def attn_subblock_cost(n_slots: int, d_model: int, n_heads: int,
     decision-log dict mirrors ``subblock_cost``'s shape: VMEM feasibility,
     the saved-boundary-bytes objective (dominated by the decomposition's
     gathered contiguous cache), and est_unfused/fused_us with the unfused
-    side charged ``DECODE_UNFUSED_LAUNCHES_ATTN`` kernel launches."""
+    side charged ``DECODE_UNFUSED_LAUNCHES_ATTN`` kernel launches and the
+    fused side its grid steps (``n_heads + 3 * kv_heads``: a head a step
+    of q/k/v projection, a KV head a step of the page walk; none a page).
+
+    Estimate against the chip, at 32 slots, D 4096, 32 / 8 heads x 128,
+    16-token pages, window 2,048, 8 layers (my chip runs, PR 26, TPU v5
+    lite; a whole decode step, same seed): fused 24.4 ms (this kernel 0.40
+    ms a layer), planner off 57.3 ms, XLA only 75.3 ms — the estimate says
+    0.50 ms fused against 1.16 ms unfused a layer: the right winner, the
+    unfused side 6x too cheap (XLA's page gather runs at ~50 GB/s, not at
+    the HBM rate charged here). With the one-page grid this kernel had
+    until PR 26 the same three read 181.7 / 88.4 / 75.3 ms: the fused
+    program was the slowest and the estimate, 0.46 ms, could not tell.
+    Both sides are charged the WHOLE window's K/V bytes: the planner sees
+    shapes, not lengths; the walk reads a request's live pages only."""
     L = pages_per_request * page_size              # block-table window
     qkv_w = (n_heads + 2 * kv_heads) * head_dim
     flops = (2 * n_slots * d_model * qkv_w                 # q/k/v projections
@@ -574,14 +650,17 @@ def attn_subblock_cost(n_slots: int, d_model: int, n_heads: int,
     unfused = (flop_us / constant("SUBBLOCK_XLA_EFFICIENCY")
                + (boundary_bytes + interior_bytes) * bw_us_per_byte
                + DECODE_UNFUSED_LAUNCHES_ATTN * launch)
+    grid_steps = n_heads + 3 * kv_heads
     fused = (flop_us / constant("SUBBLOCK_FUSED_EFFICIENCY")
-             + boundary_bytes * bw_us_per_byte + launch)
+             + boundary_bytes * bw_us_per_byte + launch
+             + grid_steps * DECODE_GRID_STEP_US)
     vmem = decode_subblock_vmem_bytes(n_slots, d_model, n_heads, kv_heads,
-                                      head_dim, page_size, 0, dtype_bytes)
+                                      head_dim, page_size, 0, dtype_bytes,
+                                      pages_per_request)
     return stamp_calibration(
         {"n_slots": n_slots, "d_model": d_model, "n_heads": n_heads,
          "kv_heads": kv_heads, "head_dim": head_dim,
-         "context_window": L, "flops": flops,
+         "context_window": L, "flops": flops, "grid_steps": grid_steps,
          "saved_boundary_bytes": interior_bytes,
          "flop_us": round(flop_us, 3),
          "boundary_us": round(boundary_bytes * bw_us_per_byte, 3),
@@ -606,7 +685,8 @@ def decode_layer_cost(attn_cost: dict, mlp_cost: dict, n_slots: int,
              + h2_roundtrip * bw_us_per_byte)
     vmem = decode_subblock_vmem_bytes(
         n_slots, d_model, attn_cost["n_heads"], attn_cost["kv_heads"],
-        attn_cost["head_dim"], page_size, mlp_cost["d_ff"], dtype_bytes)
+        attn_cost["head_dim"], page_size, mlp_cost["d_ff"], dtype_bytes,
+        attn_cost["context_window"] // page_size)
     return stamp_calibration(
         {"n_slots": n_slots, "d_model": d_model,
          "d_ff": mlp_cost["d_ff"], "context_window":

@@ -1135,6 +1135,8 @@ def _linear_act_checker(a, w, bias=None, act: str = "relu"):
 from thunder_tpu.core.cost_model import (  # noqa: E402
     SUBBLOCK_FF_BLOCK as _SUBBLOCK_FF_BUDGET,
     SUBBLOCK_ROW_BLOCK as _SUBBLOCK_ROW_BUDGET,
+    decode_pages_per_block,
+    decode_subblock_pages_per_block,
 )
 
 
@@ -1443,63 +1445,157 @@ def _mlp_subblock_bwd_checker(g, residual, x, w_norm, w_gate, w_up, w_down,
 
 
 # ---------------------------------------------------------------------------
-# paged decode attention (serving engine): one launch computes ragged-batch
-# decode attention over the block-allocated paged KV cache. The grid is
-# (request, kv_head, page); the block table and per-request context lengths
-# ride as SCALAR-PREFETCH operands, so each grid step's K/V page is selected
-# by block-table lookup in the BlockSpec index map — the kernel never sees a
-# gathered contiguous cache (that materialization is exactly what the XLA
-# decomposition of nn.paged_decode_attention pays per step). Pages past a
-# request's length skip their compute via pl.when; masking inside the last
-# partial page is ragged per-request (col < length). Claims the T == 1
-# decode case only — prefill chunks (T > 1 rows over the paged context)
-# take the decomposition, whose gather XLA fuses into the surrounding
-# region once per chunk rather than per token.
+# paged decode attention (serving engine): ragged-batch decode attention
+# over the block-allocated paged KV cache, WITHOUT a gathered contiguous
+# cache (that materialization is exactly what the XLA decomposition of
+# nn.paged_decode_attention pays per step). The pools stay in HBM
+# (memory_space=ANY); the block table and the per-request context lengths
+# ride as SCALAR-PREFETCH operands, and ONE page walk — _walk_live_pages,
+# shared by this kernel and by the attention phase of the decode megakernel
+# below — copies each request's LIVE pages into VMEM itself:
+#
+#   grid step = one KV head. Inside it a loop over the slots, and for each
+#   slot a loop whose trip count is the request's own
+#   cdiv(length, page_size * pages_per_block): a page past a request's
+#   length costs nothing, an idle slot (length 0) an empty loop, and the
+#   grid does not grow with the block-table window. A block is
+#   ``pages_per_block`` pages of K and of V (cost_model.decode_pages_per_block:
+#   from the page's bytes, the window and the VMEM left), one async-copy
+#   descriptor a live page (k_pages.at[kvh, bt[b, p]]), started together into
+#   one half of a double buffer while the other half is computed — the next
+#   block of this request, or the first block of the next slot. The online
+#   softmax (f32 m / l / acc) runs over the whole (G, block) score tile.
+#   Rows past the length are masked in the scores and zeroed in V (the
+#   buffer's dead rows hold whatever an earlier block left there).
+#
+# Claims the T == 1 decode case only — prefill chunks (T > 1 rows over the
+# paged context) take the decomposition, whose gather XLA fuses into the
+# surrounding region once per chunk rather than per token.
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(bt_ref, ln_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, scale: float, ps: int):
-    """Online-softmax accumulation over one request's pages (innermost grid
-    dim sequential). q block: (G, hd) where G = n_heads // kv_heads grouped
-    rows of the single decode position; k/v block: one (ps, hd) page picked
-    by the index map from the scalar-prefetched block table."""
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-    npg = pl.num_programs(2)
+def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
+                     m_ref, l_ref, acc_ref, *, S: int, npg: int, ps: int,
+                     ppb: int, scale: float, q_of, emit, fresh_of=None):
+    """Online-softmax decode attention of KV head ``kvh`` over every slot's
+    live pages. ``q_of(b)`` gives slot b's (G, hd) grouped query rows,
+    ``emit(b, out)`` takes its normalized (G, hd) f32 result (zeros for a
+    length-0 slot); ``fresh_of(b)``, when given, is THIS token's (1, hd) K
+    and V rows, patched in at position length-1 because the pool still holds
+    the pre-append contents. ``bt_ref`` is the flattened (S * npg,) block
+    table; ``kbuf`` / ``vbuf`` are (2, ppb * ps, hd) VMEM buffers and
+    ``sem`` a (2, 2) DMA semaphore array (pool x buffer)."""
+    bk = ppb * ps
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def copies(b, j, buf, do):
+        """``do`` (start or wait) on the copies of block ``j`` of slot
+        ``b``: its live pages only, into buffer ``buf``."""
+        live = jnp.minimum(ppb, (ln_ref[b] + ps - 1) // ps - j * ppb)
+
+        def page(p, carry):
+            pid = bt_ref[b * npg + j * ppb + p]
+            rows = pl.ds(pl.multiple_of(p * ps, ps), ps)
+            do(pltpu.make_async_copy(kp_ref.at[kvh, pid],
+                                     kbuf.at[buf, rows, :], sem.at[0, buf]))
+            do(pltpu.make_async_copy(vp_ref.at[kvh, pid],
+                                     vbuf.at[buf, rows, :], sem.at[1, buf]))
+            return carry
+
+        jax.lax.fori_loop(0, live, page, 0)
+
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
+
+    def slot(b, buf0):
+        ln = ln_ref[b]
+        nblk = (ln + bk - 1) // bk
+        nxt = jnp.minimum(b + 1, S - 1)
+
+        # the first block is already in flight when the slot before had a
+        # loop to start it from
+        @pl.when((nblk > 0) & ((b == 0) | (ln_ref[jnp.maximum(b - 1, 0)] == 0)))
+        def _first():
+            copies(b, 0, buf0, start)
+
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        q = q_of(b)
+        fresh = fresh_of(b) if fresh_of is not None else None
 
-    ln = ln_ref[b]
+        def block(j, carry):
+            buf = (buf0 + j) % 2
 
-    @pl.when(p * ps < ln)
-    def _compute():
-        q = q_ref[0, 0]                                # (G, hd) input dtype
-        k = k_ref[0, 0]                                # (ps, hd)
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        col = p * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col < ln, s, -jnp.inf)           # ragged tail mask
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        pexp = jnp.exp(s - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+            @pl.when(j + 1 < nblk)
+            def _next_block():
+                copies(b, j + 1, 1 - buf, start)
 
-    @pl.when(p == npg - 1)
-    def _finalize():
+            @pl.when((j + 1 == nblk) & (b + 1 < S) & (ln_ref[nxt] > 0))
+            def _next_slot():
+                copies(nxt, 0, 1 - buf, start)
+
+            copies(b, j, buf, wait)
+            k = kbuf[buf]                              # (bk, hd)
+            v = vbuf[buf]
+            row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            v = jnp.where(row < ln, v, jnp.zeros_like(v))   # dead rows
+            if fresh is not None:
+                fk, fv = fresh
+                k = jnp.where(row == ln - 1, fk, k)
+                v = jnp.where(row == ln - 1, fv, v)
+            s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32) * scale
+            col = j * bk + jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
+            s_ = jnp.where(col < ln, s_, -jnp.inf)     # ragged tail mask
+            m = m_ref[...]
+            m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            pexp = jnp.exp(s_ - m_new)
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=-1,
+                                                      keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, nblk, block, 0)
         l = l_ref[...]
-        lsafe = jnp.where(l == 0.0, 1.0, l)            # unreachable rows
-        o_ref[0, 0] = (acc_ref[...] / lsafe).astype(o_ref.dtype)
+        lsafe = jnp.where(l == 0.0, 1.0, l)            # length-0 slot
+        emit(b, acc_ref[...] / lsafe)
+        return (buf0 + nblk) % 2
+
+    jax.lax.fori_loop(0, S, slot, jnp.int32(0))
+
+
+def _whole_tiles(rows: int, dtype_bytes: int) -> bool:
+    """``rows`` rows of this dtype are whole (8 x 32-bit) sublane tiles."""
+    return rows % (8 * max(4 // dtype_bytes, 1)) == 0
+
+
+def _walk_scratch(ppb: int, ps: int, hd: int, G: int, dtype):
+    """The walk's VMEM: K and V double buffers, their DMA semaphores, and
+    the online-softmax m / l / acc of one (slot, head)."""
+    return [pltpu.VMEM((2, ppb * ps, hd), dtype),
+            pltpu.VMEM((2, ppb * ps, hd), dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, hd), jnp.float32)]
+
+
+def _paged_decode_kernel(bt_ref, ln_ref, q_ref, kp_ref, vp_ref, o_ref,
+                         kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
+                         scale: float, ps: int, npg: int, ppb: int):
+    """One KV head of every request: q block (B, 1, G, hd) where G =
+    n_heads // kv_heads grouped rows of the single decode position."""
+    def emit(b, out):
+        o_ref[b, 0] = out.astype(o_ref.dtype)
+
+    _walk_live_pages(pl.program_id(0), bt_ref, ln_ref, kp_ref, vp_ref, kbuf,
+                     vbuf, sem, m_ref, l_ref, acc_ref, S=q_ref.shape[0],
+                     npg=npg, ps=ps, ppb=ppb, scale=scale,
+                     q_of=lambda b: q_ref[b, 0], emit=emit)
 
 
 def pallas_paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
@@ -1530,29 +1626,26 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, lengths, scale=None):
     npg = block_tables.shape[1]
     G = (H // KV) * T                                  # grouped decode rows
     scale_v = scale if scale is not None else 1.0 / math.sqrt(hd)
+    ppb = decode_pages_per_block(ps, hd, q.dtype.itemsize, npg)
     q4 = q.reshape(B, KV, G, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                         # block_tables, lengths
-        grid=(B, KV, npg),
+        grid=(KV,),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, p, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, ps, hd),
-                         lambda b, h, p, bt, ln: (h, bt[b, p], 0, 0)),
-            pl.BlockSpec((1, 1, ps, hd),
-                         lambda b, h, p, bt, ln: (h, bt[b, p], 0, 0)),
+            pl.BlockSpec((B, 1, G, hd), lambda h, bt, ln: (0, h, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),         # k pages, in HBM
+            pl.BlockSpec(memory_space=pl.ANY),         # v pages
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, p, bt, ln: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((G, hd), jnp.float32),
-                        pltpu.VMEM((G, 1), jnp.float32),
-                        pltpu.VMEM((G, 1), jnp.float32)],
+        out_specs=pl.BlockSpec((B, 1, G, hd), lambda h, bt, ln: (0, h, 0, 0)),
+        scratch_shapes=_walk_scratch(ppb, ps, hd, G, k_pages.dtype),
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale_v, ps=ps),
+        functools.partial(_paged_decode_kernel, scale=scale_v, ps=ps,
+                          npg=npg, ppb=ppb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=_interpret(),
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
       q4, k_pages, v_pages)
     return out.reshape(B, H, T, hd)
 
@@ -1584,11 +1677,10 @@ def _paged_decode_checker(q, k_pages, v_pages, block_tables, lengths,
         return False
     if _interpret():
         return True
-    # real-TPU tiling: lane-aligned head dim, sublane-aligned page rows.
-    # The on-chip A/B vs the gathered-decomposition fallback is specified
-    # in ONCHIP_AB.md (C1-C3, not yet run); the claim stays cost-model
-    # gated either way.
-    return hd % 128 == 0 and ps % 8 == 0
+    # real-TPU tiling: lane-aligned head dim, and pages of whole sublane
+    # tiles (a page is one DMA into a row slice of the walk's buffer). The
+    # claim stays cost-model gated either way.
+    return hd % 128 == 0 and _whole_tiles(ps, q.dtype.bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -1600,23 +1692,30 @@ def _paged_decode_checker(q, k_pages, v_pages, block_tables, lengths,
 # The grid is ONE flattened sequential dimension whose steps encode three
 # phases; index maps decode the phase from the step index and pin every
 # operand not used by the current phase to a constant block (revisiting the
-# same block index means Mosaic skips the redundant DMA):
+# same block index means Mosaic skips the redundant DMA). Its length,
+# H + 3*KV (+ F / bf), does not depend on the context window:
 #
 #   phase QKV  (H + 2*KV steps, one head each): at step 0 the whole slot
 #     batch's rows are normalized into VMEM scratch; each step streams one
 #     head's weight tile, runs the (S, D) x (D, hd) projection, applies the
 #     rope half-rotation in-register, and parks the roped rows in scratch
 #     (k/v rows are also emitted as outputs for the page-pool append).
-#   phase ATTN (S * KV * npg steps): the PR 10 scalar-prefetch discipline —
-#     each step's K/V page is selected by bt[b, p] inside the BlockSpec
-#     index map, online-softmax (m, l, acc) carries across the sequential
-#     page dimension, pages wholly past a request's length skip compute via
-#     pl.when. The page that holds THIS token's row is patched from the
-#     fresh-row scratch (jnp.where on the row iota), so the kernel never
-#     re-reads its own append from HBM. At each request's last page the
-#     finalized head group is immediately projected through its wo slice
-#     and accumulated onto the residual rows — the out-projection rides the
-#     attention phase, no separate pass.
+#   phase ATTN (KV steps, one KV head each, whatever the block-table
+#     window): the page walk of the paged decode attention above
+#     (_walk_live_pages) over every slot — the pools stay in HBM, each
+#     request's LIVE pages are copied a block at a time into a double
+#     buffer, online-softmax (m, l, acc) runs over the whole (G, block)
+#     score tile, a dead page costs nothing. THIS token's row is patched
+#     from the fresh-row scratch (jnp.where on the row iota), so the kernel
+#     never re-reads its own append from HBM. Each slot's finalized head
+#     group lands in an (S, G*hd) scratch row; at the end of the head's walk
+#     the whole slot batch is projected through the head group's wo slice
+#     in ONE (S, G*hd) x (G*hd, D) matmul and accumulated onto the residual
+#     rows — wo streams through VMEM once a layer, and the out-projection
+#     rides the attention phase, no separate pass. (Until PR 26 this phase
+#     was S * KV * npg grid steps of one page each, and a step of this
+#     grid cost 0.6 us with nothing in it: 161 of a 182 ms decode step at
+#     32 slots x 8 KV heads x 128 pages.)
 #   phase MLP  (F / bf steps, decode_layer only): the pallas_mlp_subblock
 #     recipe at row-block = the whole slot batch — second norm from the
 #     residual accumulator at the first step, gate/up/down tiles streamed,
@@ -1681,63 +1780,37 @@ def _decode_qkv_phase(i, h_ref, wn1_ref, wq_ref, wk_ref, wv_ref, cos_ref,
         vr_ref[...] = t[None].astype(vr_ref.dtype)
 
 
-def _decode_attn_phase(i, off, n_att, wo_ref, kp_ref, vp_ref, ln_ref, q_ref,
-                       kf_ref, vf_ref, hacc_ref, m_ref, l_ref, acc_ref, *,
-                       KV: int, G: int, hd: int, ps: int, npg: int,
+def _decode_attn_phase(i, off, wo_ref, kp_ref, vp_ref, bt_ref, ln_ref, q_ref,
+                       kf_ref, vf_ref, hacc_ref, att_ref, walk, *, S: int,
+                       KV: int, G: int, hd: int, ps: int, npg: int, ppb: int,
                        scale: float, cast):
-    """Phase ATTN step: online softmax over one (request, kv_head, page)."""
-    t = jnp.clip(i - off, 0, n_att - 1)
-    b = t // (KV * npg)
-    rem = t - b * (KV * npg)
-    kvh = rem // npg
-    p = rem - kvh * npg
-    active = (i >= off) & (i < off + n_att)
+    """Phase ATTN step: one KV head's walk over every slot's live pages,
+    then the head group's out-projection for the whole slot batch."""
+    @pl.when((i >= off) & (i < off + KV))
+    def _head():
+        kvh = jnp.clip(i - off, 0, KV - 1)
 
-    @pl.when(active & (p == 0))
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        def q_of(b):
+            return q_ref[pl.ds(kvh * G, G), pl.ds(b, 1), :] \
+                .reshape(G, hd).astype(cast)
 
-    ln = ln_ref[b]
+        def fresh_of(b):
+            # THIS token's rows (position length-1) from the fresh-row
+            # scratch: the HBM page still holds the pre-append contents
+            return tuple(r[pl.ds(kvh, 1), pl.ds(b, 1), :]
+                         .reshape(1, hd).astype(cast)
+                         for r in (kf_ref, vf_ref))
 
-    @pl.when(active & (p * ps < ln))
-    def _compute():
-        qg = q_ref[pl.ds(kvh * G, G), pl.ds(b, 1), :].reshape(G, hd).astype(cast)
-        k = kp_ref[0, 0]                               # (ps, hd), bt-selected
-        v = vp_ref[0, 0]
-        # patch THIS token's row (position ln-1) from the fresh-row scratch:
-        # the HBM page still holds the pre-append contents
-        fp = ln - 1
-        row = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-        sel = (fp >= p * ps) & (fp < (p + 1) * ps) & (row == fp - p * ps)
-        fk = kf_ref[pl.ds(kvh, 1), pl.ds(b, 1), :].reshape(1, hd).astype(cast)
-        fv = vf_ref[pl.ds(kvh, 1), pl.ds(b, 1), :].reshape(1, hd).astype(cast)
-        k = jnp.where(sel, fk, k)
-        v = jnp.where(sel, fv, v)
-        s_ = jax.lax.dot_general(qg, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
-        col = p * ps + jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
-        s_ = jnp.where(col < ln, s_, -jnp.inf)         # ragged tail mask
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        pexp = jnp.exp(s_ - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        def emit(b, out):
+            att_ref[pl.ds(b, 1), :] = out.astype(cast).astype(jnp.float32) \
+                .reshape(1, G * hd)
+
+        _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, *walk, S=S,
+                         npg=npg, ps=ps, ppb=ppb, scale=scale, q_of=q_of,
+                         emit=emit, fresh_of=fresh_of)
+        hacc_ref[...] += jax.lax.dot_general(
+            att_ref[...].astype(cast), wo_ref[...], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(active & (p == npg - 1))
-    def _finalize():
-        l = l_ref[...]
-        lsafe = jnp.where(l == 0.0, 1.0, l)            # unreachable rows
-        attn = (acc_ref[...] / lsafe).astype(cast).reshape(1, G * hd)
-        contrib = jax.lax.dot_general(attn, wo_ref[...],
-                                      (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        hacc_ref[pl.ds(b, 1), :] += contrib
 
 
 def _decode_mlp_phase(i, off, nf, wn2_ref, wg_ref, wu_ref, wd_ref, o_ref,
@@ -1775,24 +1848,25 @@ def _decode_layer_kernel(bt_ref, ln_ref, h_ref, wn1_ref, wq_ref, wk_ref,
                          wv_ref, wo_ref, cos_ref, sin_ref, kp_ref, vp_ref,
                          wn2_ref, wg_ref, wu_ref, wd_ref,
                          o_ref, kr_ref, vr_ref,
-                         xn_ref, q_ref, kf_ref, vf_ref, hacc_ref,
-                         m_ref, l_ref, acc_ref, x2_ref, macc_ref, *,
-                         H, KV, G, hd, ps, npg, nf, eps, scale, act, cast):
+                         xn_ref, q_ref, kf_ref, vf_ref, hacc_ref, att_ref,
+                         kbuf, vbuf, sem, m_ref, l_ref, acc_ref,
+                         x2_ref, macc_ref, *,
+                         H, KV, G, hd, ps, npg, ppb, nf, eps, scale, act, cast):
     i = pl.program_id(0)
     OA = H + 2 * KV
-    n_att = pl.num_programs(0) - OA - nf
     _decode_qkv_phase(i, h_ref, wn1_ref, wq_ref, wk_ref, wv_ref, cos_ref,
                       sin_ref, kr_ref, vr_ref, xn_ref, q_ref, kf_ref, vf_ref,
                       hacc_ref, H=H, KV=KV, hd=hd, eps=eps, cast=cast,
                       init_h=True)
-    _decode_attn_phase(i, OA, n_att, wo_ref, kp_ref, vp_ref, ln_ref, q_ref,
-                       kf_ref, vf_ref, hacc_ref, m_ref, l_ref, acc_ref,
-                       KV=KV, G=G, hd=hd, ps=ps, npg=npg, scale=scale,
-                       cast=cast)
+    _decode_attn_phase(i, OA, wo_ref, kp_ref, vp_ref, bt_ref, ln_ref, q_ref,
+                       kf_ref, vf_ref, hacc_ref, att_ref,
+                       (kbuf, vbuf, sem, m_ref, l_ref, acc_ref),
+                       S=h_ref.shape[0], KV=KV, G=G, hd=hd, ps=ps, npg=npg,
+                       ppb=ppb, scale=scale, cast=cast)
 
-    @pl.when(i >= OA + n_att)
+    @pl.when(i >= OA + KV)
     def _mlp():
-        _decode_mlp_phase(i, OA + n_att, nf, wn2_ref, wg_ref, wu_ref, wd_ref,
+        _decode_mlp_phase(i, OA + KV, nf, wn2_ref, wg_ref, wu_ref, wd_ref,
                           o_ref, hacc_ref, x2_ref, macc_ref, eps=eps, act=act,
                           cast=cast)
 
@@ -1800,20 +1874,20 @@ def _decode_layer_kernel(bt_ref, ln_ref, h_ref, wn1_ref, wq_ref, wk_ref,
 def _attn_subblock_kernel(bt_ref, ln_ref, h_ref, wn1_ref, wq_ref, wk_ref,
                           wv_ref, wo_ref, cos_ref, sin_ref, kp_ref, vp_ref,
                           o_ref, kr_ref, vr_ref,
-                          xn_ref, q_ref, kf_ref, vf_ref, hacc_ref,
-                          m_ref, l_ref, acc_ref, *,
-                          H, KV, G, hd, ps, npg, eps, scale, cast):
+                          xn_ref, q_ref, kf_ref, vf_ref, hacc_ref, att_ref,
+                          kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
+                          H, KV, G, hd, ps, npg, ppb, eps, scale, cast):
     i = pl.program_id(0)
     OA = H + 2 * KV
-    n_att = pl.num_programs(0) - OA
     _decode_qkv_phase(i, h_ref, wn1_ref, wq_ref, wk_ref, wv_ref, cos_ref,
                       sin_ref, kr_ref, vr_ref, xn_ref, q_ref, kf_ref, vf_ref,
                       hacc_ref, H=H, KV=KV, hd=hd, eps=eps, cast=cast,
                       init_h=False)
-    _decode_attn_phase(i, OA, n_att, wo_ref, kp_ref, vp_ref, ln_ref, q_ref,
-                       kf_ref, vf_ref, hacc_ref, m_ref, l_ref, acc_ref,
-                       KV=KV, G=G, hd=hd, ps=ps, npg=npg, scale=scale,
-                       cast=cast)
+    _decode_attn_phase(i, OA, wo_ref, kp_ref, vp_ref, bt_ref, ln_ref, q_ref,
+                       kf_ref, vf_ref, hacc_ref, att_ref,
+                       (kbuf, vbuf, sem, m_ref, l_ref, acc_ref),
+                       S=h_ref.shape[0], KV=KV, G=G, hd=hd, ps=ps, npg=npg,
+                       ppb=ppb, scale=scale, cast=cast)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _store():
@@ -1842,21 +1916,10 @@ def _decode_call(h, w_norm, wq, wk, wv, wo, cos, sin, k_pages, v_pages,
     cos2 = jnp.concatenate([cos2, cos2], axis=-1)
     sin2 = jnp.concatenate([-sin2, sin2], axis=-1)
     OA = H + 2 * KV
-    n_att = S * KV * npg
-
-    def att_decode(i):
-        t = jnp.clip(i - OA, 0, n_att - 1)
-        b = t // (KV * npg)
-        rem = t - b * (KV * npg)
-        return b, rem // npg, rem - (rem // npg) * npg
-
-    def im_page(i, bt, ln):
-        b, kvh, p = att_decode(i)
-        return (kvh, bt[b, p], 0, 0)
-
-    def im_wo(i, bt, ln):
-        _, kvh, _ = att_decode(i)
-        return (0, kvh)
+    # pages of each pool a block of the walk stages: the gate's own number
+    ppb = decode_subblock_pages_per_block(
+        S, D, H, KV, hd, ps, 0 if mlp is None else mlp[1].shape[0],
+        cast.itemsize, npg)
 
     in_specs = [
         pl.BlockSpec((S, D), lambda i, bt, ln: (0, 0)),            # h
@@ -1866,11 +1929,12 @@ def _decode_call(h, w_norm, wq, wk, wv, wo, cos, sin, k_pages, v_pages,
                      lambda i, bt, ln: (jnp.clip(i - H, 0, KV - 1), 0)),
         pl.BlockSpec((hd, D),
                      lambda i, bt, ln: (jnp.clip(i - H - KV, 0, KV - 1), 0)),
-        pl.BlockSpec((D, G * hd), im_wo),                          # wo
+        pl.BlockSpec((D, G * hd),                                  # wo
+                     lambda i, bt, ln: (0, jnp.clip(i - OA, 0, KV - 1))),
         pl.BlockSpec((S, hd), lambda i, bt, ln: (0, 0)),           # [c, c]
         pl.BlockSpec((S, hd), lambda i, bt, ln: (0, 0)),           # [-s, s]
-        pl.BlockSpec((1, 1, ps, hd), im_page),                     # k pages
-        pl.BlockSpec((1, 1, ps, hd), im_page),                     # v pages
+        pl.BlockSpec(memory_space=pl.ANY),                         # k pages
+        pl.BlockSpec(memory_space=pl.ANY),                         # v pages
     ]
     operands = [h2, w_norm, wq, wk, wv, wo, cos2, sin2, k_pages, v_pages]
     scratch = [
@@ -1882,16 +1946,16 @@ def _decode_call(h, w_norm, wq, wk, wv, wo, cos, sin, k_pages, v_pages,
         pltpu.VMEM((KV, S, hd), jnp.float32),  # fresh k rows
         pltpu.VMEM((KV, S, hd), jnp.float32),  # fresh v rows
         pltpu.VMEM((S, D), jnp.float32),   # residual accumulator
-        pltpu.VMEM((G, 1), jnp.float32),   # online-softmax m
-        pltpu.VMEM((G, 1), jnp.float32),   # online-softmax l
-        pltpu.VMEM((G, hd), jnp.float32),  # online-softmax acc
+        pltpu.VMEM((S, G * hd), jnp.float32),  # one head group's attention
+        #                                        rows, written a slot a time
+        *_walk_scratch(ppb, ps, hd, G, k_pages.dtype),
     ]
     if mlp is not None:
         wn2, wg, wu, wd = mlp
         F = wg.shape[0]
         bf = _pick_block(F, _SUBBLOCK_FF_BUDGET)
         nf = F // bf
-        OM = OA + n_att
+        OM = OA + KV
         in_specs += [
             pl.BlockSpec((D,), lambda i, bt, ln: (0,)),            # wn2
             pl.BlockSpec((bf, D),
@@ -1905,14 +1969,14 @@ def _decode_call(h, w_norm, wq, wk, wv, wo, cos, sin, k_pages, v_pages,
         scratch += [pltpu.VMEM((S, D), cast),          # second norm rows
                     pltpu.VMEM((S, D), jnp.float32)]   # mlp accumulator
         kern = functools.partial(_decode_layer_kernel, H=H, KV=KV, G=G,
-                                 hd=hd, ps=ps, npg=npg, nf=nf, eps=eps,
-                                 scale=scale_v, act=act, cast=cast)
+                                 hd=hd, ps=ps, npg=npg, ppb=ppb, nf=nf,
+                                 eps=eps, scale=scale_v, act=act, cast=cast)
         n_total = OM + nf
     else:
         kern = functools.partial(_attn_subblock_kernel, H=H, KV=KV, G=G,
-                                 hd=hd, ps=ps, npg=npg, eps=eps,
+                                 hd=hd, ps=ps, npg=npg, ppb=ppb, eps=eps,
                                  scale=scale_v, cast=cast)
-        n_total = OA + n_att
+        n_total = OA + KV
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                         # block_tables, lengths
@@ -1935,7 +1999,8 @@ def _decode_call(h, w_norm, wq, wk, wv, wo, cos, sin, k_pages, v_pages,
                    jax.ShapeDtypeStruct((KV, S, hd), cast),
                    jax.ShapeDtypeStruct((KV, S, hd), cast)],
         interpret=_interpret(), **_grid_params(planned_vmem=True),
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+    )(block_tables.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
+      *operands)
     # the page-pool append stays a plain replace-semantics scatter in the
     # same XLA program (identical traffic to the decomposition's
     # prims.scatter; duplicate idle-slot positions all hit the reserved
@@ -2034,9 +2099,11 @@ def _attn_subblock_checker(h, w_norm, wq, wk, wv, wo, cos, sin, k_pages,
     tp = _plan_shards()
     if H % tp or KV % tp:
         return False
-    return (hd % 128 == 0 and ps % 8 == 0 and D % 128 == 0 and S % 8 == 0
+    return (hd % 128 == 0 and _whole_tiles(ps, h.dtype.bytes)
+            and D % 128 == 0 and S % 8 == 0
             and decode_subblock_vmem_bytes(S, D, H // tp, KV // tp, hd, ps,
-                                           0, h.dtype.bytes)
+                                           0, h.dtype.bytes,
+                                           int(block_tables.shape[1]))
             <= VMEM_BUDGET_BYTES)
 
 
@@ -2074,7 +2141,8 @@ def _decode_layer_checker(h, attn_norm, wq, wk, wv, wo, cos, sin, k_pages,
     S = int(h.shape[0])
     return (F % 128 == 0
             and decode_subblock_vmem_bytes(S, D, H, KV, hd, ps, F,
-                                           h.dtype.bytes)
+                                           h.dtype.bytes,
+                                           int(block_tables.shape[1]))
             <= VMEM_BUDGET_BYTES)
 
 

@@ -117,6 +117,16 @@ def _request_timeline_lines() -> list[str]:
     if occ:
         out.append("  slot occupancy (sampled): " + ", ".join(
             f"{k} x{occ[k]}" for k in sorted(occ)))
+    # the share of the block-table window the decode attention walked
+    # (``decode_dispatch`` carries both counts of every step)
+    walks = [r["args"] for r in recs
+             if r["type"] == "span" and r["name"] == "decode_dispatch"
+             and r["args"].get("window_pages")]
+    if walks:
+        live = sum(a["live_pages"] for a in walks)
+        window = sum(a["window_pages"] for a in walks)
+        out.append(f"  decode walk: {live} live of {window} window pages "
+                   f"({100.0 * live / window:.1f}%) over {len(walks)} steps")
     return out
 
 

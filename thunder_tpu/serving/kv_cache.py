@@ -10,7 +10,7 @@ so the compiled decode step has ONE shape regardless of who is resident
 Page 0 is reserved as the scratch page: it is never allocated, inactive
 decode slots write their (discarded) K/V there, and unallocated block-table
 entries point at it — every table entry is always a valid pool index, which
-is what lets the Pallas kernel's scalar-prefetch index map run unguarded.
+is what lets the Pallas kernel's page walk (and the gather) run unguarded.
 
 Pages are REFCOUNTED (copy-on-write substrate): ``alloc`` hands out pages
 at refcount 1, ``retain`` lets a second block table share a page, and

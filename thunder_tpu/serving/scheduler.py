@@ -58,7 +58,8 @@ different sequence lengths served by ONE compiled decode program.
       engine_step                 root; args step, decoding, queued
         schedule                  deadlines, forks, admission
         decode_build              page growth, the batch arrays
-        decode_dispatch
+        decode_dispatch           ``live_pages`` of ``window_pages``: what
+                                  the decode attention walks of the tables
           decode_enqueue          the call into the bound program until it
                                   returns (``step:serving_decode`` inside)
           decode_wait             the device wait and the token fetch
@@ -1100,6 +1101,10 @@ class ServingEngine:
                 topp[i] = sp.top_p
                 rng[i, 0] = r.stream_seed
                 rng[i, 1] = len(r.generated)    # counter: tokens sampled so far
+            # what the decode attention walks of what the block tables span
+            # (an idle slot's one scratch page included: the kernel walks it)
+            walk = {"live_pages": int((-(-lengths // g.page_size)).sum()),
+                    "window_pages": bt.size}
 
         def dispatch():
             # injected faults fire BEFORE the device dispatch, so a retried
@@ -1149,7 +1154,8 @@ class ServingEngine:
         # two parts: the call into the bound program until it returns, and
         # the wait for the device
         with self.obs.span("decode_dispatch", "serving:sched",
-                           {"step": self._step_count, "batch": len(active)}):
+                           {"step": self._step_count, "batch": len(active),
+                            **walk}):
             with self.obs.span("decode_enqueue", "serving:sched",
                                self._step_args, ring=False):
                 tok_ids, self.last_decode_logits, pools = \
