@@ -279,6 +279,208 @@ def test_planner_vmem_infeasibility():
                for d in log), log
 
 
+# forward-only verdicts, as the parent commit's cost model gave them: the
+# pair's terms exist only for a chain planned ahead of the pullback, so
+# these dicts must not move by a digit (decode, the prefill chunk, the
+# training shape scored forward-only, and the shapes the two older tests use)
+_FORWARD_ONLY_GOLDEN = {
+    "decode32": ((32, 4096, 14336, 2), True, {
+        "n_tokens": 32, "d_model": 4096, "d_ff": 14336, "flops": 11274289152,
+        "decode": True, "saved_boundary_bytes": 7077888, "flop_us": 57.23,
+        "boundary_us": 431.145, "vmem_bytes_per_step": 8650752,
+        "vmem_feasible": True, "est_unfused_us": 539.918,
+        "est_fused_us": 510.683, "est_saved_us": 29.236}),
+    "prefill512": ((512, 4096, 14336, 2), False, {
+        "n_tokens": 512, "d_model": 4096, "d_ff": 14336,
+        "flops": 180388626432, "decode": False,
+        "saved_boundary_bytes": 113246208, "flop_us": 915.678,
+        "boundary_us": 445.549, "vmem_bytes_per_step": 15728640,
+        "vmem_feasible": True, "est_unfused_us": 1673.916,
+        "est_fused_us": 1598.147, "est_saved_us": 75.769}),
+    "train16384": ((16384, 4096, 14336, 2), False, {
+        "n_tokens": 16384, "d_model": 4096, "d_ff": 14336,
+        "flops": 5772436045824, "decode": False,
+        "saved_boundary_bytes": 3623878656, "flop_us": 29301.706,
+        "boundary_us": 921.825, "vmem_bytes_per_step": 15728640,
+        "vmem_feasible": True, "est_unfused_us": 40229.568,
+        "est_fused_us": 37556.957, "est_saved_us": 2672.611}),
+    "bench11008": ((16384, 4096, 11008, 2), False, {
+        "n_tokens": 16384, "d_model": 4096, "d_ff": 11008,
+        "flops": 4432406249472, "decode": False,
+        "saved_boundary_bytes": 2969567232, "flop_us": 22499.524,
+        "boundary_us": 821.961, "vmem_bytes_per_step": 15728640,
+        "vmem_feasible": True, "est_unfused_us": 31232.954,
+        "est_fused_us": 28954.366, "est_saved_us": 2278.588}),
+    "huge": ((16384, 8192, 32768, 2), False, {
+        "n_tokens": 16384, "d_model": 8192, "d_ff": 32768,
+        "flops": 26388279066624, "decode": False,
+        "saved_boundary_bytes": 8053063680, "flop_us": 133950.655,
+        "boundary_us": 2949.84, "vmem_bytes_per_step": 31457280,
+        "vmem_feasible": False, "est_unfused_us": 172247.706,
+        "est_fused_us": 170396.159, "est_saved_us": 1851.547}),
+    "tiny": ((32, 64, 176, 4), False, {
+        "n_tokens": 32, "d_model": 64, "d_ff": 176, "flops": 2162688,
+        "decode": False, "saved_boundary_bytes": 184320, "flop_us": 0.011,
+        "boundary_us": 0.195, "vmem_bytes_per_step": 245760,
+        "vmem_feasible": True, "est_unfused_us": 0.433,
+        "est_fused_us": 8.209, "est_saved_us": -7.776}),
+    "decode8_11008": ((8, 4096, 11008, 2), True, {
+        "n_tokens": 8, "d_model": 4096, "d_ff": 11008, "flops": 2164260864,
+        "decode": True, "saved_boundary_bytes": 1449984, "flop_us": 10.986,
+        "boundary_us": 330.561, "vmem_bytes_per_step": 6881280,
+        "vmem_feasible": True, "est_unfused_us": 377.41,
+        "est_fused_us": 352.293, "est_saved_us": 25.116}),
+    "fwd8_11008": ((8, 4096, 11008, 2), False, {
+        "n_tokens": 8, "d_model": 4096, "d_ff": 11008, "flops": 2164260864,
+        "decode": False, "saved_boundary_bytes": 1449984, "flop_us": 10.986,
+        "boundary_us": 330.561, "vmem_bytes_per_step": 6881280,
+        "vmem_feasible": True, "est_unfused_us": 345.41,
+        "est_fused_us": 352.293, "est_saved_us": -6.884}),
+}
+
+# a chain scored with its backward: (shape) -> what the pair's model must
+# say. At the training cell's shape the kernel times are the ledger's (PR
+# 26, TPU v5 lite): 60.4 ms forward, 127.1 ms backward a layer.
+_PAIR_CASES = {
+    "train-cell": ((16384, 4096, 14336, 2), False, (60.4e3, 127.1e3)),
+    "train-llama2": ((16384, 4096, 11008, 2), False, None),
+    "prefill-rows": ((512, 4096, 14336, 2), False, None),
+    "one-row-block": ((128, 4096, 14336, 2), True, None),
+    "tiny": ((32, 64, 176, 4), False, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_FORWARD_ONLY_GOLDEN) + [
+    f"pair:{k}" for k in _PAIR_CASES])
+def test_subblock_cost_forward_only_unchanged_and_pair_terms(case):
+    """One model, two inputs it can see. Forward-only (decode, prefill): the
+    parent's numbers to the last digit. Planned ahead of the pullback: the
+    forward and backward pair each side commits to — within 15% of the
+    ledger's kernel times at the training cell's shape, and rejected there."""
+    if not case.startswith("pair:"):
+        shape, decode, golden = _FORWARD_ONLY_GOLDEN[case]
+        assert cost_model.subblock_cost(*shape, decode=decode) == golden
+        assert cost_model.subblock_cost(*shape, decode=decode,
+                                        with_backward=False) == golden
+        return
+    shape, plans, measured = _PAIR_CASES[case[len("pair:"):]]
+    fwd_only = cost_model.subblock_cost(*shape)
+    c = cost_model.subblock_cost(*shape, with_backward=True)
+    assert c["with_backward"] is True and "with_backward" not in fwd_only
+    # the pair's totals are the sums of its parts, the objective their gap
+    assert c["est_fused_us"] == pytest.approx(
+        c["est_fused_fwd_us"] + c["est_fused_bwd_us"], abs=2e-3)
+    assert c["est_unfused_us"] == pytest.approx(
+        c["est_unfused_fwd_us"] + c["est_unfused_bwd_us"], abs=2e-3)
+    assert c["est_saved_us"] == pytest.approx(
+        c["est_unfused_us"] - c["est_fused_us"], abs=2e-3)
+    # XLA's forward is the forward-only estimate; everything not an
+    # estimate (shape, VMEM staging, the interior bytes) is shared
+    assert c["est_unfused_fwd_us"] == fwd_only["est_unfused_us"]
+    for k in ("flops", "saved_boundary_bytes", "vmem_bytes_per_step",
+              "vmem_feasible", "flop_us", "boundary_us"):
+        assert c[k] == fwd_only[k]
+    n, d, f, s = shape
+    assert c["recomputed_flops"] == 10 * 2 * n * d * f   # 28 NDF for 18
+    row_blocks = -(-n // cost_model.SUBBLOCK_ROW_BLOCK)
+    ff_blocks = -(-f // min(cost_model.SUBBLOCK_FF_BLOCK, f))
+    assert c["restreamed_bytes"] == (
+        (2 * row_blocks - 2) * 3 * d * f * s + (ff_blocks - 1) * 2 * n * d * s)
+    assert cost_model.subblock_profitable(c) is plans
+    if measured is not None:
+        fwd_us, bwd_us = measured
+        assert abs(c["est_fused_fwd_us"] - fwd_us) / fwd_us < 0.15
+        assert abs(c["est_fused_bwd_us"] - bwd_us) / bwd_us < 0.15
+        assert c["est_saved_us"] < -50e3            # ~70 ms a layer
+        assert cost_model.subblock_profitable(fwd_only)   # what planned it
+
+
+@pytest.mark.parametrize("block_fusion", [None, True, False],
+                         ids=["default", "forced", "off"])
+def test_train_step_scores_chain_with_its_backward(block_fusion):
+    """A ``tt.jit`` train step at a small shape: by default the chain is
+    scored with its backward, the decision carries the pair's terms and
+    nothing is planned in either direction; ``block_fusion=True`` still
+    plans both kernels; every variant matches ``block_fusion=False``."""
+    cfg = llama.CONFIGS["tiny"]
+    params = llama.init_params(cfg, seed=11, scale_layers=2)
+    rng = np.random.RandomState(11)
+    tokens = rng.randint(0, cfg.vocab_size, size=(2, 16)).astype(np.int32)
+    targets = np.roll(tokens, -1, 1).astype(np.int32)
+    step = _tiny_train_step(cfg)
+    kw = {} if block_fusion is None else {"block_fusion": block_fusion}
+    observe.enable(clear=True)
+    jf = tt.jit(step, executors=["pallas", "xla"], **kw)
+    loss, grads = jf(params, tokens, targets)
+    n_fusions = observe.snapshot()["counters"].get("fusion.block_fusions", 0)
+    observe.disable()
+    plain = tt.jit(step, executors=["pallas", "xla"], block_fusion=False)
+    l_u, g_u = plain(params, tokens, targets)
+    np.testing.assert_allclose(np.asarray(loss), np.asarray(l_u), atol=2e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(g_u)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=5e-4, rtol=5e-4)
+    names = _symbol_names(tt.last_execution_trace(jf))
+    dec = _block_decisions(jf)
+    if block_fusion is False:
+        assert not dec and n_fusions == 0
+        assert "pallas_mlp_subblock" not in names
+        return
+    # both layers' chains were seen once, at the pre-autodiff entry, and
+    # scored there as the pair; the second entry re-plans neither
+    assert len(dec) == 2, dec
+    pair_terms = {"with_backward", "restreamed_bytes", "recomputed_flops",
+                  "est_fused_fwd_us", "est_fused_bwd_us",
+                  "est_unfused_fwd_us", "est_unfused_bwd_us"}
+    assert all(pair_terms <= set(d["cost"]) for d in dec), dec
+    report = observe.explain(jf)
+    assert "with_backward=True" in report and "est_fused_bwd_us=" in report
+    if block_fusion is True:
+        assert [d["decision"] for d in dec] == ["planned"] * 2
+        assert n_fusions == 2
+        assert {"pallas_mlp_subblock", "pallas_mlp_subblock_bwd"} <= names
+    else:
+        assert [d["decision"] for d in dec] == ["cost-rejected"] * 2
+        assert all("scored with its backward" in d["reason"] for d in dec)
+        assert n_fusions == 0
+        assert not {"pallas_mlp_subblock", "pallas_mlp_subblock_bwd"} & names
+
+
+def test_residual_ledger_holds_a_pair_scored_chain_to_its_forward():
+    """The profiled region of a planned ``nn.mlp_subblock`` is the forward
+    kernel: the residual ledger compares it with the forward estimates of
+    a chain that was scored with its backward, not with the pair's sums."""
+    from thunder_tpu.observe import profile
+
+    cost = cost_model.subblock_cost(128, 4096, 14336, 2, with_backward=True)
+    dec = [{"kind": "block", "op": "nn.mlp_subblock", "decision": "planned",
+            "cost": cost, "region": "pallas:mlp_subblock#0"}]
+    prof = profile.StepProfile(
+        {"pallas:mlp_subblock#0": {"mean_us": 500.0, "total_us": 500.0,
+                                   "calls": 1}},
+        steps=1, mode="profiler", platform="test")
+    (rec,) = profile.residual_ledger(dec, prof)
+    assert rec["predicted_us"] == cost["est_fused_fwd_us"]
+    assert rec["est_unfused_us"] == cost["est_unfused_fwd_us"]
+    assert rec["status"] == "measured" and not rec["flipped"]
+
+
+def test_inference_chain_is_scored_forward_only():
+    """The inference entry (``transform_for_execution``) plans no pullback:
+    its decision carries none of the pair's terms."""
+    args = _chain_inputs(np.float32, seed=5)
+    jf = tt.jit(_chain, executors=["pallas", "xla"])
+    jf(*args)
+    (d,) = _block_decisions(jf)
+    assert d["decision"] == "cost-rejected"
+    assert "with_backward" not in d["cost"]
+    assert "saved boundary bytes lose" in d["reason"]
+    assert d["cost"] == dict(cost_model.subblock_cost(16, 32, 48, 4),
+                             chain=d["cost"]["chain"], act="silu", ops=8)
+
+
 def test_planner_decisions_use_registered_kinds_only():
     from thunder_tpu.core import fusion_passes
 

@@ -614,6 +614,20 @@ def _record_block(decision: str, reason: str, cost: dict | None,
     _decisions.record("block", op, None, decision, reason, cost=cost)
 
 
+def _mlp_reject_reason(cost: dict) -> str:
+    """Why the cost model turned an MLP sub-block chain down, in the terms
+    it was scored in (``cost_model.subblock_cost``)."""
+    if not cost.get("with_backward"):
+        return ("saved boundary bytes lose to launch overhead + modeled "
+                "MXU-efficiency handicap (need est_saved_us > 0)")
+    return ("scored with its backward: the kernels' forward + backward "
+            f"({cost['est_fused_fwd_us']:.0f} + {cost['est_fused_bwd_us']:.0f}"
+            f" us; {cost['restreamed_bytes'] >> 20} MiB re-streamed, "
+            f"{cost['recomputed_flops'] / 1e9:.1f} GFLOP recomputed) lose to "
+            f"XLA's ({cost['est_unfused_fwd_us']:.0f} + "
+            f"{cost['est_unfused_bwd_us']:.0f} us) (need est_saved_us > 0)")
+
+
 def _plain_linear(b: BoundSymbol):
     """(input, weight) for a bias-free single-GEMM ``nn.linear``, else None.
     A bias add, TP collective, or fp8 path adds subsymbols; such linears are
@@ -638,7 +652,8 @@ def _chain_act(b: BoundSymbol) -> str | None:
     return act
 
 
-def block_fusion_pass(trc: TraceCtx, executors) -> TraceCtx:
+def block_fusion_pass(trc: TraceCtx, executors,
+                      with_backward: bool = False) -> TraceCtx:
     """The block-level megakernel planner (ROADMAP item 3 / FlashFuser-class
     fusion scale), three staged dataflow walks:
 
@@ -663,6 +678,9 @@ def block_fusion_pass(trc: TraceCtx, executors) -> TraceCtx:
     ``transform_for_execution`` for inference traces); the attention and
     chaining stages only ever fire on decode traces (their anchor,
     ``nn.paged_decode_attention`` at T==1, cannot appear under autodiff).
+    The pre-autodiff entry passes ``with_backward=True``: a verdict there
+    also commits the pullback to ``nn.mlp_subblock_bwd``, so the chain is
+    scored as the forward and backward pair (``cost_model.subblock_cost``).
 
     Every verdict — chain found, boundary chosen, VMEM-infeasible,
     cost-rejected, escape-blocked, chained — lands in
@@ -691,7 +709,7 @@ def block_fusion_pass(trc: TraceCtx, executors) -> TraceCtx:
         "rung, never to per-op XLA)",
         None)
     trc = _attn_block_pass(trc, executors, enabled)
-    trc = _mlp_block_pass(trc, executors, enabled)
+    trc = _mlp_block_pass(trc, executors, enabled, with_backward)
     if tp_shards is not None and int(tp_shards) > 1:
         # record the cap only on traces that reached the chainable rung —
         # an attention sub-block anchor means _decode_chain_pass would
@@ -707,8 +725,10 @@ def block_fusion_pass(trc: TraceCtx, executors) -> TraceCtx:
     return _decode_chain_pass(trc, executors, enabled)
 
 
-def _mlp_block_pass(trc: TraceCtx, executors, enabled) -> TraceCtx:
-    """The MLP sub-block walk (stage 2 of :func:`block_fusion_pass`)."""
+def _mlp_block_pass(trc: TraceCtx, executors, enabled,
+                    with_backward: bool) -> TraceCtx:
+    """The MLP sub-block walk (stage 2 of :func:`block_fusion_pass`);
+    ``with_backward``: the trace will be differentiated after this pass."""
     bsyms = trc.bound_symbols
     # cheap anchor scan: the chain needs a composite-level rms_norm AND
     # composite-level linears (post-autodiff traces are prim-level for the
@@ -855,8 +875,8 @@ def _mlp_block_pass(trc: TraceCtx, executors, enabled) -> TraceCtx:
             for p in (residual, xx) if p.name in producer)
         cost = dict(cost_model.subblock_cost(
             n_tokens, int(w_gate.shape[1]), int(w_gate.shape[0]),
-            h.dtype.bytes, decode=decode_ctx), chain=h.name, act=act,
-            ops=len(chain))
+            h.dtype.bytes, decode=decode_ctx, with_backward=with_backward),
+            chain=h.name, act=act, ops=len(chain))
         # --- verdicts (phase 2) --------------------------------------------
         # exclusivity: every interior value must be consumed ONLY inside the
         # chain and must not be a trace output — the megakernel does not
@@ -887,10 +907,7 @@ def _mlp_block_pass(trc: TraceCtx, executors, enabled) -> TraceCtx:
                           "budget", cost)
             continue
         if enabled is not True and not cost_model.subblock_profitable(cost):
-            _record_block("cost-rejected",
-                          "saved boundary bytes lose to launch overhead + "
-                          "modeled MXU-efficiency handicap "
-                          "(need est_saved_us > 0)", cost)
+            _record_block("cost-rejected", _mlp_reject_reason(cost), cost)
             continue
         comp_args = (residual, xx, w_norm, w_gate, w_up, w_down)
         comp_kwargs = {"act": act, "eps": eps}
@@ -912,10 +929,15 @@ def _mlp_block_pass(trc: TraceCtx, executors, enabled) -> TraceCtx:
                            f"chain planned as one megakernel "
                            f"({cost['saved_boundary_bytes'] >> 10} KiB of "
                            f"interior traffic kept in VMEM)")
-        _record_block("planned",
-                      "forced by block_fusion=True" if enabled is True
-                      else "cost model: interior-byte saving beats the "
-                           "fused-path overheads", cost)
+        if enabled is True:
+            why = "forced by block_fusion=True"
+        elif with_backward:
+            why = ("cost model: the forward and backward kernels beat XLA's "
+                   "pair (weights streamed once)")
+        else:
+            why = ("cost model: interior-byte saving beats the fused-path "
+                   "overheads")
+        _record_block("planned", why, cost)
         _observe.inc("fusion.block_fusions")
         replacements[fi] = repl
         dropped.update(chain - {fi})
@@ -1432,6 +1454,8 @@ def plan_blocks_for_autodiff(trc: TraceCtx) -> TraceCtx:
     pullback replay): resolves the compiling function's executor stack from
     the compile context and runs :func:`block_fusion_pass`, so planned
     composites hit their VJP rule and stay claimable in both directions.
+    A verdict taken here is a verdict on the backward too, and the pass is
+    told so (``with_backward=True``): the cost model scores the pair.
     Outside a compile (no context, e.g. direct trace manipulation in tests)
     this is a no-op."""
     from thunder_tpu.core.compile_data import get_compile_data
@@ -1441,7 +1465,7 @@ def plan_blocks_for_autodiff(trc: TraceCtx) -> TraceCtx:
     if not executors:
         return trc
     with _observe.span("block_fusion_pre_autodiff"):
-        return block_fusion_pass(trc, executors)
+        return block_fusion_pass(trc, executors, with_backward=True)
 
 
 def epilogue_fusion_pass(trc: TraceCtx, executors) -> TraceCtx:
