@@ -417,7 +417,7 @@ def train_phase(preset: Preset, meter: CompileMeter, dev: dict) -> dict:
         f"{time.perf_counter() - t0:.1f} s)")
     meter.take()                    # the reference's compiles are not ours
 
-    # bf16 first moment, f32 second: the step bench.py times
+    # bf16 first moment, f32 second: the step the benchmark's train cell times
     opt = AdamW(lr=1e-4, state_dtype=dtypes.bfloat16, v_dtype=dtypes.float32)
     jstep = tt.jit(make_train_step(cfg, opt), donate_argnums=(0, 1))
     out, params, opt_state = run_steps(

@@ -2,10 +2,11 @@
 optimizer state.
 
 CPU-only (Pallas interpret mode), tier-1. Covers: sub-block megakernel
-parity vs the unfused decomposition (forward + backward, ragged shapes),
-planner verdicts in the decision log / explain(), dist-annotated operands
-never planned across shards, fusion-shape regressions on the tiny-llama
-train trace, quarantine fallback to the per-op XLA decomposition (chaos),
+parity vs the unfused decomposition (forward, ragged shapes; gradients go
+through the decomposition), planner verdicts in the decision log /
+explain(), dist-annotated operands never planned across shards, the
+planner's one entry (a train step plans nothing under any ``block_fusion``
+value), quarantine fallback to the per-op XLA decomposition (chaos),
 and the slab-persistent AdamW contracts (kernel-level bit-identity,
 layout-version checkpoint round-trips).
 """
@@ -122,35 +123,44 @@ def test_subblock_megakernel_forward_parity(np_dtype):
 
 @pytest.mark.parametrize("np_dtype", [np.float32, jnp.bfloat16], ids=["f32", "bf16"])
 def test_subblock_megakernel_backward_parity(np_dtype):
-    """Grads of the planned chain (VJP rule -> nn.mlp_subblock_bwd kernel)
-    match jax autodiff of the unfused reference, for every operand."""
+    """``nn.mlp_subblock`` is a serving kernel with no VJP rule. Called
+    directly, its forward is claimed by Pallas; under ``tt.value_and_grad``
+    it differentiates through its decomposition (no ``pallas_mlp_subblock*``
+    op in the step) and the gradients match jax autodiff of the unfused
+    reference, for every operand."""
+    from thunder_tpu.ops import nn as tnn
+
     args = _chain_inputs(np_dtype)
+    jfwd = tt.jit(lambda *a: tnn.mlp_subblock(*a, act="silu", eps=1e-5),
+                  executors=["pallas", "xla"])
+    out = jfwd(*args)
+    assert "pallas_mlp_subblock" in _symbol_names(tt.last_execution_trace(jfwd))
 
     def loss(*a):
-        return ops.sum(ops.mul(_chain(*a), 0.1))
+        return ops.sum(ops.mul(tnn.mlp_subblock(*a, act="silu", eps=1e-5), 0.1))
 
-    # value_and_grad (not grad): with the recompute-based VJP the forward
-    # kernel is dead code unless its value is returned — DCE correctly drops
-    # it when only grads are requested
     jf = tt.jit(lambda *a: tt.value_and_grad(loss, argnums=tuple(range(6)))(*a),
                 executors=["pallas", "xla"], block_fusion=True)
     lval, grads = jf(*args)
     names = _symbol_names(tt.last_execution_trace(jf))
-    assert "pallas_mlp_subblock" in names
-    assert "pallas_mlp_subblock_bwd" in names
+    assert not [n for n in names if "mlp_subblock" in n], names
+    assert not _block_decisions(jf)
 
     def jref_loss(*a):
         return (_subblock_ref(*a).astype(jnp.float32) * 0.1).sum()
 
     jl, jg = jax.value_and_grad(jref_loss, argnums=tuple(range(6)))(*args)
-    tol = dict(atol=2e-4, rtol=2e-4) if np_dtype == np.float32 \
-        else dict(atol=0.12, rtol=0.12)
+    fwd_tol, loss_rtol, grad_tol = (1e-5, 2e-3, 2e-4) \
+        if np_dtype == np.float32 else (8e-2, 2e-2, 0.12)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(_subblock_ref(*args), np.float32),
+                               atol=fwd_tol, rtol=fwd_tol)
     np.testing.assert_allclose(np.asarray(lval, np.float32),
-                               np.asarray(jl, np.float32),
-                               rtol=2e-3 if np_dtype == np.float32 else 2e-2)
+                               np.asarray(jl, np.float32), rtol=loss_rtol)
     for g, jg_i in zip(grads, jg):
         np.testing.assert_allclose(np.asarray(g, np.float32),
-                                   np.asarray(jg_i, np.float32), **tol)
+                                   np.asarray(jg_i, np.float32),
+                                   atol=grad_tol, rtol=grad_tol)
 
 
 def test_subblock_megakernel_ragged_rows():
@@ -279,10 +289,9 @@ def test_planner_vmem_infeasibility():
                for d in log), log
 
 
-# forward-only verdicts, as the parent commit's cost model gave them: the
-# pair's terms exist only for a chain planned ahead of the pullback, so
-# these dicts must not move by a digit (decode, the prefill chunk, the
-# training shape scored forward-only, and the shapes the two older tests use)
+# the verdicts as the cost model gave them before the training pair came (PR
+# 29) and after it went (PR 30): these dicts must not move by a digit (decode,
+# the prefill chunk, the training shape, and the shapes the two older tests use)
 _FORWARD_ONLY_GOLDEN = {
     "decode32": ((32, 4096, 14336, 2), True, {
         "n_tokens": 32, "d_model": 4096, "d_ff": 14336, "flops": 11274289152,
@@ -338,76 +347,21 @@ _FORWARD_ONLY_GOLDEN = {
         "est_fused_us": 352.293, "est_saved_us": -6.884}),
 }
 
-# a chain scored with its backward: (shape) -> what the pair's model must
-# say. At the training cell's shape the kernel times are the ledger's (PR
-# 26, TPU v5 lite): 60.4 ms forward, 127.1 ms backward a layer.
-_PAIR_CASES = {
-    "train-cell": ((16384, 4096, 14336, 2), False, (60.4e3, 127.1e3)),
-    "train-llama2": ((16384, 4096, 11008, 2), False, None),
-    "prefill-rows": ((512, 4096, 14336, 2), False, None),
-    "one-row-block": ((128, 4096, 14336, 2), True, None),
-    "tiny": ((32, 64, 176, 4), False, None),
-}
-
-
-@pytest.mark.parametrize("case", list(_FORWARD_ONLY_GOLDEN) + [
-    f"pair:{k}" for k in _PAIR_CASES])
-def test_subblock_cost_forward_only_unchanged_and_pair_terms(case):
-    """One model, two inputs it can see. Forward-only (decode, prefill): the
-    parent's numbers to the last digit. Planned ahead of the pullback: the
-    forward and backward pair each side commits to — within 15% of the
-    ledger's kernel times at the training cell's shape, and rejected there."""
-    if not case.startswith("pair:"):
-        shape, decode, golden = _FORWARD_ONLY_GOLDEN[case]
-        assert cost_model.subblock_cost(*shape, decode=decode) == golden
-        assert cost_model.subblock_cost(*shape, decode=decode,
-                                        with_backward=False) == golden
-        return
-    shape, plans, measured = _PAIR_CASES[case[len("pair:"):]]
-    fwd_only = cost_model.subblock_cost(*shape)
-    c = cost_model.subblock_cost(*shape, with_backward=True)
-    assert c["with_backward"] is True and "with_backward" not in fwd_only
-    # the pair's totals are the sums of its parts, the objective their gap
-    assert c["est_fused_us"] == pytest.approx(
-        c["est_fused_fwd_us"] + c["est_fused_bwd_us"], abs=2e-3)
-    assert c["est_unfused_us"] == pytest.approx(
-        c["est_unfused_fwd_us"] + c["est_unfused_bwd_us"], abs=2e-3)
-    assert c["est_saved_us"] == pytest.approx(
-        c["est_unfused_us"] - c["est_fused_us"], abs=2e-3)
-    # XLA's forward is the forward-only estimate; everything not an
-    # estimate (shape, VMEM staging, the interior bytes) is shared
-    assert c["est_unfused_fwd_us"] == fwd_only["est_unfused_us"]
-    for k in ("flops", "saved_boundary_bytes", "vmem_bytes_per_step",
-              "vmem_feasible", "flop_us", "boundary_us"):
-        assert c[k] == fwd_only[k]
-    n, d, f, s = shape
-    assert c["recomputed_flops"] == 10 * 2 * n * d * f   # 28 NDF for 18
-    row_blocks = -(-n // cost_model.SUBBLOCK_ROW_BLOCK)
-    ff_blocks = -(-f // min(cost_model.SUBBLOCK_FF_BLOCK, f))
-    assert c["restreamed_bytes"] == (
-        (2 * row_blocks - 2) * 3 * d * f * s + (ff_blocks - 1) * 2 * n * d * s)
-    assert cost_model.subblock_profitable(c) is plans
-    if measured is not None:
-        fwd_us, bwd_us = measured
-        assert abs(c["est_fused_fwd_us"] - fwd_us) / fwd_us < 0.15
-        assert abs(c["est_fused_bwd_us"] - bwd_us) / bwd_us < 0.15
-        assert c["est_saved_us"] < -50e3            # ~70 ms a layer
-        assert cost_model.subblock_profitable(fwd_only)   # what planned it
+@pytest.mark.parametrize("case", list(_FORWARD_ONLY_GOLDEN))
+def test_subblock_cost_forward_only_unchanged(case):
+    """The one scoring of an MLP chain, forward-only (decode, prefill): the
+    numbers of the commit before PR 29, to the last digit."""
+    shape, decode, golden = _FORWARD_ONLY_GOLDEN[case]
+    assert cost_model.subblock_cost(*shape, decode=decode) == golden
 
 
 @pytest.mark.parametrize("block_fusion", [None, True, False],
                          ids=["default", "forced", "off"])
-def test_train_step_scores_chain_with_its_backward(block_fusion):
-    """A ``tt.jit`` train step at a small shape: by default the chain is
-    scored with its backward, the decision carries the pair's terms and
-    nothing is planned in either direction; ``block_fusion=True`` still
-    plans both kernels; every variant matches ``block_fusion=False``."""
-    cfg = llama.CONFIGS["tiny"]
-    params = llama.init_params(cfg, seed=11, scale_layers=2)
-    rng = np.random.RandomState(11)
-    tokens = rng.randint(0, cfg.vocab_size, size=(2, 16)).astype(np.int32)
-    targets = np.roll(tokens, -1, 1).astype(np.int32)
-    step = _tiny_train_step(cfg)
+def test_train_step_plans_no_mlp_chain(block_fusion):
+    """A ``tt.jit`` train step under each of ``block_fusion``'s three values:
+    the planner has nothing to select under autodiff — no chain planned, no
+    ``block`` decision, no sub-block kernel — and all three match."""
+    step, params, tokens, targets = _tiny_train_inputs(11)
     kw = {} if block_fusion is None else {"block_fusion": block_fusion}
     observe.enable(clear=True)
     jf = tt.jit(step, executors=["pallas", "xla"], **kw)
@@ -423,62 +377,67 @@ def test_train_step_scores_chain_with_its_backward(block_fusion):
                                    np.asarray(b, np.float32),
                                    atol=5e-4, rtol=5e-4)
     names = _symbol_names(tt.last_execution_trace(jf))
-    dec = _block_decisions(jf)
-    if block_fusion is False:
-        assert not dec and n_fusions == 0
-        assert "pallas_mlp_subblock" not in names
-        return
-    # both layers' chains were seen once, at the pre-autodiff entry, and
-    # scored there as the pair; the second entry re-plans neither
-    assert len(dec) == 2, dec
-    pair_terms = {"with_backward", "restreamed_bytes", "recomputed_flops",
-                  "est_fused_fwd_us", "est_fused_bwd_us",
-                  "est_unfused_fwd_us", "est_unfused_bwd_us"}
-    assert all(pair_terms <= set(d["cost"]) for d in dec), dec
-    report = observe.explain(jf)
-    assert "with_backward=True" in report and "est_fused_bwd_us=" in report
-    if block_fusion is True:
-        assert [d["decision"] for d in dec] == ["planned"] * 2
-        assert n_fusions == 2
-        assert {"pallas_mlp_subblock", "pallas_mlp_subblock_bwd"} <= names
-    else:
-        assert [d["decision"] for d in dec] == ["cost-rejected"] * 2
-        assert all("scored with its backward" in d["reason"] for d in dec)
-        assert n_fusions == 0
-        assert not {"pallas_mlp_subblock", "pallas_mlp_subblock_bwd"} & names
-
-
-def test_residual_ledger_holds_a_pair_scored_chain_to_its_forward():
-    """The profiled region of a planned ``nn.mlp_subblock`` is the forward
-    kernel: the residual ledger compares it with the forward estimates of
-    a chain that was scored with its backward, not with the pair's sums."""
-    from thunder_tpu.observe import profile
-
-    cost = cost_model.subblock_cost(128, 4096, 14336, 2, with_backward=True)
-    dec = [{"kind": "block", "op": "nn.mlp_subblock", "decision": "planned",
-            "cost": cost, "region": "pallas:mlp_subblock#0"}]
-    prof = profile.StepProfile(
-        {"pallas:mlp_subblock#0": {"mean_us": 500.0, "total_us": 500.0,
-                                   "calls": 1}},
-        steps=1, mode="profiler", platform="test")
-    (rec,) = profile.residual_ledger(dec, prof)
-    assert rec["predicted_us"] == cost["est_fused_fwd_us"]
-    assert rec["est_unfused_us"] == cost["est_unfused_fwd_us"]
-    assert rec["status"] == "measured" and not rec["flipped"]
+    assert not [n for n in names if "mlp_subblock" in n], names
+    assert not _block_decisions(jf) and n_fusions == 0
+    assert "(none — no sub-block chains found" in observe.explain(jf)
+    assert (tt.last_execution_trace(jf).python()
+            == tt.last_execution_trace(plain).python())
 
 
 def test_inference_chain_is_scored_forward_only():
-    """The inference entry (``transform_for_execution``) plans no pullback:
-    its decision carries none of the pair's terms."""
+    """The planner's entry (``transform_for_execution``) scores a chain by
+    the forward-only byte objective, and the decision carries its terms."""
     args = _chain_inputs(np.float32, seed=5)
     jf = tt.jit(_chain, executors=["pallas", "xla"])
     jf(*args)
     (d,) = _block_decisions(jf)
     assert d["decision"] == "cost-rejected"
-    assert "with_backward" not in d["cost"]
     assert "saved boundary bytes lose" in d["reason"]
     assert d["cost"] == dict(cost_model.subblock_cost(16, 32, 48, 4),
                              chain=d["cost"]["chain"], act="silu", ops=8)
+
+
+def test_block_planner_is_entered_once_a_train_compile():
+    """One planner entry (``executors/passes.py``): a compile of a train
+    step opens exactly one ``block_fusion*`` span (the decode step's case is
+    in ``test_decode_layer.py``)."""
+    observe.enable(clear=True)
+    step, params, tokens, targets = _tiny_train_inputs(12)
+    tt.jit(step, executors=["pallas", "xla"])(params, tokens, targets)
+    spans = [sp["name"] for sp in observe.get_registry().spans
+             if sp["name"].startswith("block_fusion")]
+    observe.disable()
+    assert spans == ["block_fusion"], spans
+
+
+def test_training_pair_is_gone():
+    """No ``nn.mlp_subblock_bwd`` op is registered, ``pallasex`` exports no
+    backward for the sub-block, and ``nn.mlp_subblock`` has no VJP rule."""
+    from thunder_tpu.core import transforms
+    from thunder_tpu.executors import pallasex
+    from thunder_tpu.ops import _opsym_registry, get_op, nn as tnn
+
+    assert get_op("nn.mlp_subblock") is not None
+    assert not [k for k in _opsym_registry if "mlp_subblock_bwd" in str(k)]
+    assert not hasattr(tnn, "mlp_subblock_bwd")
+    assert not [n for n in dir(pallasex) if "mlp_subblock_bwd" in n]
+    assert "nn.mlp_subblock" not in transforms._vjp_rules
+
+
+def test_autodiff_imports_nothing_from_the_planner():
+    """``core/transforms.py`` (autodiff) imports nothing from
+    ``core/fusion_passes``: the pipeline is a straight line."""
+    import ast
+    import inspect
+
+    from thunder_tpu.core import transforms
+
+    for node in ast.walk(ast.parse(inspect.getsource(transforms))):
+        if isinstance(node, ast.ImportFrom):
+            assert "fusion_passes" not in (node.module or ""), ast.dump(node)
+            assert not [a for a in node.names if a.name == "fusion_passes"]
+        elif isinstance(node, ast.Import):
+            assert not [a for a in node.names if "fusion_passes" in a.name]
 
 
 def test_planner_decisions_use_registered_kinds_only():
@@ -498,26 +457,27 @@ def test_planner_decisions_use_registered_kinds_only():
 # tiny-llama train trace: fusion shape + parity (the acceptance path)
 # ---------------------------------------------------------------------------
 
-def _tiny_train_step(cfg):
-    def train_step(params, tokens, targets):
-        loss, grads = tt.value_and_grad(
-            lambda p: llama.loss_fn(p, tokens, targets, cfg))(params)
-        return loss, grads
+def _tiny_train_inputs(seed):
+    """The two-layer tiny-Llama train step and one batch for it."""
+    cfg = llama.CONFIGS["tiny"]
+    params = llama.init_params(cfg, seed=seed, scale_layers=2)
+    rng = np.random.RandomState(seed)
+    tokens = rng.randint(0, cfg.vocab_size, size=(2, 16)).astype(np.int32)
+    targets = np.roll(tokens, -1, 1).astype(np.int32)
 
-    return train_step
+    def train_step(params, tokens, targets):
+        return tt.value_and_grad(
+            lambda p: llama.loss_fn(p, tokens, targets, cfg))(params)
+
+    return train_step, params, tokens, targets
 
 
 def test_llama_train_step_block_planner_shape_and_parity():
-    """The planner emits one claimed megakernel per layer (forward AND
-    backward) on the tiny-llama train trace, numerics match the unplanned
-    trace, every verdict is visible in observe.explain(), and the planned
-    trace does not regress the region count."""
-    cfg = llama.CONFIGS["tiny"]
-    params = llama.init_params(cfg, seed=7, scale_layers=2)
-    rng = np.random.RandomState(7)
-    tokens = rng.randint(0, cfg.vocab_size, size=(2, 16)).astype(np.int32)
-    targets = np.roll(tokens, -1, 1).astype(np.int32)
-    step = _tiny_train_step(cfg)
+    """The two-layer tiny-Llama train step under ``block_fusion=True``: no
+    MLP chain is planned (the planner's entry comes after autodiff and the
+    chain is prim-level there), numerics match ``block_fusion=False``, the
+    report says so, and the region count is no higher."""
+    step, params, tokens, targets = _tiny_train_inputs(7)
 
     planned = tt.jit(step, executors=["pallas", "xla"], block_fusion=True)
     plain = tt.jit(step, executors=["pallas", "xla"], block_fusion=False)
@@ -530,21 +490,17 @@ def test_llama_train_step_block_planner_shape_and_parity():
                                    atol=5e-4, rtol=5e-4)
 
     trc = tt.last_execution_trace(planned)
-    # one forward + one backward megakernel per layer
-    assert _count_symbols(trc, "mlp_subblock") >= 2
-    assert "pallas_mlp_subblock" in _symbol_names(trc)
-    assert "pallas_mlp_subblock_bwd" in _symbol_names(trc)
+    assert _count_symbols(trc, "mlp_subblock") == 0
+    assert not [n for n in _symbol_names(trc) if "mlp_subblock" in n]
     n_planned = sum(1 for b in trc.bound_symbols
                     if str(b.sym.id).startswith("xla.fusion"))
     n_plain = sum(1 for b in tt.last_execution_trace(plain).bound_symbols
                   if str(b.sym.id).startswith("xla.fusion"))
     assert n_planned <= n_plain, (n_planned, n_plain)
 
-    dec = _block_decisions(planned)
-    assert sum(1 for d in dec if d["decision"] == "planned") == 2, dec
+    assert not _block_decisions(planned)
     report = observe.explain(planned)
-    assert "block planner" in report
-    assert "planned" in report
+    assert "block planner (0 candidate chains)" in report
 
 
 def test_planner_counter_and_marker_inference():

@@ -103,7 +103,7 @@ def gqa_model():
 # ---------------------------------------------------------------------------
 
 def test_decode_trace_plans_and_chains_by_default(gqa_model):
-    """At the bench_serve --smoke geometry the T==1 decode trace plans the
+    """At a tiny serving geometry the T==1 decode trace plans the
     attention sub-block, chains it with the MLP megakernel into
     nn.decode_layer under the DEFAULT cost model (no block_fusion forcing),
     and the compiled decode step dispatches <= 2 Pallas launches per layer
@@ -234,6 +234,29 @@ def test_megakernel_parity_vs_decomposition(model):
             np.testing.assert_allclose(np.asarray(f_kv[key]),
                                        np.asarray(p_kv[key]),
                                        atol=2e-5, rtol=2e-5)
+
+
+def test_block_planner_is_entered_once_a_decode_compile():
+    """One planner entry (``executors/passes.py``): a compile of a decode
+    step opens exactly one ``block_fusion*`` span (the train step's case is
+    in ``test_block_planner.py``)."""
+    from thunder_tpu.serving.runner import PagedLlamaRunner
+
+    cfg = llama.CONFIGS["tiny"]
+    params = jax.device_put(llama.init_params(cfg, seed=12, scale_layers=1))
+    geom, tokens, bt, lengths, write_pos, pools = _decode_inputs(
+        cfg, 1, S=2, npg=2, seed=12)
+    runner = PagedLlamaRunner(cfg, geom, n_layers=1, block_fusion=True)
+    observe.enable(clear=True)
+    runner.decode_jit(params, tokens, bt, lengths, write_pos, pools,
+                      np.zeros(2, np.float32), np.zeros(2, np.int32),
+                      np.ones(2, np.float32), np.zeros((2, 2), np.uint32))
+    spans = [sp["name"] for sp in observe.get_registry().spans
+             if sp["name"].startswith("block_fusion")]
+    observe.disable()
+    assert "pallas_decode_layer" in _symbol_names(
+        tt.last_execution_trace(runner.decode_jit))
+    assert spans == ["block_fusion"], spans
 
 
 def test_engine_tokens_identical_to_generate(gqa_model):

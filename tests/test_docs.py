@@ -5,6 +5,8 @@ the reference's notebooks/zero_to_thunder.ipynb — but executed in CI)."""
 import os
 import re
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DOC = os.path.join(REPO, "docs", "zero_to_thunder_tpu.md")
 KERNELS_DOC = os.path.join(REPO, "KERNELS.md")
@@ -298,3 +300,34 @@ def test_block_planner_decision_kinds_documented():
     assert not stale, (
         "KERNELS.md planner-decisions table documents kinds the planner "
         f"no longer registers: {stale}")
+
+
+# a repo-relative Python path as a document writes it: under one of our
+# directories, or a bare file name at the top level. The reference's own
+# ``thunder/...`` paths are not ours and are skipped by the look-behind, as
+# is a quoted file name a user would choose (``execution_file="prog.py"``).
+_PY_PATH = re.compile(
+    r"(?<![\w/.\"-])((?:thunder_tpu|benchmark|tests|examples)/[\w/.-]*?\.py"
+    r"|[A-Za-z_]\w*\.py)\b")
+
+
+@pytest.mark.parametrize("doc", ["README.md", "KERNELS.md",
+                                 "docs/zero_to_thunder_tpu.md"])
+def test_named_python_paths_exist(doc):
+    """Every repo-relative ``*.py`` path a document names exists in the
+    tree: a script that goes takes its prose with it. A bare file name
+    (``pallasex.py``) may live anywhere under our directories."""
+    with open(os.path.join(REPO, doc)) as f:
+        text = f.read()
+    named = set(_PY_PATH.findall(text))
+    assert named, f"{doc} names no Python file?"
+    basenames = set()
+    for top in ("thunder_tpu", "benchmark", "tests", "examples"):
+        for _, _, files in os.walk(os.path.join(REPO, top)):
+            basenames.update(f for f in files if f.endswith(".py"))
+    basenames.update(f for f in os.listdir(REPO) if f.endswith(".py"))
+    missing = sorted(
+        p for p in named
+        if not (os.path.exists(os.path.join(REPO, p)) if "/" in p
+                else p in basenames))
+    assert not missing, f"{doc} names Python files that are gone: {missing}"

@@ -131,10 +131,11 @@ _COMPOSITE_GRAD_EXEMPT_REASONED = {
     "nn.decode_layer": "inference-only whole-decode-layer composite (the "
                        "chaining stage's unit) — serving decode traces are "
                        "never differentiated",
-    "nn.mlp_subblock_bwd": "backward half of the block planner's megakernel "
-                           "pair (emitted by the nn.mlp_subblock VJP rule); "
-                           "differentiating it is second-order autodiff, "
-                           "like nn.sdpa_bwd",
+    "nn.mlp_subblock": "a serving composite (the block planner builds it on "
+                       "inference traces only); no VJP rule: differentiates "
+                       "through its decomposition, grads verified vs jax "
+                       "autodiff in test_block_planner.py::"
+                       "test_subblock_megakernel_backward_parity",
     "sentinel.observe_grads": "identity marker tagging grads for the numerics "
                               "guard — consumes DETACHED grads strictly after "
                               "the backward; stripped by the guard transform "

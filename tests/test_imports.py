@@ -55,3 +55,25 @@ def test_observe_package_exports():
                  "export_jsonl", "export_chrome_trace", "export_prometheus",
                  "span", "inc", "set_gauge", "event"):
         assert callable(getattr(observe, attr)), attr
+
+
+def test_only_the_benchmark_reads_bench_environment_variables():
+    """One yardstick: no Python file of the library or at the repo root
+    reads a ``BENCH_*`` / ``SERVE_*`` environment variable — those were the
+    pre-benchmark timing scripts' 36 knobs. ``benchmark/`` keeps its own."""
+    import re
+
+    repo = os.path.dirname(os.path.dirname(thunder_tpu.__file__))
+    reads = re.compile(r"environ[^\n]*[\"'](?:BENCH|SERVE)_[A-Z0-9_]*[\"']"
+                       r"|getenv\([\"'](?:BENCH|SERVE)_")
+    paths = [os.path.join(repo, f) for f in os.listdir(repo) if f.endswith(".py")]
+    for top in ("thunder_tpu", "examples"):
+        for dirpath, _, files in os.walk(os.path.join(repo, top)):
+            paths += [os.path.join(dirpath, f) for f in files if f.endswith(".py")]
+    assert len(paths) > 50
+    found = []
+    for path in paths:
+        with open(path) as f:
+            found += [f"{os.path.relpath(path, repo)}: {m.group(0)}"
+                      for m in reads.finditer(f.read())]
+    assert not found, found

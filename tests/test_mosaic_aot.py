@@ -102,9 +102,8 @@ def test_linear_act_and_mlp_subblock_every_activation(v5e):
     wn, wg, wd = sds((256,)), sds((384, 256)), sds((256, 384))
     for act in sorted(px._ACT_IMPLS):       # exact GELU needs erf in-kernel
         _compile(v5e, functools.partial(px.pallas_linear_act, act=act), x, w, b)
-        _compile(v5e, functools.partial(px.pallas_mlp_subblock_bwd, act=act),
-                 x, x, x, wn, wg, wg, wd)
-    _compile(v5e, px.pallas_mlp_subblock, x, x, wn, wg, wg, wd)
+        _compile(v5e, functools.partial(px.pallas_mlp_subblock, act=act),
+                 x, x, wn, wg, wg, wd)
 
 
 def _decode_operands(H, KV, S=8, D=256, hd=128, ps=16, npg=4, F=384,

@@ -587,8 +587,8 @@ def test_jsonl_export_emits_labeled_records(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_bench_metric_names_exist_after_compile():
-    """bench.py reads these registry names; renaming them must fail a test,
-    not silently zero the bench JSON."""
+    """Dashboards and the docs name these registry entries; renaming them
+    must fail a test, not silently zero a reading."""
     observe.enable(clear=True)
     train_step, params, opt_state, tokens, targets = _tiny_llama_step()
     jstep = tt.jit(train_step, horizontal_fusion=True)
@@ -603,7 +603,7 @@ def test_fused_optimizer_decisions_logged(monkeypatch):
     """Satellite of the r6 fused multi-tensor AdamW: every bucket verdict —
     accept with the byte-model numbers, or reject with the gate that refused
     — lands in CompileStats.last_decisions, and the accepted buckets bump
-    the fusion.optimizer_buckets counter bench.py reads."""
+    the fusion.optimizer_buckets counter."""
     monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
     from thunder_tpu.optim import AdamW
     from thunder_tpu.models import llama

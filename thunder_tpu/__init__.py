@@ -75,8 +75,8 @@ _DEFAULT_COMPILATION_CACHE = _os.path.join(
 def enable_compilation_cache(directory: str | None = None, *,
                              min_compile_secs: float = 1.0) -> str:
     """Place JAX's persistent compilation cache and return the directory in
-    use — the ONE helper every entry point (chip_smoke.py, the bench
-    scripts, tests/conftest.py, ``ElasticTrainer``) shares.
+    use — the ONE helper every entry point (chip_smoke.py,
+    benchmark/run.py, tests/conftest.py, ``ElasticTrainer``) shares.
 
     ``JAX_COMPILATION_CACHE_DIR`` set in the environment: JAX's own handling
     owns the cache — nothing is configured here, whatever ``directory``
@@ -705,8 +705,7 @@ class ThunderTPUFunction:
     def _compile(self, flat, treedef, args, kwargs) -> CacheEntry:
         from thunder_tpu.core.compile_data import CompileContext, compile_context
 
-        self._compile_ctx = CompileContext(self.compile_options,
-                                           executors=self.executors)
+        self._compile_ctx = CompileContext(self.compile_options)
         with compile_context(self._compile_ctx):
             return self._compile_inner(flat, treedef, args, kwargs)
 

@@ -306,8 +306,8 @@ def overlap_projection(entry: dict, *, spec=V5P) -> dict:
     measured ``recv_bytes_per_device_hlo`` (2.2x on the r5 7B run) back to
     the trace ring-model expectation — the ICI term is re-folded from
     ``recv_bytes_per_device_trace``. Pure arithmetic on the committed
-    metrics (no chips): the model recorded here is the prediction the
-    queued ONCHIP_AB.md pin A/B measures against."""
+    metrics (no chips): the model recorded here is a prediction no chip run
+    has tested."""
     recv_pinned = int(entry["recv_bytes_per_device_trace"])
     recv_hlo = int(entry["recv_bytes_per_device_hlo"])
     proj = project({"t_math_s": entry["t_math_s"],

@@ -17,17 +17,13 @@ _compile_ctx: ContextVar = ContextVar("thunder_tpu_compile_ctx", default=None)
 
 class CompileContext:
     """Holds the options passed to ``jit`` plus the registry of queries made
-    by passes during compilation. ``executors`` is the compiling function's
-    resolved executor stack — trace-time passes that must probe claimability
-    BEFORE ``transform_for_execution`` (the pre-autodiff block planner)
-    read it from here."""
+    by passes during compilation."""
 
-    __slots__ = ("options", "queried", "executors")
+    __slots__ = ("options", "queried")
 
-    def __init__(self, options: dict[str, Any], executors: Any = None):
+    def __init__(self, options: dict[str, Any]):
         self.options = dict(options)
         self.queried: dict[str, str] = {}  # name -> description
-        self.executors = executors
 
 
 class compile_context:
@@ -42,10 +38,6 @@ class compile_context:
     def __exit__(self, *exc):
         _compile_ctx.reset(self.token)
         return False
-
-
-def get_compile_data() -> CompileContext | None:
-    return _compile_ctx.get()
 
 
 def get_compile_option(name: str, description: str, default: Any = None) -> Any:

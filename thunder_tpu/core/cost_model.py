@@ -403,26 +403,19 @@ def fused_adamw_profitable(n_tensors: int, total_bytes: int) -> bool:
 #    megakernel keeps them in VMEM. The byte saving must beat the fused
 #    path's launch overhead and its (modeled) MXU-efficiency handicap vs
 #    XLA's own GEMM scheduling.
-# 3. What the verdict commits to. A chain planned ahead of the pullback is a
-#    PAIR of programs: the forward kernel and nn.mlp_subblock_bwd, whose two
-#    passes recompute the forward's GEMMs (22 NDF of backward where the
-#    mathematics needs 12) — and every row block of the forward and of pass 1
-#    streams all three weight matrices again. Such a chain is scored as that
-#    pair against XLA's forward and backward (subblock_cost(with_backward=
-#    True)); a forward-only chain is scored as before.
-SUBBLOCK_XLA_EFFICIENCY = 0.84    # measured-class: 251.8 ms dense region vs
-                                  # its 210.5 ms roofline (BENCH_BREAKDOWN r5)
+#
+# Only inference traces reach the planner (decode steps, prefill chunks): a
+# train step's MLP GEMMs are XLA's, forward and backward (ledger, PR 29).
+SUBBLOCK_XLA_EFFICIENCY = 0.84    # an estimate of XLA's forward and backward
+                                  # GEMMs built on it read 14% high at 16,384
+                                  # rows x 4096 x 14336: ~118 ms a layer where
+                                  # the chip took ~103 (ledger, PR 29)
 SUBBLOCK_FUSED_EFFICIENCY = 0.80  # modeled, and about right on the chip: the
-                                  # kernels run at 0.80-0.85 of what their OWN
-                                  # structure allows (ledger, PR 26, at 16,384
-                                  # rows x 4096 x 14336: forward 60.4 ms where
-                                  # its re-streamed weights need 55, backward
-                                  # 127.1 where its 22 NDF need 107-134). What
-                                  # the chip's 47% of the roofline measures is
-                                  # that structure — weights streamed again for
-                                  # every row block, the forward's GEMMs done
-                                  # twice more in the backward — not this
-                                  # number; subblock_cost charges it as such
+                                  # forward kernel ran at 0.80-0.85 of what its
+                                  # OWN structure allows (ledger, PR 26, at
+                                  # 16,384 rows x 4096 x 14336: 60.4 ms where
+                                  # its weights, streamed again for every row
+                                  # block, need 55)
 SUBBLOCK_LAUNCH_OVERHEAD_US = 8.0  # dispatch + pipeline fill (v5e, as adamw)
 # kernel tile budgets — the SINGLE source of truth: executors/pallasex.py
 # imports these for the megakernel's actual block picks, so the feasibility
@@ -445,8 +438,7 @@ def subblock_vmem_bytes(d_model: int, d_ff: int, dtype_bytes: int,
 
 
 def subblock_cost(n_tokens: int, d_model: int, d_ff: int,
-                  dtype_bytes: int, decode: bool = False,
-                  with_backward: bool = False) -> dict:
+                  dtype_bytes: int, decode: bool = False) -> dict:
     """Score one MLP sub-block chain for megakernel planning. Returns the
     decision-log dict: VMEM feasibility, the saved-boundary-bytes objective,
     and est_unfused/fused_us under the efficiency constants above.
@@ -458,16 +450,7 @@ def subblock_cost(n_tokens: int, d_model: int, d_ff: int,
     ``DECODE_UNFUSED_LAUNCHES_MLP`` launches — the launch amortization that
     makes decode-layer fusion win where the byte objective alone would lose
     at serving row counts. A forward-only chain at many rows (``decode=False``:
-    the prefill ladder) keeps the pure byte objective, weights counted once.
-
-    ``with_backward=True`` scores a chain that will be differentiated (the
-    planner sets it at its pre-autodiff entry, where a ``planned`` verdict
-    also commits the backward to ``nn.mlp_subblock_bwd``): the estimates are
-    of the forward AND backward programs of each side, see
-    :func:`_subblock_pair_cost`. The forward-only byte objective planned the
-    training chain (+2.7 ms a layer at 16,384 rows x 4096 x 14336) and the
-    chip said otherwise: the kernels took 60.4 + 127.1 ms a layer there
-    (ledger, PR 26) where XLA's GEMMs need ~115."""
+    the prefill ladder) keeps the pure byte objective, weights counted once."""
     flops = 3 * 2 * n_tokens * d_model * d_ff  # gate + up + down GEMMs
     # interior values written+read once each between kernels in the unfused
     # program: normed (N*D), gate pre-act (N*F), up (N*F), swiglu product
@@ -497,84 +480,15 @@ def subblock_cost(n_tokens: int, d_model: int, d_ff: int,
             "vmem_feasible": vmem <= VMEM_BUDGET_BYTES,
             "est_unfused_us": round(unfused, 3), "est_fused_us": round(fused, 3),
             "est_saved_us": round(unfused - fused, 3)}
-    if with_backward:
-        cost.update(_subblock_pair_cost(n_tokens, d_model, d_ff, dtype_bytes,
-                                        unfused_fwd_us=unfused))
     return stamp_calibration(cost)
-
-
-def _subblock_pair_cost(n_tokens: int, d_model: int, d_ff: int,
-                        dtype_bytes: int, unfused_fwd_us: float) -> dict:
-    """The terms of a chain scored WITH its backward: what each side's
-    forward and backward programs cost, from the structure of the kernels in
-    ``executors/pallasex.py`` (grids of ``SUBBLOCK_ROW_BLOCK`` x
-    ``SUBBLOCK_FF_BLOCK`` tiles, the same numbers the kernels tile by).
-
-    Fused, each pass the larger of its GEMM time and its streamed bytes:
-
-    - forward, grid (row blocks, ff blocks): 6 NDF; every row block streams
-      the three weight matrices again — ``N / bn`` times ``3·D·F``.
-    - backward pass 1 (``dh``): recomputes gate and up, then dy and the two
-      dn products: 10 NDF; the weights again ``N / bn`` times.
-    - backward pass 2 (weight grads), grid (ff blocks, row blocks):
-      recomputes gate, up and dy AGAIN, then three weight-grad GEMMs:
-      12 NDF; every ff block streams the rows ``g`` and ``n`` again —
-      ``F / bf`` times ``2·N·D``.
-
-    That is 28 NDF where the mathematics needs 18. Unfused: XLA's forward as
-    the forward-only estimate has it, and a backward of 12 NDF that reads
-    the saved interiors once and round-trips their gradients.
-
-    Against the chip at (16384, 4096, 14336, bf16), TPU v5 lite (ledger,
-    PR 26, ``pallas_mlp_subblock`` / ``pallas_mlp_subblock_bwd``, 12 kernel
-    pairs of a six-step window): forward 60.4 ms, backward 127.1 — the
-    estimates say 55.6 and 134.3. The unfused side's ~118 ms is checked by
-    the whole step: PERF.md §6, PR 29."""
-    N, D, F, s = n_tokens, d_model, d_ff, dtype_bytes
-    bn = min(SUBBLOCK_ROW_BLOCK, N)
-    bf = min(SUBBLOCK_FF_BLOCK, F)
-    row_blocks, ff_blocks = -(-N // bn), -(-F // bf)
-    ndf_us = 2 * N * D * F / TPU_PEAK_FLOPS * 1e6      # one GEMM of the chain
-    bw_us_per_byte = 1.0 / (constant("ADAMW_HBM_GBPS") * 1e3)
-    launch = constant("SUBBLOCK_LAUNCH_OVERHEAD_US")
-    fused_eff = constant("SUBBLOCK_FUSED_EFFICIENCY")
-    weights, rows = 3 * D * F * s, N * D * s
-    fwd_bytes = row_blocks * weights + 3 * rows          # r, x in; out
-    dx_bytes = row_blocks * weights + 5 * rows           # g, r, x in; dh, n out
-    dw_bytes = ff_blocks * 2 * rows + 2 * weights        # g, n; weights, grads
-    fused_fwd = max(3 * ndf_us / fused_eff, fwd_bytes * bw_us_per_byte) + launch
-    fused_bwd = (max(5 * ndf_us / fused_eff, dx_bytes * bw_us_per_byte)
-                 + max(6 * ndf_us / fused_eff, dw_bytes * bw_us_per_byte)
-                 + 2 * launch)
-    # XLA's backward: g in and dh out (3 rows with the residual's), the
-    # weights read and their grads written, the forward's saved interiors
-    # read once, their gradients written and read once
-    interior = N * (3 * D + 3 * F) * s
-    unfused_bwd = (6 * ndf_us / constant("SUBBLOCK_XLA_EFFICIENCY")
-                   + (3 * rows + 2 * weights + 3 * interior) * bw_us_per_byte)
-    fused, unfused = fused_fwd + fused_bwd, unfused_fwd_us + unfused_bwd
-    return {"with_backward": True,
-            "restreamed_bytes": ((2 * row_blocks - 2) * weights
-                                 + (ff_blocks - 1) * 2 * rows),
-            "recomputed_flops": 10 * 2 * N * D * F,      # 28 NDF done, 18 needed
-            "est_fused_fwd_us": round(fused_fwd, 3),
-            "est_fused_bwd_us": round(fused_bwd, 3),
-            "est_unfused_fwd_us": round(unfused_fwd_us, 3),
-            "est_unfused_bwd_us": round(unfused_bwd, 3),
-            "est_unfused_us": round(unfused, 3), "est_fused_us": round(fused, 3),
-            "est_saved_us": round(unfused - fused, 3)}
 
 
 def subblock_profitable(cost: dict) -> bool:
     """Plan the chain? VMEM-infeasible never plans; otherwise the estimate
-    of what the verdict commits to must come out ahead: ``est_saved_us > 0``.
-    Tiny traces lose on the 8 µs launch term alone. A decode chain wins on
-    the launches it amortizes, a forward-only chain at prefill rows on its
-    interior bytes. A chain scored with its backward at training rows loses
-    — at 16,384 x 4096 x 14336 by ~70 ms a layer, which is what the chip
-    read: 187.5 ms of kernels a layer (ledger, PR 26) against ~115 for XLA's
-    GEMMs — and wins only where the weights are streamed once (a row block
-    or so of rows). ``block_fusion=True/False`` overrides per-compile."""
+    must come out ahead: ``est_saved_us > 0``. Tiny traces lose on the 8 µs
+    launch term alone. A decode chain wins on the launches it amortizes, a
+    chain at prefill rows on its interior bytes.
+    ``block_fusion=True/False`` overrides per-compile."""
     return bool(cost["vmem_feasible"]) and cost["est_saved_us"] > 0.0
 
 

@@ -614,20 +614,6 @@ def _record_block(decision: str, reason: str, cost: dict | None,
     _decisions.record("block", op, None, decision, reason, cost=cost)
 
 
-def _mlp_reject_reason(cost: dict) -> str:
-    """Why the cost model turned an MLP sub-block chain down, in the terms
-    it was scored in (``cost_model.subblock_cost``)."""
-    if not cost.get("with_backward"):
-        return ("saved boundary bytes lose to launch overhead + modeled "
-                "MXU-efficiency handicap (need est_saved_us > 0)")
-    return ("scored with its backward: the kernels' forward + backward "
-            f"({cost['est_fused_fwd_us']:.0f} + {cost['est_fused_bwd_us']:.0f}"
-            f" us; {cost['restreamed_bytes'] >> 20} MiB re-streamed, "
-            f"{cost['recomputed_flops'] / 1e9:.1f} GFLOP recomputed) lose to "
-            f"XLA's ({cost['est_unfused_fwd_us']:.0f} + "
-            f"{cost['est_unfused_bwd_us']:.0f} us) (need est_saved_us > 0)")
-
-
 def _plain_linear(b: BoundSymbol):
     """(input, weight) for a bias-free single-GEMM ``nn.linear``, else None.
     A bias add, TP collective, or fp8 path adds subsymbols; such linears are
@@ -652,8 +638,7 @@ def _chain_act(b: BoundSymbol) -> str | None:
     return act
 
 
-def block_fusion_pass(trc: TraceCtx, executors,
-                      with_backward: bool = False) -> TraceCtx:
+def block_fusion_pass(trc: TraceCtx, executors) -> TraceCtx:
     """The block-level megakernel planner (ROADMAP item 3 / FlashFuser-class
     fusion scale), three staged dataflow walks:
 
@@ -673,14 +658,12 @@ def block_fusion_pass(trc: TraceCtx, executors,
        stream chains into one ``nn.decode_layer`` composite: one Pallas
        launch per layer per decoded token.
 
-    MLP planning runs at two points (pre-autodiff on the loss sub-trace via
-    ``plan_blocks_for_autodiff`` so the VJP rule fires, and in
-    ``transform_for_execution`` for inference traces); the attention and
-    chaining stages only ever fire on decode traces (their anchor,
-    ``nn.paged_decode_attention`` at T==1, cannot appear under autodiff).
-    The pre-autodiff entry passes ``with_backward=True``: a verdict there
-    also commits the pullback to ``nn.mlp_subblock_bwd``, so the chain is
-    scored as the forward and backward pair (``cost_model.subblock_cost``).
+    The pass has one entry, ``transform_for_execution``, after autodiff, and
+    plans inference traces only: a differentiated trace's linears are
+    prim-level by then, so the MLP walk finds no chain in a train step (its
+    GEMMs are XLA's: ledger, PR 29), and the attention and chaining stages'
+    anchor, ``nn.paged_decode_attention`` at T==1, cannot appear under
+    autodiff. ``block_fusion`` has nothing to select there.
 
     Every verdict — chain found, boundary chosen, VMEM-infeasible,
     cost-rejected, escape-blocked, chained — lands in
@@ -695,8 +678,9 @@ def block_fusion_pass(trc: TraceCtx, executors,
         "block_fusion",
         "plan whole transformer sub-block chains into single claimed "
         "megakernels (nn.mlp_subblock / nn.attn_subblock, chained into "
-        "nn.decode_layer on the T==1 serving path): True = always (skips "
-        "the cost/VMEM gates), False = never, unset = cost-model decision",
+        "nn.decode_layer on the T==1 serving path) in inference traces: "
+        "True = always (skips the cost/VMEM gates), False = never, unset = "
+        "cost-model decision; a chain under autodiff is never planned",
         None)
     if enabled is False or not executors:
         return trc
@@ -709,7 +693,7 @@ def block_fusion_pass(trc: TraceCtx, executors,
         "rung, never to per-op XLA)",
         None)
     trc = _attn_block_pass(trc, executors, enabled)
-    trc = _mlp_block_pass(trc, executors, enabled, with_backward)
+    trc = _mlp_block_pass(trc, executors, enabled)
     if tp_shards is not None and int(tp_shards) > 1:
         # record the cap only on traces that reached the chainable rung —
         # an attention sub-block anchor means _decode_chain_pass would
@@ -725,14 +709,12 @@ def block_fusion_pass(trc: TraceCtx, executors,
     return _decode_chain_pass(trc, executors, enabled)
 
 
-def _mlp_block_pass(trc: TraceCtx, executors, enabled,
-                    with_backward: bool) -> TraceCtx:
-    """The MLP sub-block walk (stage 2 of :func:`block_fusion_pass`);
-    ``with_backward``: the trace will be differentiated after this pass."""
+def _mlp_block_pass(trc: TraceCtx, executors, enabled) -> TraceCtx:
+    """The MLP sub-block walk (stage 2 of :func:`block_fusion_pass`)."""
     bsyms = trc.bound_symbols
     # cheap anchor scan: the chain needs a composite-level rms_norm AND
     # composite-level linears (post-autodiff traces are prim-level for the
-    # linears, and their chains were already planned pre-autodiff)
+    # linears: a train step has no chain to plan)
     ids = {b.sym.id for b in bsyms}
     if "nn.rms_norm" not in ids or "nn.linear" not in ids:
         return trc
@@ -875,7 +857,7 @@ def _mlp_block_pass(trc: TraceCtx, executors, enabled,
             for p in (residual, xx) if p.name in producer)
         cost = dict(cost_model.subblock_cost(
             n_tokens, int(w_gate.shape[1]), int(w_gate.shape[0]),
-            h.dtype.bytes, decode=decode_ctx, with_backward=with_backward),
+            h.dtype.bytes, decode=decode_ctx),
             chain=h.name, act=act, ops=len(chain))
         # --- verdicts (phase 2) --------------------------------------------
         # exclusivity: every interior value must be consumed ONLY inside the
@@ -907,7 +889,10 @@ def _mlp_block_pass(trc: TraceCtx, executors, enabled,
                           "budget", cost)
             continue
         if enabled is not True and not cost_model.subblock_profitable(cost):
-            _record_block("cost-rejected", _mlp_reject_reason(cost), cost)
+            _record_block("cost-rejected",
+                          "saved boundary bytes lose to launch overhead + "
+                          "modeled MXU-efficiency handicap (need "
+                          "est_saved_us > 0)", cost)
             continue
         comp_args = (residual, xx, w_norm, w_gate, w_up, w_down)
         comp_kwargs = {"act": act, "eps": eps}
@@ -929,15 +914,10 @@ def _mlp_block_pass(trc: TraceCtx, executors, enabled,
                            f"chain planned as one megakernel "
                            f"({cost['saved_boundary_bytes'] >> 10} KiB of "
                            f"interior traffic kept in VMEM)")
-        if enabled is True:
-            why = "forced by block_fusion=True"
-        elif with_backward:
-            why = ("cost model: the forward and backward kernels beat XLA's "
-                   "pair (weights streamed once)")
-        else:
-            why = ("cost model: interior-byte saving beats the fused-path "
-                   "overheads")
-        _record_block("planned", why, cost)
+        _record_block("planned",
+                      "forced by block_fusion=True" if enabled is True
+                      else "cost model: interior-byte saving beats the "
+                           "fused-path overheads", cost)
         _observe.inc("fusion.block_fusions")
         replacements[fi] = repl
         dropped.update(chain - {fi})
@@ -1446,26 +1426,6 @@ def _decode_chain_pass(trc: TraceCtx, executors, enabled) -> TraceCtx:
         return trc
     return _rebuild_trace(trc, replacements, dropped,
                           f"Decode-layer chaining ({n_chained} layers)")
-
-
-def plan_blocks_for_autodiff(trc: TraceCtx) -> TraceCtx:
-    """Pre-autodiff planner entry (called by ``inline_value_and_grad`` /
-    ``forward_and_backward_from_trace`` on the loss sub-trace, BEFORE the
-    pullback replay): resolves the compiling function's executor stack from
-    the compile context and runs :func:`block_fusion_pass`, so planned
-    composites hit their VJP rule and stay claimable in both directions.
-    A verdict taken here is a verdict on the backward too, and the pass is
-    told so (``with_backward=True``): the cost model scores the pair.
-    Outside a compile (no context, e.g. direct trace manipulation in tests)
-    this is a no-op."""
-    from thunder_tpu.core.compile_data import get_compile_data
-
-    ctx = get_compile_data()
-    executors = getattr(ctx, "executors", None) if ctx is not None else None
-    if not executors:
-        return trc
-    with _observe.span("block_fusion_pre_autodiff"):
-        return block_fusion_pass(trc, executors, with_backward=True)
 
 
 def epilogue_fusion_pass(trc: TraceCtx, executors) -> TraceCtx:

@@ -369,14 +369,6 @@ def inline_value_and_grad(fn, argnums=0, has_aux: bool = False):
         check(get_tracectx() is not None,
               "inline_value_and_grad must run under tracing (wrap with thunder_tpu.jit)")
         inner, inner_inputs, _ = _trace_subfn(fn, args, kwargs)
-        # block-level megakernel planning BEFORE the pullback replay: planned
-        # nn.mlp_subblock composites hit their VJP rule below, so the forward
-        # stays one claimable megakernel and the backward emits the
-        # equally-claimable nn.mlp_subblock_bwd (post-autodiff passes would
-        # be too late — the chain's interiors are saved-for-backward by then)
-        from thunder_tpu.core.fusion_passes import plan_blocks_for_autodiff
-
-        inner = plan_blocks_for_autodiff(inner)
         # env: inner input proxies -> actual outer values (same flatten order)
         flat_actual, _ = tree_flatten((args, kwargs))
         env: dict = {}
@@ -421,11 +413,7 @@ def forward_and_backward_from_trace(trc: TraceCtx) -> tuple[TraceCtx, TraceCtx, 
     ``(outputs, saved_for_backward)`` and a backward trace
     ``(saved_for_backward..., cotangents...) -> grads_of_inputs``."""
     from thunder_tpu import ops
-    from thunder_tpu.core.fusion_passes import plan_blocks_for_autodiff
 
-    trc = plan_blocks_for_autodiff(trc)  # same pre-autodiff planning as
-    # inline_value_and_grad: megakernel composites must exist before the
-    # pullback replay for their VJP rule to fire
     fwd = from_trace(trc)
     fwd.fn_name = "augmented_forward"
     env: dict = {Variable(p): p for p in trc.args}

@@ -553,7 +553,7 @@ def _report(groups, order, new_pos, pairs, sched_stats) -> None:
             # (est_transfer_us) so the residual ledger joins measured
             # issue->wait windows against the ICI model; recv_bytes is the
             # fit component observe.calibrate regresses ICI_BW_BYTES_PER_S /
-            # COLLECTIVE_LAUNCH_US against (the ONCHIP_AB.md B6 harness)
+            # COLLECTIVE_LAUNCH_US against
             cost=_cm.stamp_calibration(
                 {"issue_at": new_pos[src], "wait_at": new_pos[wg],
                  "distance": new_pos[wg] - new_pos[src],

@@ -54,8 +54,8 @@ class DistPrimIDs(Enum):
 # The pinned lowering feeds each sharded collective through
 # ``jax.lax.optimization_barrier`` — the same pin ``regather`` uses against
 # CSE — so the collective the trace scheduled is the collective XLA emits.
-# Default ON; ``pin_collectives(False)`` is the A/B escape hatch for the
-# on-chip measurement queued in ONCHIP_AB.md. The census's
+# Default ON; ``pin_collectives(False)`` is the A/B escape hatch for an
+# on-chip measurement nobody has made yet (ROADMAP S5). The census's
 # ``reduce-scatter-rewritten`` finding verifies the pin per compile.
 _PIN_STATE = {"enabled": True}
 

@@ -1121,10 +1121,8 @@ def _linear_act_checker(a, w, bias=None, act: str = "relu"):
 # with the weights STREAMED through the grid in d_ff blocks — h/n/acc live
 # in VMEM scratch for the row block, so none of the chain's interior values
 # (n, gate/up pre-activations, the SwiGLU product, the down projection)
-# ever round-trips HBM. The backward pair below applies the same recipe to
-# nn.mlp_subblock_bwd: recompute the interiors per tile (the flash-attention
-# memory contract), one pass producing dh (+ the normed rows for reuse), a
-# second accumulating the weight grads across the row grid dimension.
+# ever round-trips HBM. Forward only: a serving kernel (decode steps and
+# prefill chunks); a train step's MLP GEMMs are XLA's (ledger, PR 29).
 # ---------------------------------------------------------------------------
 
 # tile budgets are owned by core/cost_model.py: the planner's
@@ -1138,24 +1136,6 @@ from thunder_tpu.core.cost_model import (  # noqa: E402
     decode_pages_per_block,
     decode_subblock_pages_per_block,
 )
-
-
-def _act_grad_f32(act: str, a):
-    """d act(a)/da on an f32 tile (closed forms; mirrors ops.nn._act_grad)."""
-    if act == "relu":
-        return (a > 0).astype(jnp.float32)
-    if act == "silu":
-        sig = jax.nn.sigmoid(a)
-        return sig * (1.0 + a * (1.0 - sig))
-    if act == "gelu":
-        cdf = 0.5 * (1.0 + _erf_f32(a / math.sqrt(2.0)))
-        pdf = jnp.exp(-0.5 * a * a) / math.sqrt(2.0 * math.pi)
-        return cdf + a * pdf
-    c = math.sqrt(2.0 / math.pi)  # gelu_tanh
-    u = c * (a + 0.044715 * a * a * a)
-    t = jnp.tanh(u)
-    du = c * (1.0 + 3.0 * 0.044715 * a * a)
-    return 0.5 * (1.0 + t) + 0.5 * a * (1.0 - t * t) * du
 
 
 def _mlp_subblock_kernel(r_ref, x_ref, wn_ref, wg_ref, wu_ref, wd_ref, o_ref,
@@ -1249,154 +1229,6 @@ def _mlp_subblock_call(residual, x, w_norm, w_gate, w_up, w_down, act, eps,
     return out.reshape(orig_shape)
 
 
-def _mlp_subblock_bwd_dx_kernel(g_ref, r_ref, x_ref, wn_ref, wg_ref, wu_ref,
-                                wd_ref, dh_ref, n_ref, dwn_ref,
-                                xhat_ref, rr_ref, dn_ref, *, act: str,
-                                eps: float, nf: int, cast):
-    """Backward pass 1: dh for the row block (plus the recomputed normed
-    rows, written out once for pass 2, and per-row-block partials of the
-    norm-weight grad). The inner ff grid dimension accumulates
-    dn = dgpre @ wg + dup @ wu into scratch; the final step runs the
-    rms-norm backward — which needs the WHOLE dn row — and emits dh."""
-    f = pl.program_id(1)
-
-    @pl.when(f == 0)
-    def _init():
-        h32 = (r_ref[...] + x_ref[...]).astype(jnp.float32)
-        ms = jnp.mean(h32 * h32, axis=-1, keepdims=True)
-        rr = jax.lax.rsqrt(ms + eps)
-        xhat = h32 * rr
-        xhat_ref[...] = xhat
-        rr_ref[...] = rr
-        n_ref[...] = (xhat.astype(cast) * wn_ref[...]).astype(n_ref.dtype)
-        dn_ref[...] = jnp.zeros_like(dn_ref)
-
-    n = n_ref[...]
-    gpre = jax.lax.dot_general(n, wg_ref[...], (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    u = jax.lax.dot_general(n, wu_ref[...], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    dy = jax.lax.dot_general(g_ref[...], wd_ref[...], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    dga = dy * u
-    dup = (dy * _ACT_IMPLS[act](gpre)).astype(cast)
-    dgpre = (dga * _act_grad_f32(act, gpre)).astype(cast)
-    dn_ref[...] += (
-        jax.lax.dot_general(dgpre, wg_ref[...], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        + jax.lax.dot_general(dup, wu_ref[...], (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32))
-
-    @pl.when(f == nf - 1)
-    def _finalize():
-        dn = dn_ref[...]
-        xhat = xhat_ref[...]
-        dwn_ref[0] = jnp.sum(dn * xhat, axis=0, keepdims=True)
-        gxhat = dn * wn_ref[...].astype(jnp.float32)
-        proj = jnp.mean(gxhat * xhat, axis=-1, keepdims=True)
-        dh = g_ref[...].astype(jnp.float32) + rr_ref[...] * (gxhat - xhat * proj)
-        dh_ref[...] = dh.astype(dh_ref.dtype)
-
-
-def _mlp_subblock_bwd_dw_kernel(g_ref, n_ref, wg_ref, wu_ref, wd_ref,
-                                dwg_ref, dwu_ref, dwd_ref,
-                                dwg_acc, dwu_acc, dwd_acc, *, act: str,
-                                nr: int, cast):
-    """Backward pass 2: weight grads. Grid (ff_blocks, row_blocks), rows
-    innermost — each ff block's dwg/dwu/dwd slices accumulate across the
-    row stream in f32 scratch (the interiors are recomputed per tile from
-    the normed rows pass 1 wrote out)."""
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        dwg_acc[...] = jnp.zeros_like(dwg_acc)
-        dwu_acc[...] = jnp.zeros_like(dwu_acc)
-        dwd_acc[...] = jnp.zeros_like(dwd_acc)
-
-    n = n_ref[...]
-    g = g_ref[...]
-    gpre = jax.lax.dot_general(n, wg_ref[...], (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    ga = _ACT_IMPLS[act](gpre)
-    u = jax.lax.dot_general(n, wu_ref[...], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    dy = jax.lax.dot_general(g, wd_ref[...], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    dga = dy * u
-    dup = (dy * ga).astype(cast)
-    dgpre = (dga * _act_grad_f32(act, gpre)).astype(cast)
-    y = (ga.astype(cast) * u.astype(cast))
-    dwg_acc[...] += jax.lax.dot_general(dgpre, n, (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-    dwu_acc[...] += jax.lax.dot_general(dup, n, (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-    dwd_acc[...] += jax.lax.dot_general(g, y, (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    @pl.when(i == nr - 1)
-    def _finalize():
-        dwg_ref[...] = dwg_acc[...].astype(dwg_ref.dtype)
-        dwu_ref[...] = dwu_acc[...].astype(dwu_ref.dtype)
-        dwd_ref[...] = dwd_acc[...].astype(dwd_ref.dtype)
-
-
-def pallas_mlp_subblock_bwd(g, residual, x, w_norm, w_gate, w_up, w_down,
-                            act: str = "silu", eps: float = 1e-5):
-    orig_shape = x.shape
-    D = x.shape[-1]
-    N = x.size // D
-    F = w_gate.shape[0]
-    g2 = g.reshape(N, D)
-    r2 = residual.reshape(N, D)
-    x2 = x.reshape(N, D)
-    bn, bf = _subblock_grid(N, D, F)
-    grid1 = (N // bn, F // bf)
-    row1 = pl.BlockSpec((bn, D), lambda i, f: (i, 0))
-    wrow1 = pl.BlockSpec((bf, D), lambda i, f: (f, 0))
-    dh, n2, dwn_parts = pl.pallas_call(
-        functools.partial(_mlp_subblock_bwd_dx_kernel, act=act, eps=eps,
-                          nf=grid1[1], cast=x.dtype),
-        grid=grid1,
-        in_specs=[row1, row1, row1,
-                  pl.BlockSpec((D,), lambda i, f: (0,)),
-                  wrow1, wrow1,
-                  pl.BlockSpec((D, bf), lambda i, f: (0, f))],
-        # per-row-block norm-weight partials as (blocks, 1, D): the block's
-        # last two dims equal the array's, which is what Mosaic's tiling
-        # rule asks of a one-row block
-        out_specs=[row1, row1, pl.BlockSpec((1, 1, D), lambda i, f: (i, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((N, D), x.dtype),
-                   jax.ShapeDtypeStruct((N, D), x.dtype),
-                   jax.ShapeDtypeStruct((N // bn, 1, D), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bn, D), jnp.float32),
-                        pltpu.VMEM((bn, 1), jnp.float32),
-                        pltpu.VMEM((bn, D), jnp.float32)],
-        interpret=_interpret(), **_grid_params(planned_vmem=True),
-    )(g2, r2, x2, w_norm, w_gate, w_up, w_down)
-    dwn = jnp.sum(dwn_parts, axis=(0, 1)).astype(w_norm.dtype)
-
-    grid2 = (F // bf, N // bn)
-    row2 = pl.BlockSpec((bn, D), lambda f, i: (i, 0))
-    wrow2 = pl.BlockSpec((bf, D), lambda f, i: (f, 0))
-    dwg, dwu, dwd = pl.pallas_call(
-        functools.partial(_mlp_subblock_bwd_dw_kernel, act=act, nr=grid2[1],
-                          cast=x.dtype),
-        grid=grid2,
-        in_specs=[row2, row2, wrow2, wrow2,
-                  pl.BlockSpec((D, bf), lambda f, i: (0, f))],
-        out_specs=[wrow2, wrow2, pl.BlockSpec((D, bf), lambda f, i: (0, f))],
-        out_shape=[jax.ShapeDtypeStruct((F, D), w_gate.dtype),
-                   jax.ShapeDtypeStruct((F, D), w_up.dtype),
-                   jax.ShapeDtypeStruct((D, F), w_down.dtype)],
-        scratch_shapes=[pltpu.VMEM((bf, D), jnp.float32),
-                        pltpu.VMEM((bf, D), jnp.float32),
-                        pltpu.VMEM((D, bf), jnp.float32)],
-        interpret=_interpret(), **_grid_params(planned_vmem=True),
-    )(g2, n2, w_gate, w_up, w_down)
-    return dh.reshape(orig_shape), dwn, dwg, dwu, dwd
-
-
 def _mlp_subblock_checker(residual, x, w_norm, w_gate, w_up, w_down,
                           act: str = "silu", eps: float = 1e-5):
     if not _enabled() or act not in _ACT_IMPLS:
@@ -1434,14 +1266,6 @@ def _mlp_subblock_checker(residual, x, w_norm, w_gate, w_up, w_down,
     return (D % 128 == 0 and F % 128 == 0 and N % 8 == 0
             and subblock_vmem_bytes(int(D), F, x.dtype.bytes, N)
             <= VMEM_BUDGET_BYTES)
-
-
-def _mlp_subblock_bwd_checker(g, residual, x, w_norm, w_gate, w_up, w_down,
-                              act: str = "silu", eps: float = 1e-5):
-    if tuple(g.shape) != tuple(x.shape) or g.dtype != x.dtype:
-        return False
-    return _mlp_subblock_checker(residual, x, w_norm, w_gate, w_up, w_down,
-                                 act, eps)
 
 
 # ---------------------------------------------------------------------------
@@ -2453,21 +2277,14 @@ fused_adamw_slab_op = ex.register_operator(
 ex.register_implementation("optim.fused_adamw_slab", fused_adamw_slab_op,
                            checker=_fused_adamw_slab_checker)
 
-# block-planner megakernels: the whole MLP sub-block forward, and its
-# recompute-based backward pair (claimed from the composites the planner
-# / the nn.mlp_subblock VJP rule emit; no `profitable` hook — the
-# planner's cost model already decided)
+# block-planner megakernel: the whole MLP sub-block forward (claimed from
+# the composite the planner emits; no `profitable` hook — the planner's
+# cost model already decided)
 _mlp_sub_sym = get_op("nn.mlp_subblock")
-_mlp_sub_bwd_sym = get_op("nn.mlp_subblock_bwd")
 mlp_subblock_op = ex.register_operator(
     "mlp_subblock", meta=_mlp_sub_sym.meta, fn=pallas_mlp_subblock)
-mlp_subblock_bwd_op = ex.register_operator(
-    "mlp_subblock_bwd", meta=_mlp_sub_bwd_sym.meta,
-    fn=pallas_mlp_subblock_bwd)
 ex.register_implementation("nn.mlp_subblock", mlp_subblock_op,
                            checker=_mlp_subblock_checker)
-ex.register_implementation("nn.mlp_subblock_bwd", mlp_subblock_bwd_op,
-                           checker=_mlp_subblock_bwd_checker)
 
 _rms_res_sym = get_op("nn.rms_norm_residual")
 _linear_act_sym = get_op("nn.linear_act")

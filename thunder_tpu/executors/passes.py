@@ -151,9 +151,9 @@ def transform_for_execution(trc: TraceCtx, executors) -> TraceCtx:
     # claim walk to offer. The block planner goes FIRST — it wants whole
     # sub-block chains, which horizontal merging (gate+up GEMMs share the
     # normed activation) and epilogue fusion (add→rms_norm) would otherwise
-    # carve up. Training traces were already planned pre-autodiff (the chain
-    # is prim-level here and the anchor scan early-outs); this entry serves
-    # inference traces, whose composite-level chains survive to this pass.
+    # carve up. This is the planner's one entry: a train step's chain is
+    # prim-level here (the anchor scan early-outs, its GEMMs are XLA's); an
+    # inference trace's composite-level chains survive to this pass.
     with _observe.span("block_fusion"):
         trc = block_fusion_pass(trc, executors)
     with _observe.span("horizontal_fusion"):

@@ -322,12 +322,6 @@ def explain(jfn) -> str:
             detail.append(f"est_saved_us={cost['est_saved_us']}")
         if "vmem_bytes_per_step" in cost:
             detail.append(f"vmem_bytes_per_step={cost['vmem_bytes_per_step']}")
-        if cost.get("with_backward"):
-            # a chain planned ahead of the pullback: the pair's terms
-            detail += [f"{k}={cost[k]}" for k in (
-                "with_backward", "restreamed_bytes", "recomputed_flops",
-                "est_fused_fwd_us", "est_fused_bwd_us",
-                "est_unfused_fwd_us", "est_unfused_bwd_us")]
         suffix = f" ({', '.join(detail)})" if detail else ""
         # the planner plans three composite kinds (nn.mlp_subblock,
         # nn.attn_subblock, nn.decode_layer) — name the op per line

@@ -444,12 +444,9 @@ def residual_ledger(decisions, prof: StepProfile) -> list:
             "kind": d.get("kind"), "op": d.get("op"),
             "decision": d.get("decision"), "region": d.get("region"),
             "platform": prof.platform,
-            # a chain scored with its backward carries the pair's totals
-            # under est_*_us; the region joined below is its FORWARD kernel
-            "predicted_us": cost.get("est_fused_fwd_us", cost.get(
-                "est_fused_us", cost.get("transfer_us"))),
-            "est_unfused_us": cost.get("est_unfused_fwd_us",
-                                       cost.get("est_unfused_us")),
+            "predicted_us": cost.get("est_fused_us",
+                                     cost.get("transfer_us")),
+            "est_unfused_us": cost.get("est_unfused_us"),
             "measured_us": None, "residual_us": None, "residual_pct": None,
             "flipped": False, "status": "unattributed",
         }

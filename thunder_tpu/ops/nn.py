@@ -128,9 +128,9 @@ def mlp_subblock(residual, x, w_norm, w_gate, w_up, w_down, *,
     the quarantine/bisection machinery recompiles to); the Pallas executor
     claims it as a single streamed-weight kernel that keeps every interior
     value (n, the gate/up pre-activations, the SwiGLU product) in VMEM.
-    The VJP rule below keeps it claimable under autodiff: only the INPUTS
-    are saved and the backward recomputes the interiors, flash-style, via
-    the equally-claimable ``nn.mlp_subblock_bwd``.
+    It is a serving kernel: the planner builds it on inference traces only,
+    and it has no VJP rule — under autodiff it differentiates through this
+    decomposition like any other composite (XLA's GEMMs, both directions).
     """
     _tensor_like(x, "mlp_subblock")
     check(tuple(residual.shape) == tuple(x.shape) and residual.dtype == x.dtype,
@@ -143,99 +143,6 @@ def mlp_subblock(residual, x, w_norm, w_gate, w_up, w_down, *,
     gate = _LINEAR_ACT_FNS[act](ops.linear(n, w_gate))
     up = ops.linear(n, w_up)
     return ops.add(h, ops.linear(ops.mul(gate, up), w_down))
-
-
-@opsymbol(id="nn.mlp_subblock_bwd")
-def mlp_subblock_bwd(g, residual, x, w_norm, w_gate, w_up, w_down, *,
-                     act: str = "silu", eps: float = 1e-5):
-    """Backward of :func:`mlp_subblock` from the saved INPUTS only:
-    recomputes the forward interiors (the flash-attention memory contract
-    applied to the MLP sub-block) and returns
-    ``(dh, dw_norm, dw_gate, dw_up, dw_down)`` where ``dh`` is the
-    cotangent of BOTH ``residual`` and ``x`` (they are summands of the
-    same ``h``). Claimable by the Pallas executor as the backward
-    megakernel pair; unclaimed, this decomposition is the exact chain
-    rule over the unfused ops."""
-    check(act in _SUBBLOCK_ACTS,
-          lambda: f"mlp_subblock_bwd: unknown activation {act!r}")
-    dt = x.dtype
-    wide = dtypes.float32 if dt in (dtypes.float16, dtypes.bfloat16) else dt
-    h = ops.add(residual, x)
-    h32 = ops.convert_element_type(h, wide)
-    ms = ops.mean(ops.mul(h32, h32), -1, keepdim=True)
-    r = ops.rsqrt(ops.add(ms, eps))
-    xhat = ops.mul(h32, r)                      # pre-weight normalized rows
-    n = ops.mul(ops.convert_element_type(xhat, dt), w_norm)
-    gpre = ops.linear(n, w_gate)
-    ga = _LINEAR_ACT_FNS[act](gpre)
-    up = ops.linear(n, w_up)
-    y = ops.mul(ga, up)
-
-    g32 = ops.convert_element_type(g, wide)
-    # out = h + y @ w_down.T
-    dy = ops.convert_element_type(
-        prims.dot_general(g, w_down, contract_dims=((g.ndim - 1,), (0,))), dt)
-    N = 1
-    for d in g.shape[:-1]:
-        N *= int(d)
-    g2 = ops.reshape(g, (N, g.shape[-1]))
-    y2 = ops.reshape(y, (N, y.shape[-1]))
-    dw_down = ops.convert_element_type(
-        prims.dot_general(g2, y2, contract_dims=((0,), (0,)),
-                          preferred_element_type=wide), w_down.dtype)
-    dga = ops.mul(dy, up)
-    dup = ops.mul(dy, ga)
-    dgpre = ops.mul(dga, _act_grad(act, gpre))
-    # dn = dgpre @ w_gate + dup @ w_up
-    dn = ops.add(
-        prims.dot_general(dgpre, w_gate, contract_dims=((dgpre.ndim - 1,), (0,))),
-        prims.dot_general(dup, w_up, contract_dims=((dup.ndim - 1,), (0,))))
-    dgpre2 = ops.reshape(dgpre, (N, dgpre.shape[-1]))
-    dup2 = ops.reshape(dup, (N, dup.shape[-1]))
-    n2 = ops.reshape(n, (N, n.shape[-1]))
-    dw_gate = ops.convert_element_type(
-        prims.dot_general(dgpre2, n2, contract_dims=((0,), (0,)),
-                          preferred_element_type=wide), w_gate.dtype)
-    dw_up = ops.convert_element_type(
-        prims.dot_general(dup2, n2, contract_dims=((0,), (0,)),
-                          preferred_element_type=wide), w_up.dtype)
-    # rms_norm backward (same math as the nn.rms_norm VJP rule)
-    dn32 = ops.convert_element_type(dn, wide)
-    dw_norm = None
-    if w_norm is not None and isinstance(w_norm, TensorProxy):
-        lead = tuple(range(x.ndim - 1))
-        dwn = ops.mul(dn32, xhat) if not lead else ops.sum(ops.mul(dn32, xhat), lead)
-        dw_norm = ops.convert_element_type(dwn, w_norm.dtype)
-        gxhat = ops.mul(dn32, ops.convert_element_type(w_norm, wide))
-    else:
-        gxhat = dn32
-    proj = ops.mean(ops.mul(gxhat, xhat), -1, keepdim=True)
-    dh_norm = ops.mul(r, ops.sub(gxhat, ops.mul(xhat, proj)))
-    dh = ops.convert_element_type(ops.add(g32, dh_norm), dt)
-    return dh, dw_norm, dw_gate, dw_up, dw_down
-
-
-def _act_grad(act: str, a):
-    """d act(a) / d a, in ``a``'s dtype (traced ops)."""
-    if act == "relu":
-        return ops.convert_element_type(ops.gt(a, 0.0), a.dtype)
-    if act == "silu":
-        sig = ops.sigmoid(a)
-        return ops.mul(sig, ops.add(1.0, ops.mul(a, ops.sub(1.0, sig))))
-    if act == "gelu":
-        # Φ(a) + a·φ(a)
-        phi_cdf = ops.mul(ops.add(ops.erf(ops.mul(a, 1.0 / math.sqrt(2.0))), 1.0), 0.5)
-        pdf = ops.mul(ops.exp(ops.mul(ops.mul(a, a), -0.5)), 1.0 / math.sqrt(2.0 * math.pi))
-        return ops.add(phi_cdf, ops.mul(a, pdf))
-    check(act == "gelu_tanh", lambda: f"_act_grad: unknown activation {act!r}")
-    c = math.sqrt(2.0 / math.pi)
-    a2 = ops.mul(a, a)
-    u = ops.mul(ops.add(a, ops.mul(ops.mul(a2, a), 0.044715)), c)
-    t = ops.tanh(u)
-    sech2 = ops.sub(1.0, ops.mul(t, t))
-    du = ops.mul(ops.add(1.0, ops.mul(a2, 3.0 * 0.044715)), c)
-    return ops.add(ops.mul(ops.add(t, 1.0), 0.5),
-                   ops.mul(ops.mul(ops.mul(a, sech2), du), 0.5))
 
 
 @opsymbol(id="nn.dropout")
@@ -688,29 +595,6 @@ def _rms_norm_vjp(a, weight=None, eps: float = 1e-5, dim: int = -1):
             lead = tuple(range(a.ndim - 1))
             dw = ops.mul(g32, xhat) if not lead else ops.sum(ops.mul(g32, xhat), lead)
             pairs.append((weight, ops.convert_element_type(dw, weight.dtype)))
-        return pairs
-
-    return out, pullback
-
-
-@register_vjp("nn.mlp_subblock")
-def _mlp_subblock_vjp(residual, x, w_norm, w_gate, w_up, w_down, *,
-                      act: str = "silu", eps: float = 1e-5):
-    """Keep the planned sub-block megakernel claimable under autodiff: the
-    forward stays the ONE ``nn.mlp_subblock`` composite (saving only its
-    inputs), and the pullback emits the equally-claimable
-    ``nn.mlp_subblock_bwd`` — forward and backward are each a single
-    Pallas-claimable unit, and neither materializes the chain's interior
-    activations outside VMEM (the sdpa fwd/bwd memory contract applied to
-    the MLP sub-block)."""
-    out = mlp_subblock(residual, x, w_norm, w_gate, w_up, w_down, act=act, eps=eps)
-
-    def pullback(g):
-        dh, dwn, dwg, dwu, dwd = mlp_subblock_bwd(
-            g, residual, x, w_norm, w_gate, w_up, w_down, act=act, eps=eps)
-        pairs = [(residual, dh), (x, dh), (w_gate, dwg), (w_up, dwu), (w_down, dwd)]
-        if w_norm is not None and isinstance(w_norm, TensorProxy):
-            pairs.append((w_norm, dwn))
         return pairs
 
     return out, pullback

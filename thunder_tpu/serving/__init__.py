@@ -54,9 +54,10 @@ The layers (ROADMAP item 1 + the serving containment story):
 >>> req = sup.submit(prompt_ids, max_new_tokens=32, deadline_s=30.0)
 >>> sup.drain(); req.output()
 
-``bench_serve.py`` at the repo root is the committed throughput benchmark
-(requests/s and aggregate decode tokens/s at a latency SLO; ``--overload``
-measures shedding and SLO attainment past capacity).
+The benchmark's serving cells (``benchmark/run.py --workload
+mistral7b_serve_decode_sat`` / ``mistral7b_serve_chat``) drive this engine:
+output tokens/s at full slots, TTFT and inter-token latency under an open
+Poisson loop.
 """
 
 from thunder_tpu.serving.events import EVENT_KINDS  # noqa: F401

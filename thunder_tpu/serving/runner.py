@@ -276,9 +276,8 @@ class PagedLlamaRunner:
         surface uses (``observe.census.trace_census`` — one owner, so the
         serving gauges and ``CompileStats.last_census`` can never disagree):
         how many Pallas launches one decode step dispatches, and how many
-        of them are whole-decode-layer megakernels. ``bench_serve.py``
-        stamps both; the fusion-shape acceptance test reads
-        launches-per-layer from them."""
+        of them are whole-decode-layer megakernels. The fusion-shape
+        acceptance test reads launches-per-layer from them."""
         import thunder_tpu as tt
         from thunder_tpu.observe import census as _census
         from thunder_tpu.observe import registry as _observe

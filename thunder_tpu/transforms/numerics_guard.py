@@ -36,9 +36,8 @@ the loss and new-state counts (grad norm reports 0).
 Cost note: the selects keep the OLD state live until the verdict, so XLA
 cannot alias donated parameter buffers into the update — the rollback
 guarantee costs up to one extra copy of the guarded state in peak memory
-plus the select bandwidth. ``bench.py`` measures the end-to-end step
-overhead as ``sentinel_overhead_pct`` so the price is tracked, not
-assumed. With ``donate_argnums`` set, a failing call still consumes its
+plus the select bandwidth (not measured on the chip: no benchmark cell
+runs the guard). With ``donate_argnums`` set, a failing call still consumes its
 input buffers, so in-process *bisection* cannot replay them — it
 escalates ``PersistentNonFinite`` to the supervisor (checkpoint restore)
 instead; jit without donation to enable in-process bisection.
