@@ -512,14 +512,18 @@ def serve_phase(preset: Preset, meter: CompileMeter, dev: dict,
                          size=preset.parity_prompt).astype(np.int32)
     req = eng.submit(prompt, preset.parity_tokens)
     rows, decode_ms = [], []
+    slot = None
     while not req.done:
-        slot = eng.slots.index(req) if req in eng.slots else None
         decoding = req.state == "decode"
         n_before = len(req.generated)
         t0 = time.perf_counter()
         if not eng.step():
             raise RuntimeError(f"[{label}] parity request stalled")
         dt = (time.perf_counter() - t0) * 1e3
+        if req in eng.slots:
+            # admitted, prefilled and decoded within one step: the slot is
+            # known only now (and is gone again once the request is done)
+            slot = eng.slots.index(req)
         if len(req.generated) > n_before:
             rows.append(np.asarray(eng.last_decode_logits[slot], np.float32))
             if decoding and n_before > 0:   # a pure decode step, warm

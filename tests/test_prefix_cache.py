@@ -458,7 +458,9 @@ class TestEnginePrefix:
         eng = _engine(params, cfg, max_slots=2)
         prim = eng.submit(p, 10, best_of=3,
                           sampling=SamplingParams(temperature=0.8, seed=5))
-        for _ in range(3):      # prefill + first clone fork + decode
+        # the first step prefills, forks the first clone into the free slot
+        # and decodes both replay rows; two more decode steps follow
+        for _ in range(3):
             eng.step()
         assert sum(r.state == "decode" for r in prim.fork_group) == 2
         assert len(prim.fork_pending) == 1
@@ -488,7 +490,8 @@ class TestEnginePrefix:
         eng = _engine(params, cfg, max_slots=4, prefix_cache=True)
         sup = EngineSupervisor(eng)
         prim = eng.submit(p, 8, sampling=sp, best_of=3)
-        # let the forks materialize (prefill + fork steps), THEN crash
+        # let the forks materialize (they do in the first step, behind the
+        # prefill) and decode a little, THEN crash
         for _ in range(4):
             sup.step()
         assert sum(r.state == "decode" for r in prim.fork_group) >= 2

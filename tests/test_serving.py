@@ -568,6 +568,182 @@ class TestServingEngine:
 
 
 # ---------------------------------------------------------------------------
+# an iteration is schedule -> prefill -> decode: a prompt that turns resident
+# rides the same iteration's decode step as its replay row
+# ---------------------------------------------------------------------------
+
+def _first_token_steps():
+    return {e["request"]: e["steps"]
+            for e in observe.get_registry().events
+            if e["kind"] == "serving_first_token"}
+
+
+def _generate(params, cfg, req):
+    """``generate()``'s greedy tokens for a request's prompt and budget."""
+    return np.asarray(llama.generate(params, cfg, req.prompt[None],
+                                     req.max_new_tokens, n_layers=1))[0]
+
+
+class TestPrefillBeforeDecode:
+    @pytest.fixture(scope="class")
+    def model(self):
+        cfg = llama.CONFIGS["tiny-gqa"]
+        return cfg, llama.init_params(cfg, seed=0, scale_layers=1)
+
+    def _prompt(self, cfg, n, seed):
+        return np.random.RandomState(seed).randint(
+            1, cfg.vocab_size, size=n).astype(np.int32)
+
+    def test_first_token_when_the_admitting_step_returns(self, model):
+        """An empty engine and one with a resident decoding request alike:
+        a request submitted before iteration k holds its first token when
+        that ``step()`` returns, the resident got its own token from the
+        same decode step, and the event says ``steps == 1``."""
+        cfg, params = model
+        observe.enable(clear=True)
+        try:
+            eng = _tiny_engine(params, cfg)
+            resident = eng.submit(self._prompt(cfg, 5, 0), 10)
+            eng.step()
+            assert len(resident.generated) == 1     # the first step's own
+            eng.step()
+            newcomer = eng.submit(self._prompt(cfg, 7, 1), 4)
+            two = eng.submit(self._prompt(cfg, 40, 2), 4)  # 32 + 16: a burst
+            eng.step()
+            assert len(newcomer.generated) == 1 and newcomer.state == "decode"
+            assert len(two.generated) == 1
+            assert len(resident.generated) == 3
+            eng.drain()
+            steps = _first_token_steps()
+        finally:
+            observe.disable()
+        assert steps == {resident.request_id: 1, newcomer.request_id: 1,
+                         two.request_id: 1}
+        for r in (resident, newcomer, two):
+            np.testing.assert_array_equal(r.output(),
+                                          _generate(params, cfg, r))
+        eng.assert_quiescent()
+
+    def test_a_chunk_an_iteration_under_a_well_filled_batch(self, model):
+        """More than half the slots decode: prefill advances one chunk an
+        iteration, read from the batch BEFORE the iteration's prefill, so a
+        3-chunk prompt has its first token after three steps — from the
+        step that ran its last chunk — and the event says ``steps == 3``."""
+        cfg, params = model
+        observe.enable(clear=True)
+        try:
+            eng = _tiny_engine(params, cfg, prefill_chunk=16)
+            residents = [eng.submit(self._prompt(cfg, 5, i), 20)
+                         for i in range(2)]
+            eng.step()
+            assert all(len(r.generated) == 1 for r in residents)
+            long = eng.submit(self._prompt(cfg, 40, 9), 3)  # 16 + 16 + 16
+            for chunks in (1, 2):
+                eng.step()
+                assert long.prefill_chunks == chunks
+                assert long.state == "prefill" and not long.generated
+            eng.step()
+            assert long.prefill_chunks == 3 and len(long.generated) == 1
+            assert all(len(r.generated) == 4 for r in residents)
+            eng.drain()
+            steps = _first_token_steps()
+        finally:
+            observe.disable()
+        assert steps[long.request_id] == 3
+        assert [steps[r.request_id] for r in residents] == [1, 1]
+        np.testing.assert_array_equal(long.output(),
+                                      _generate(params, cfg, long))
+
+    def test_scripted_run_keeps_every_requests_tokens(self, model):
+        """Fixed arrivals over a pool too small for full residency — greedy
+        and sampled requests, a best-of-3 fork, at least one preemption:
+        greedy tokens are ``generate()``'s, and a sampled stream is what
+        the same request draws alone on a fresh engine (a token depends on
+        its seed, its count and its logits, never on which iteration or
+        which batch sampled it)."""
+        from thunder_tpu.serving import SamplingParams
+
+        cfg, params = model
+        sp = SamplingParams(temperature=0.8, top_k=20, top_p=0.9, seed=42)
+        fork_sp = SamplingParams(temperature=0.7, top_k=30, seed=7)
+        script = {      # iteration -> [(prompt length, new tokens, kwargs)]
+            0: [(30, 8, {}), (28, 8, {"sampling": sp})],
+            1: [(20, 8, {})],
+            3: [(12, 6, {"sampling": fork_sp, "best_of": 3})],
+            6: [(9, 5, {}), (17, 5, {"sampling": sp})],
+        }
+        eng = _tiny_engine(params, cfg, max_slots=3, page_size=8,
+                           num_pages=12, prefill_chunk=16)
+        reqs, it = [], 0
+        while it <= max(script) or not eng.idle:
+            for n, new, kw in script.get(it, ()):
+                r = eng.submit(self._prompt(cfg, n, seed=100 + len(reqs)),
+                               new, **kw)
+                reqs.extend(r.fork_group or [r])
+            eng.step()
+            it += 1
+            assert it < 500
+        assert all(r.done for r in reqs)
+        assert any(r.preemptions for r in reqs)
+        assert sum(r.fork_parent is not None for r in reqs) == 2
+        for r in reqs:
+            if r.sampling.greedy:
+                want = _generate(params, cfg, r)
+            else:
+                alone = _tiny_engine(params, cfg, page_size=8,
+                                     prefill_chunk=16)
+                solo = alone.submit(r.prompt, r.max_new_tokens,
+                                    sampling=r.sampling)
+                alone.drain()
+                want = solo.output()
+            np.testing.assert_array_equal(r.output(), want)
+        eng.assert_quiescent()
+
+    def test_a_residents_next_page_outranks_a_newcomers_chunk(self, model):
+        """Four pages: the resident holds two and is about to need a third,
+        the newcomer's first chunk takes the other two at admission. The
+        resident's page is taken BEFORE any chunk runs: the newcomer is
+        requeued without a chunk computed for it in that iteration, the
+        resident keeps decoding, and both end with exact tokens."""
+        cfg, params = model
+        observe.enable(clear=True)
+        try:
+            eng = _tiny_engine(params, cfg, max_slots=2, page_size=8,
+                               num_pages=5, prefill_chunk=16)
+            resident = eng.submit(self._prompt(cfg, 14, 3), 8)
+            for _ in range(3):
+                eng.step()
+            # context 16 of 2 x 8: the next token needs a third page
+            assert resident.length == 16 and len(resident.pages) == 2
+            newcomer = eng.submit(self._prompt(cfg, 10, 4), 4)
+            eng.step()
+            crowded = eng._step_count
+            assert len(resident.generated) == 4 and len(resident.pages) == 3
+            assert newcomer.preemptions == 1 and newcomer.state == "queued"
+            assert not newcomer.prefill_chunks
+            eng.step()                  # one page free of the two it needs
+            assert newcomer.state == "queued"
+            assert len(resident.generated) == 5
+            eng.drain()
+            chunks = [(s["args"]["request"], s["args"]["step"])
+                      for s in observe.get_registry().spans
+                      if s["name"] == "prefill_chunk"]
+            steps = _first_token_steps()
+        finally:
+            observe.disable()
+        assert resident.preemptions == 0
+        mine = [step for rid, step in chunks if rid == newcomer.request_id]
+        assert len(mine) == 1 and mine[0] > crowded
+        # admitted again once the resident was done: that admission's own
+        # iteration gave the token
+        assert steps[newcomer.request_id] == 1
+        for r in (resident, newcomer):
+            np.testing.assert_array_equal(r.output(),
+                                          _generate(params, cfg, r))
+        eng.assert_quiescent()
+
+
+# ---------------------------------------------------------------------------
 # bind() + seq_buckets error names the serving path
 # ---------------------------------------------------------------------------
 

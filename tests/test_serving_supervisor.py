@@ -184,8 +184,9 @@ def test_drain_bounds_wall_clock_and_stops_admissions(model):
         eng = _engine(params, cfg)
         sup = EngineSupervisor(eng)
         r1 = sup.submit(np.ones(5, np.int32), 30)
-        sup.step()                               # admit + prefill
-        sup.step()                               # first-token replay decode
+        sup.step()              # admit, prefill, first-token replay decode
+        assert len(r1.generated) == 1
+        sup.step()              # one more decode step
         done = sup.drain(deadline_s=0.0)         # bound expires immediately
         snap = observe.snapshot()
     finally:

@@ -195,6 +195,23 @@ def _warm(eng):
     eng.completed.clear()
 
 
+def _tiling_leaves(root):
+    """The leaves of one ``engine_step`` in time order, checked to lie inside
+    it without overlap."""
+    by_id = {s["id"]: s for s in _spans()}
+    mine = sorted((s for s in _spans() if s["name"] in LEAVES
+                   and (s["parent"] == root["id"]
+                        or by_id.get(s["parent"], {}).get("parent") == root["id"])),
+                  key=lambda s: s["ts_us"])
+    assert mine, root
+    end = root["ts_us"]
+    for s in mine:
+        assert s["ts_us"] >= end - 0.5, (s["name"], "overlaps")
+        end = s["ts_us"] + s["dur_us"]
+    assert end <= root["ts_us"] + root["dur_us"] + 0.5
+    return mine
+
+
 def test_engine_step_leaves_cover_it(model):
     """Over N iterations: one ``engine_step`` root each; its leaves lie
     inside it and do not overlap; together they cover at least 95% of the
@@ -217,15 +234,7 @@ def test_engine_step_leaves_cover_it(model):
     covered = total = 0.0
     for root in roots:
         assert set(root["args"]) == {"step", "decoding", "queued"}
-        mine = [s for s in _spans() if s["name"] in LEAVES
-                and (s["parent"] == root["id"]
-                     or by_id.get(s["parent"], {}).get("parent") == root["id"])]
-        assert mine, root
-        end = root["ts_us"]
-        for s in sorted(mine, key=lambda s: s["ts_us"]):
-            assert s["ts_us"] >= end - 0.5, (s["name"], "overlaps")
-            end = s["ts_us"] + s["dur_us"]
-        assert end <= root["ts_us"] + root["dur_us"] + 0.5
+        mine = _tiling_leaves(root)
         covered += sum(s["dur_us"] for s in mine)
         total += root["dur_us"]
         names = Counter(s["name"] for s in mine)
@@ -248,11 +257,52 @@ def test_engine_step_leaves_cover_it(model):
             assert "request" in s["args"], s["name"]
 
 
+def test_busy_iteration_runs_schedule_prefill_decode(model):
+    """One iteration with a decoding resident and two arrivals (one of two
+    chunks, so the thin batch bursts three chunks): the leaves lie inside
+    ``engine_step`` without overlap and cover it, in the order schedule,
+    the prefill leaves a chunk at a time, the decode leaves; the root says
+    what the decode step ran; and the benchmark's reader finds each
+    arrival's ``prefill_wait`` inside ``schedule`` + ``prefill_build`` —
+    no decode step stands between an admission and its first chunk."""
+    eng = _engine(model, max_slots=16, max_context=512, n_layers=None)
+    _warm(eng)
+    resident = eng.submit(_prompt(9), 12)
+    eng.step()
+    observe.enable(clear=True)
+    arrivals = [eng.submit(_prompt(20, seed=1), 4),
+                eng.submit(_prompt(40, seed=2), 4)]
+    eng.step()
+    observe.disable()
+    assert [len(q.generated) for q in (resident, *arrivals)] == [2, 1, 1]
+    root = _one("engine_step")
+    assert root["args"]["decoding"] == 3 and root["args"]["queued"] == 0
+    mine = _tiling_leaves(root)
+    chunk = ["prefill_build", "prefill_chunk", "prefill_deliver"]
+    assert [s["name"] for s in mine] == ["schedule"] + 3 * chunk + [
+        "decode_build", "decode_enqueue", "decode_wait", "decode_deliver"]
+    assert sum(s["dur_us"] for s in mine) / root["dur_us"] >= 0.95
+    reg_ = observe.get_registry()
+    got = _phases_reader().phases(list(reg_.spans), list(reg_.events))
+    sched, decode_build = mine[0], mine[-4]
+    for q in arrivals:
+        first_chunk = min(s["ts_us"] for s in mine if s["name"] == "prefill_chunk"
+                          and s["args"]["request"] == q.request_id)
+        wait_us = got[q.request_id]["prefill_wait"] * 1e3
+        # admitted inside ``schedule``; its chunk starts as its build ends
+        assert 0 <= wait_us <= first_chunk - sched["ts_us"]
+        assert first_chunk < decode_build["ts_us"]
+    steps = {e["request"]: e["steps"] for e in reg_.events
+             if e["kind"] == "serving_first_token"}
+    assert steps == {q.request_id: 1 for q in arrivals}
+
+
 def test_registry_off_ring_gets_what_it_got_before(model):
     """The sub-phase spans are registry-only: with the registry off, a fixed
     scenario leaves in the flight ring exactly the records it left before
-    this tree existed (pinned on the parent commit), and the registry
-    nothing."""
+    this tree existed, less the one iteration the requests no longer wait
+    (both prompts prefill, and their replay rows decode, in the iteration
+    that admits them), and the registry nothing."""
     eng = _engine(model)
     _warm(eng)
     flight.clear()
@@ -264,7 +314,7 @@ def test_registry_off_ring_gets_what_it_got_before(model):
         steps += 1
     eng.step()
     eng.step()                      # idle polls add nothing
-    assert steps == 5
+    assert steps == 4
     recs = flight.snapshot()
     got = Counter((r["type"], r.get("kind") or r["name"].split(" ")[0])
                   for r in recs)
@@ -272,15 +322,15 @@ def test_registry_off_ring_gets_what_it_got_before(model):
         ("event", "serving_submitted"): 2, ("event", "serving_admitted"): 2,
         ("event", "serving_prefill_chunk"): 3,
         ("event", "serving_first_token"): 2, ("event", "serving_complete"): 2,
-        ("gauge", "serving.queue_depth"): 7,
-        ("gauge", "serving.active_requests"): 7,
-        ("gauge", "serving.kv_pages_free"): 7,
-        ("gauge", "serving.slo_attainment"): 7,
-        ("span", "schedule"): 5, ("span", "decode_dispatch"): 4,
+        ("gauge", "serving.queue_depth"): 6,
+        ("gauge", "serving.active_requests"): 6,
+        ("gauge", "serving.kv_pages_free"): 6,
+        ("gauge", "serving.slo_attainment"): 6,
+        ("span", "schedule"): 4, ("span", "decode_dispatch"): 4,
         ("span", "prefill_chunk"): 3, ("span", "queued"): 2,
         ("span", "prefill"): 2, ("span", "decode"): 2, ("span", "request"): 2,
     }), got
-    assert len(recs) == 59
+    assert len(recs) == 54
     assert not observe.get_registry().spans
     assert not observe.get_registry().events
 
@@ -396,6 +446,33 @@ def test_first_token_event_carries_the_resident_instant(model):
     assert e["resident_us"] == pytest.approx(
         prefill["ts_us"] + prefill["dur_us"], abs=50)
     assert e["resident_us"] < e["ts_us"]
+    assert e["steps"] == 1          # admitted, resident and decoded in one
+
+
+def test_explain_prints_the_share_of_first_tokens_without_a_wait(model):
+    """``serving_first_token`` carries ``steps``; the request timeline of
+    ``explain()`` prints the share with ``steps == 1`` from the always-on
+    ring: two residents fill the batch past half, so a 3-chunk prompt needs
+    three iterations and the three others one each."""
+    from thunder_tpu.observe.explain import _request_timeline_lines
+
+    eng = _engine(model, prefill_chunk=16)
+    _warm(eng)
+    flight.clear()
+    for i in range(2):
+        eng.submit(_prompt(5, seed=i), 20)
+    eng.step()
+    eng.submit(_prompt(40, seed=3), 2)      # 16 + 16 + 16 under a full batch
+    eng.drain()
+    eng.submit(_prompt(9, seed=4), 2)
+    eng.drain()
+    steps = sorted(r["steps"] for r in flight.snapshot()
+                   if r["type"] == "event"
+                   and r.get("kind") == "serving_first_token")
+    assert steps == [1, 1, 1, 3]
+    line = [ln for ln in _request_timeline_lines() if "own iteration" in ln]
+    assert line == ["  first token in its admission's own iteration: "
+                    "3 of 4 (75.0%)"]
 
 
 def test_prefill_ms_is_the_chunk_spans_length(model):

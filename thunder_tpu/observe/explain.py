@@ -55,6 +55,7 @@ def _request_timeline_lines() -> list[str]:
     chunks: dict[int, int] = {}
     info: dict[int, dict] = {}                    # rid -> lifecycle facts
     order: list[int] = []                         # by first appearance
+    first_steps: list[int] = []     # iterations, last admission -> token
 
     def _req(rid: int) -> dict:
         if rid not in info:
@@ -81,6 +82,8 @@ def _request_timeline_lines() -> list[str]:
             d = _req(int(r["request"]))
             if kind == "serving_first_token":
                 d["ttft_ms"] = r.get("ttft_ms")
+                if r.get("steps") is not None:
+                    first_steps.append(r["steps"])
             elif kind == "serving_complete":
                 d["terminal"] = f"done ({r.get('generated', '?')} tokens)"
             elif kind == "serving_shed":
@@ -107,6 +110,13 @@ def _request_timeline_lines() -> list[str]:
             parts.append(f"preempted x{d['preemptions']}")
         out.append(f"  req {rid}: " + ", ".join(parts)
                    + f" -> {d.get('terminal', 'in flight')}")
+    # how often a prompt's replay row rode the decode step of the very
+    # iteration that admitted it (``steps`` of ``serving_first_token``)
+    if first_steps:
+        same = sum(1 for n in first_steps if n == 1)
+        out.append(f"  first token in its admission's own iteration: {same} "
+                   f"of {len(first_steps)} "
+                   f"({100.0 * same / len(first_steps):.1f}%)")
     # sampled slot-occupancy histogram (the engine's active_requests gauge
     # time series lives in the ring even when the registry is off)
     occ: dict[int, int] = {}

@@ -21,10 +21,12 @@ The layers (ROADMAP item 1 + the serving containment story):
   step programs (``bind()``-dispatched decode; ``LengthBucketer``-laddered
   prefill chunks; ragged attention via ``nn.paged_decode_attention``,
   Pallas-claimed on TPU; sampling as the decode epilogue — prefill carries
-  no lm_head, first tokens ride a decode replay step).
+  no lm_head, first tokens ride a replay row of the decode step that
+  follows the last chunk in the same iteration).
 - :mod:`thunder_tpu.serving.scheduler` — admission (priority-ordered,
   optionally bounded, infeasibility-checked), deadline-aware continuous
-  batching with chunked prefill interleaving, mid-flight join/evict,
+  batching with chunked prefill interleaving (an iteration is schedule,
+  prefill, decode), mid-flight join/evict,
   page-pressure preemption, load shedding with typed errors
   (:mod:`~thunder_tpu.serving.errors`), ``serving:*``-domain retry, and
   the ``serving.*`` observe metrics.

@@ -20,9 +20,10 @@ process:
   size), writing the chunk's K/V into the request's pages and attending
   the paged context so far. Ragged prompt lengths compile at most
   ``len(ladder)`` prefill programs, ever. Prefill emits NO logits at all:
-  every request's first token comes from a decode REPLAY step (the
+  every request's first token comes from a decode REPLAY row (the
   scheduler re-feeds the last prompt token with the write redirected to
-  the scratch page), so the lm_head matmul leaves the prefill program
+  the scratch page, in the decode step that follows the last chunk within
+  the same engine iteration), so the lm_head matmul leaves the prefill program
   entirely and the first token is sampled on the exact same program path
   as every later one — which is what makes best-of-N forks and
   recompute-on-resume token-streams line up with the unforked path.
@@ -230,9 +231,9 @@ class PagedLlamaRunner:
         (1,) int32 = chunk_start + C (context including the padded chunk);
         page_writes (C//ps,) int32 flat positions of the chunk's pages.
         Returns the updated pools. The first token is sampled by a decode
-        REPLAY step after the final chunk lands, so prefill carries no
-        lm_head work at all (the old last-row logits slice is gone with
-        its host argmax)."""
+        REPLAY row of the same iteration's decode step, dispatched behind
+        the final chunk, so prefill carries no lm_head work at all (the
+        old last-row logits slice is gone with its host argmax)."""
         cfg = self.cfg
         g = self.geom
         C = tokens.shape[1]

@@ -19,10 +19,15 @@ different sequence lengths served by ONE compiled decode program.
   (``serving.deadline_misses``); shedding of any kind counts
   ``serving.shed_requests`` and the rolling on-time completion ratio is
   the ``serving.slo_attainment`` gauge.
-- **Decode-first with chunked prefill interleaving**: every engine
-  iteration runs one batched decode step over all resident requests, plus
-  at most ONE prefill chunk of the head-of-line prefilling request — long
-  prompts cannot stall in-flight decodes for more than a chunk.
+- **Prefill, then decode, with chunked prefill interleaving**: every engine
+  iteration admits, then runs at most ONE prefill chunk of the head-of-line
+  prefilling request while the decode batch is well filled (a burst while
+  it is thin), then one batched decode step over all resident requests —
+  the ones whose prompt just became resident included, so a request's
+  first token comes from the decode step of the iteration that finished
+  its prefill. Long prompts cannot stall in-flight decodes for more than a
+  chunk, and residents keep their rank when pages are short: a decoding
+  request's next page is taken before a newcomer's chunk allocates.
 - **Continuous batching**: requests join and leave the decode batch
   mid-flight. Completion (or EOS) frees the request's pages immediately;
   the slot admits the next queued request on the same compiled program.
@@ -55,21 +60,23 @@ different sequence lengths served by ONE compiled decode program.
   registry enabled, a busy iteration is one tree whose leaves do not
   overlap and together cover it::
 
-      engine_step                 root; args step, decoding, queued
-        schedule                  deadlines, forks, admission
-        decode_build              page growth, the batch arrays
+      engine_step                 root; args step, decoding (the rows the
+                                  decode step ran), queued
+        schedule                  deadlines, forks, admission, the decoding
+                                  requests' next pages
+        prefill_build             one chunk's arrays
+        prefill_chunk             the chunk's dispatch (the device runs it
+                                  behind the call: the ``decode_wait`` of
+                                  the same iteration holds that time)
+        prefill_deliver           the request's progress, its move to decode
+                                  once resident, the admission that may free
+        decode_build              the batch arrays
         decode_dispatch           ``live_pages`` of ``window_pages``: what
                                   the decode attention walks of the tables
           decode_enqueue          the call into the bound program until it
                                   returns (``step:serving_decode`` inside)
           decode_wait             the device wait and the token fetch
         decode_deliver            tokens to requests, finishes, page release
-        prefill_build             one chunk's arrays
-        prefill_chunk             the chunk's dispatch (the device runs it
-                                  behind the call: the next ``decode_wait``
-                                  holds that time)
-        prefill_deliver           the request's progress, its move to decode
-                                  once resident, the admission that may free
 
   ``schedule``, ``decode_dispatch`` and ``prefill_chunk`` feed the flight
   ring as well; the others exist only while the registry is enabled and
@@ -81,7 +88,11 @@ different sequence lengths served by ONE compiled decode program.
   ``prefill_chunk``; ``prefill`` = that start → the prompt resident
   (``resident_us`` of the ``serving_first_token`` event; a forked clone is
   resident when it forks); ``first_decode`` = resident → the event. The
-  four add up to ``serving.ttft_ms``.
+  four add up to ``serving.ttft_ms``. The event's ``steps`` counts the
+  iterations from that admission to the token: 1 when the prompt became
+  resident in the iteration that admitted it (a burst under a thin batch,
+  or a one-chunk prompt), one more for every further chunk under a
+  well-filled batch.
 
 - **In-graph sampling**: every request carries
   :class:`~thunder_tpu.serving.sampling.SamplingParams`; the compiled
@@ -90,10 +101,11 @@ different sequence lengths served by ONE compiled decode program.
   draw) and the scheduler reads token ids, never logits. Greedy is the
   ``temperature == 0`` degenerate case of the same program — bit-identical
   to the host argmax it replaced, so token-identity-vs-``generate()`` pins
-  hold. Every request's FIRST token comes from a decode *replay* step (the
+  hold. Every request's FIRST token comes from a decode *replay* row (the
   last prompt token re-fed with its K/V write redirected to the scratch
-  page), so prefill carries no lm_head at all and first tokens ride the
-  batched decode program like every other token.
+  page) of the decode step that follows its last chunk in the same
+  iteration, so prefill carries no lm_head at all and first tokens ride
+  the batched decode program like every other token.
 - **Best-of-N via copy-on-write forks**: ``submit(best_of=N)`` prefills
   ONCE; when the primary's prompt is resident, N-1 clones fork its block
   table — full pages shared by refcount, only the partial tail page
@@ -203,11 +215,12 @@ class Request:
     preemptions: int = 0
     restarts: int = 0                   # supervisor crash-recovery re-admits
     admit_seq: int = -1                 # admission order (preemption victim pick)
+    admit_step: int = 0                 # engine iteration of the last admission
     pages_version: int = 0              # bumped when ``pages`` changes
     # in-graph sampling: per-request params + derived uint32 stream seed
     sampling: SamplingParams = GREEDY
     stream_seed: int = 0
-    _replay: bool = False               # next decode step re-feeds the last
+    _replay: bool = False               # its next decode row re-feeds the last
     #                                     prompt token (write -> scratch) to
     #                                     sample the FIRST token in-graph
     # best-of-N copy-on-write forks
@@ -481,17 +494,21 @@ class ServingEngine:
         return req
 
     def step(self) -> bool:
-        """One engine iteration: expire deadlines, admit, one batched decode
-        step, prefill. Returns whether any scheduling progress was made
-        (False = idle — and ``drain()`` treats a no-progress step with work
-        remaining as a stall, not as quiet completion).
+        """One engine iteration: expire deadlines, admit, prefill, one
+        batched decode step. Returns whether any scheduling progress was
+        made (False = idle — and ``drain()`` treats a no-progress step with
+        work remaining as a stall, not as quiet completion).
 
-        Decode-first, chunked prefill interleaving: with a well-filled
-        decode batch, prefill advances ONE chunk per iteration (a long
-        prompt can only add one bounded chunk of latency between decode
-        steps); with a thin batch, prefill bursts so arriving requests
-        reach the decode batch quickly instead of trickling in one chunk
-        per decode step."""
+        Prefill before decode, chunked prefill interleaving: a prompt that
+        becomes resident in this iteration rides this iteration's decode
+        step as its replay row, so its first token is there when ``step()``
+        returns. With a well-filled decode batch, prefill advances ONE chunk
+        per iteration (a long prompt can only add one bounded chunk of
+        latency between decode steps); with a thin batch, prefill bursts so
+        arriving requests reach the decode batch quickly instead of
+        trickling in one chunk per decode step. Residents keep their rank
+        over newcomers when pages are short: every decoding request's next
+        page is taken (``_reserve_decode_pages``) before a chunk allocates."""
         self._step_count += 1
         busy = bool(self.queue) or self.active_requests > 0
         # one dict for every span of this iteration that carries the step only
@@ -500,7 +517,7 @@ class ServingEngine:
         # over leaves that do not overlap and together cover it
         with self.obs.span("engine_step", "serving:sched", ring=False) as root:
             # host-scheduling part of the iteration: deadlines, forks,
-            # admission
+            # admission, the residents' next pages
             with self.obs.span("schedule", "serving:sched",
                                self._step_args) as sched:
                 worked = self._expire_deadlines()
@@ -511,19 +528,23 @@ class ServingEngine:
                     if r is not None and r.fork_pending:
                         worked = self._materialize_forks(r) or worked
                 worked = self._admit() or worked
+                self._reserve_decode_pages()
                 if not busy:
                     # idle polling steps stay out of the flight ring — a
                     # long idle stretch must not flush the last incident's
                     # history out of the bounded ring
                     sched.cancel()
-            worked = self._decode_step() or worked
-            decoding = sum(1 for r in self.slots
+            # the chunk budget reads how full the decode batch was BEFORE
+            # this iteration's prefill added to it
+            resident = sum(1 for r in self.slots
                            if r is not None and r.state == DECODE)
-            budget = 1 if decoding > self.max_slots // 2 else self.max_slots
+            budget = 1 if resident > self.max_slots // 2 else self.max_slots
             for _ in range(budget):
                 if not self._prefill_one():
                     break
                 worked = True
+            decoding = self._decode_step()
+            worked = bool(decoding) or worked
             if busy or worked:
                 # gauges are unchanged on a no-op idle step, and set_gauge
                 # feeds the always-on flight ring — publishing them anyway
@@ -865,6 +886,7 @@ class ServingEngine:
             req.length = 0
             req.state = PREFILL
             req.admit_seq = next(self._admits)
+            req.admit_step = self._step_count
             self.slots[slot] = req
             self._phase_end(req)            # close "queued"
             self.obs.event("serving_admitted", request=req.request_id,
@@ -960,8 +982,9 @@ class ServingEngine:
                 self.cache.pools)
 
         # the chunk's dispatch on the request's own lifecycle track (the
-        # device runs the chunk behind it: the next ``decode_wait`` holds
-        # that time); per-chunk ``serving.prefill_ms`` is this span's length
+        # device runs the chunk behind it: this iteration's ``decode_wait``
+        # holds that time); per-chunk ``serving.prefill_ms`` is this span's
+        # length
         with self.obs.span("prefill_chunk", "serving:request",
                            {"request": req.request_id, "chunk": C,
                             "pos0": pos0, "step": self._step_count},
@@ -975,10 +998,10 @@ class ServingEngine:
                            chunk=C, pos0=pos0, real=real)
             req.prefilled += real
             if req.prefilled == len(wp):            # prompt fully resident
-                # no logits left prefill: the FIRST token comes from the
-                # next batched decode step as a REPLAY — re-feed the last
-                # prompt token (its K/V row already exists; the write goes
-                # to the scratch page) and sample in-graph on the same
+                # no logits left prefill: the FIRST token comes from this
+                # iteration's batched decode step as a REPLAY — re-feed the
+                # last prompt token (its K/V row already exists; the write
+                # goes to the scratch page) and sample in-graph on the same
                 # program path as every later token
                 req.length = len(wp)
                 req.next_token = int(wp[-1])
@@ -1033,26 +1056,39 @@ class ServingEngine:
         self.obs.event("serving_preempt", request=req.request_id,
                        generated=len(req.generated))
 
-    def _decode_step(self) -> bool:
-        """One batched decode step over every resident DECODE request."""
+    def _reserve_decode_pages(self) -> None:
+        """Page capacity for this iteration's decode step, taken BEFORE any
+        prefill chunk allocates: residents outrank newcomers when pages are
+        short. May preempt (the newest lowest-priority resident, a request
+        admitted a moment ago included), so no chunk is computed for a
+        request this iteration's decode would evict."""
+        g = self.geom
+        for req in list(self.slots):
+            if req is None or req.state != DECODE:
+                continue
+            # a replay row writes nothing (scratch page): it only needs its
+            # existing context pages, not the next append page yet — so a
+            # prompt that turns resident in this iteration's prefill needs
+            # no pass of its own
+            need = (-(-req.length // g.page_size) if req._replay
+                    else req.length // g.page_size + 1)
+            if len(req.pages) < need:
+                self._grow_pages(req, need - len(req.pages))
+
+    def _decode_step(self) -> int:
+        """One batched decode step over every resident DECODE request, the
+        ones this iteration's prefill made resident included (their pages
+        were reserved by ``_reserve_decode_pages``; a resident that a
+        chunk's page growth evicted since is not in the batch). Returns the
+        number of rows it ran."""
         with self.obs.span("decode_build", "serving:sched", self._step_args,
                            ring=False) as build:
             g = self.geom
-            # page capacity first (may preempt, changing the active set)
-            for req in list(self.slots):
-                if req is None or req.state != DECODE:
-                    continue
-                # a replay row writes nothing (scratch page): it only needs its
-                # existing context pages, not the next append page yet
-                need = (-(-req.length // g.page_size) if req._replay
-                        else req.length // g.page_size + 1)
-                if len(req.pages) < need:
-                    self._grow_pages(req, need - len(req.pages))
             active = [(i, r) for i, r in enumerate(self.slots)
                       if r is not None and r.state == DECODE]
             if not active:
                 build.cancel()
-                return False
+                return 0
             tokens, bt = self._np_tokens, self._np_bt
             lengths, write_pos = self._np_len, self._np_wp
             temps, topk = self._np_temp, self._np_topk
@@ -1177,7 +1213,7 @@ class ServingEngine:
                 else:
                     r.length += 1
                 self._on_token(r, int(toks[i]))
-        return True
+        return len(active)
 
     def _on_token(self, req: Request, tok: int) -> None:
         req.generated.append(tok)
@@ -1188,9 +1224,13 @@ class ServingEngine:
             # the open lifecycle phase is "decode", begun when the prompt
             # became resident (after the last admission): with it a reader
             # has the request's road to this token from `request` alone
+            # `steps`: engine iterations from the last admission to this
+            # token, 1 = the admission's own iteration (the prompt was
+            # resident, and its replay row decoded, before step() returned)
             self.obs.event("serving_first_token", request=req.request_id,
                            ttft_ms=round(req.ttft_s * 1e3, 3),
-                           resident_us=req._phase_t0_us)
+                           resident_us=req._phase_t0_us,
+                           steps=self._step_count - req.admit_step + 1)
         if (len(req.generated) >= req.max_new_tokens
                 or (req.eos_id is not None and tok == req.eos_id)):
             self._finish(req)
@@ -1244,6 +1284,7 @@ class ServingEngine:
             clone._replay = True
             clone.state = DECODE
             clone.admit_seq = next(self._admits)
+            clone.admit_step = self._step_count
             self.slots[slot] = clone
             self._phase_end(clone)          # close "queued" (fork-pending)
             self.obs.event("serving_fork", request=clone.request_id,
