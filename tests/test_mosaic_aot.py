@@ -76,18 +76,46 @@ def sds(shape, dtype=bf16, sharding=None):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _bwd_rung_compiled(v5e, shape, dtype):
+    """Compile the causal backward at ``shape`` for the chip; the rung the
+    dispatch recorded for it (``pallas.sdpa_bwd.<rung>``)."""
+    from thunder_tpu.observe import registry as obs
+
+    q = sds(shape, dtype)
+    obs.enable(clear=True)
+    try:
+        _compile(v5e, functools.partial(px.pallas_sdpa_bwd, is_causal=True),
+                 q, q, q, q, q, sds(shape[:-1], f32))
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.disable()
+        obs.reset()
+    (name, n), = [(k, c) for k, c in counters.items()
+                  if k.startswith("pallas.sdpa_bwd.")]
+    assert n == 1
+    return name.rsplit(".", 1)[1]
+
+
 def test_flash_attention_every_rung(v5e):
-    # T x hd walks the causal dispatch ladder: resident forward + combined
-    # backward (T*hd <= 2048*128), the K/V-resident backward pair
-    # (<= 4096*128), and the grid-streaming forward/backward above that
-    for T, hd, fwd in ((512, 128, True), (2048, 256, False), (4096, 256, True)):
-        q = sds((1, 1, T, hd))
-        lse = sds((1, 1, T), f32)
+    # T x hd walks the causal dispatch ladder: resident forward + one-pass
+    # backward, the K/V-resident backward pair, and the grid-streaming
+    # forward/backward above both
+    for T, hd, fwd, rung in ((512, 128, True, "one_pass"),
+                             (2048, 256, False, "one_pass"),
+                             (4096, 256, True, "streaming")):
         if fwd:
+            q = sds((1, 1, T, hd))
             _compile(v5e, functools.partial(px.pallas_sdpa_fwd, is_causal=True),
                      q, q, q)
-        _compile(v5e, functools.partial(px.pallas_sdpa_bwd, is_causal=True),
-                 q, q, q, q, q, lse)
+        assert _bwd_rung_compiled(v5e, (1, 1, T, hd), bf16) == rung
+    # mistral7b_train's own backward operands: the one-pass kernel stages
+    # 24.00 MiB there, over Mosaic's default and under the limit it is
+    # compiled with; the chip's compiler admits it before the chip is asked
+    assert _bwd_rung_compiled(v5e, (4, 32, 4096, 128), bf16) == "one_pass"
+    # the gate counts bytes: float32 there would stage 40 MiB and takes the
+    # pair, whose dk/dv kernel stages 20.00 MiB at this batch x heads (it too
+    # needs the raised limit; a grid of one batch·head is allocated less)
+    assert _bwd_rung_compiled(v5e, (4, 32, 4096, 128), f32) == "pair"
 
 
 def test_rowwise_kernels(v5e):

@@ -599,6 +599,47 @@ def test_bench_metric_names_exist_after_compile():
     assert snap["gauges"]["compile.transform_ms"] > 0
 
 
+def test_sdpa_bwd_rung_is_counted_once_a_call_site(monkeypatch):
+    """Which flash-backward rung engaged is a program record: compiling a
+    small causal train step bumps exactly one ``pallas.sdpa_bwd.*`` counter,
+    once a call site (dispatch is trace time; a second step adds nothing),
+    the ``kernel_path`` event says why, and ``explain()`` prints it."""
+    monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+    import thunder_tpu.ops as ops
+    from thunder_tpu.executors import pallasex as px
+    from thunder_tpu.observe import flight
+
+    T, hd = 256, 16
+    rng = np.random.RandomState(34)
+    q, k, v = ((rng.randn(1, 2, T, hd) * 0.3).astype(np.float32) for _ in range(3))
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            a = ops.scaled_dot_product_attention(q, k, v, is_causal=True)
+            b = ops.scaled_dot_product_attention(a, k, v, is_causal=True)
+            return ops.sum(ops.mul(b, b))
+        return tt.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    flight.clear()
+    observe.enable(clear=True)
+    jstep = tt.jit(step, executors=["pallas", "xla"])
+    jstep(q, k, v)
+    jstep(q, k, v)
+    snap = observe.snapshot()
+    rungs = {n: c for n, c in snap["counters"].items()
+             if n.startswith("pallas.sdpa_bwd.")}
+    assert rungs == {"pallas.sdpa_bwd.one_pass": 2.0}, rungs
+    paths = [e for e in snap["events"] if e["kind"] == "kernel_path"]
+    assert len(paths) == 2
+    for e in paths:
+        assert (e["op"], e["rung"], e["T"], e["hd"]) == ("nn.sdpa_bwd", "one_pass", T, hd)
+        assert e["staged_bytes"] == px._one_pass_staged_bytes(T, hd, 4) > 0
+    report = observe.explain(jstep)
+    compile_section = report.split("== compile ==")[1].split("\n== ")[0]
+    assert "kernel path: nn.sdpa_bwd -> one_pass (T=256, hd=16" in compile_section
+    assert "x2" in compile_section
+
+
 def test_fused_optimizer_decisions_logged(monkeypatch):
     """Satellite of the r6 fused multi-tensor AdamW: every bucket verdict —
     accept with the byte-model numbers, or reject with the gate that refused

@@ -294,9 +294,9 @@ def test_pallas_sdpa_combined_causal_bwd_matches_autodiff():
 
 # ---------------------------------------------------------------------------
 # flash-backward parity at ragged / degenerate / GQA shapes, per kernel path
-# (satellite of the r6 backward rewrite: the dispatch in pallas_sdpa_bwd now
-# picks combined-resident -> resident-K/V pair -> grid-streaming; every path
-# must match the eagerjax sdpa VJP / jax autodiff of the decomposition)
+# (the dispatch in pallas_sdpa_bwd picks one-pass -> resident-K/V pair ->
+# grid-streaming, pallasex._sdpa_bwd_rung; every rung must match the eagerjax
+# sdpa VJP / jax autodiff of the decomposition)
 # ---------------------------------------------------------------------------
 
 def _causal_ref_grads(q, k, v, g):
@@ -343,7 +343,7 @@ def test_pallas_sdpa_bwd_resident_pair_diagonal_loops(monkeypatch):
     kernels is exercised, not just the single-block trivial case."""
     from thunder_tpu.executors import pallasex as px
 
-    monkeypatch.setattr(px, "_RESIDENT_BWD_COMBINED_ELEMS", 0)  # skip combined
+    monkeypatch.setattr(px, "_one_pass_block", lambda T: 0)  # skip one-pass
     monkeypatch.setattr(px, "_RESIDENT_BWD_SUB", 16)
     _bwd_parity_at(64)
 
@@ -353,9 +353,63 @@ def test_pallas_sdpa_bwd_streaming_parity_ragged(monkeypatch):
     windows on causal shapes) still matches at a ragged T."""
     from thunder_tpu.executors import pallasex as px
 
-    monkeypatch.setattr(px, "_RESIDENT_BWD_COMBINED_ELEMS", 0)
+    monkeypatch.setattr(px, "_one_pass_block", lambda T: 0)
     monkeypatch.setattr(px, "_RESIDENT_BWD_KV_ELEMS", 0)
     _bwd_parity_at(48)
+
+
+@pytest.mark.parametrize("dtype,atol", [("float32", 2e-4), ("bfloat16", 3e-2)])
+def test_pallas_sdpa_bwd_one_pass_walks_the_triangle(monkeypatch, dtype, atol):
+    """The one-pass kernel with FOUR kv blocks (blk=256 at T=1024): every kv
+    block's diagonal tile (the only one masked) and the tiles strictly under
+    it are walked, and dq accumulates across kv blocks. Both input dtypes
+    the gate admits; bf16 operands round p and ds to 8 bits for the MXU, so
+    they are held to the reference at that precision."""
+    import jax.numpy as jnp
+    from thunder_tpu.executors import pallasex as px
+
+    monkeypatch.setattr(px, "_one_pass_block", lambda T: 256)
+    T, hd = 1024, 32
+    assert px._sdpa_bwd_rung(T, T, hd, jnp.dtype(dtype).itemsize, True)[0] == "one_pass"
+    rng = np.random.RandomState(34)
+    mk = lambda: jnp.asarray((rng.randn(1, 2, T, hd) * 0.3).astype(np.float32)
+                             ).astype(dtype)
+    q, k, v, g = mk(), mk(), mk(), mk()
+    out, lse = px.pallas_sdpa_fwd(q, k, v, is_causal=True)
+    got = px.pallas_sdpa_bwd(g, q, k, v, out, lse, is_causal=True)
+    want = _causal_ref_grads(*(x.astype(jnp.float32) for x in (q, k, v, g)))
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        assert a.dtype == jnp.dtype(dtype)
+        np.testing.assert_allclose(np.asarray(a.astype(jnp.float32)),
+                                   np.asarray(b), atol=atol, err_msg=name)
+
+
+_MiB = 1 << 20
+
+
+@pytest.mark.parametrize("T,S,hd,itemsize,causal,rung,staged", [
+    (2048, 2048, 128, 2, True, "one_pass", 12 * _MiB),   # the old element cap
+    (4096, 4096, 128, 2, True, "one_pass", 24 * _MiB),   # the train cell
+    (2048, 2048, 128, 4, True, "one_pass", 20 * _MiB),
+    (4096, 4096, 128, 4, True, "pair", 0),               # 40 MiB: over the limit
+    (48, 48, 16, 4, True, "pair", 0),                    # ragged: no 256-tile
+    (1, 1, 16, 4, True, "pair", 0),
+    (5120, 5120, 128, 2, True, "streaming", 0),          # 30 MiB + the body's
+    (8192, 8192, 128, 2, True, "streaming", 0),
+    (4096, 4096, 256, 2, True, "streaming", 0),
+    (1024, 1024, 128, 2, False, "streaming", 0),         # non-causal
+    (1024, 2048, 128, 2, True, "streaming", 0),          # cross attention
+], ids=["bf16-2048x128", "bf16-4096x128", "f32-2048x128", "f32-4096x128",
+        "ragged-T48", "decode-T1", "bf16-5120x128", "bf16-8192x128",
+        "bf16-4096x256", "non-causal", "cross"])
+def test_sdpa_bwd_rung_gate(T, S, hd, itemsize, causal, rung, staged):
+    """The ladder's edges: the one-pass gate counts the bytes the kernel
+    stages (the compiler's own 24.00 MiB at the train cell's shape) against
+    the limit it compiles under; what it refuses takes the rung it took
+    before."""
+    from thunder_tpu.executors import pallasex as px
+
+    assert px._sdpa_bwd_rung(T, S, hd, itemsize, causal) == (rung, staged)
 
 
 def test_pallas_sdpa_bwd_gqa_head_grouping():

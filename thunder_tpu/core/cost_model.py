@@ -48,15 +48,17 @@ TPU_PEAK_FLOPS = _CHIP.peak_bf16_flops
 TPU_RIDGE_FLOPS_PER_BYTE = _CHIP.peak_bf16_flops / _CHIP.hbm_bytes_per_s
 
 # VMEM a single Pallas kernel invocation is PLANNED against: Mosaic's default
-# scoped limit on this chip (the r5 combined attention backward measured the
-# hard error at ~17.6 MB). Block-planner feasibility checks model against it.
+# scoped limit on this chip. Block-planner feasibility checks model against it.
 VMEM_BUDGET_BYTES = _CHIP.scoped_vmem_default_bytes
 
 # ...and what the planned megakernels are COMPILED with (vmem_limit_bytes).
 # The staging models below leave out Mosaic's own scratch, layout padding
 # and semaphores — the bench-geometry MLP sub-block models 15.7 MB and the
 # compiler allocates 16.01 MiB — so the compiler's limit sits at twice the
-# planner's budget: a quarter of this chip's physical VMEM.
+# planner's budget: a quarter of this chip's physical VMEM. The one-pass
+# flash backward is gated on its staging as the compiler allocates it
+# (24.00 MiB at the training shape) directly against this limit
+# (executors/pallasex.py::_sdpa_bwd_rung).
 VMEM_LIMIT_BYTES = 2 * VMEM_BUDGET_BYTES
 assert VMEM_LIMIT_BYTES <= _CHIP.vmem_bytes
 

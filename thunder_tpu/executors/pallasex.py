@@ -45,6 +45,7 @@ from jax.sharding import PartitionSpec as P
 
 from thunder_tpu.core.devices import chip_spec
 from thunder_tpu.executors import OperatorExecutor, register_executor
+from thunder_tpu.observe import registry as _observe
 from thunder_tpu.ops import get_op
 
 
@@ -506,12 +507,21 @@ def _sdpa_bwd_kernel_causal_resident(g_ref, q_ref, k_ref, v_ref, o_ref,
                                      delta_acc, *, scale: float, blk: int,
                                      nb: int):
     """Combined causal dq+dk+dv, one grid invocation per batch·head: the
-    whole sequence stays resident in VMEM, an unrolled loop walks kv
-    blocks, and a triangular ``fori_loop`` walks the q blocks at-or-below
-    the diagonal sharing one recomputed probability tile for all three
-    grads — the two-kernel (dq then dkv) structure recomputed p twice and
-    paid per-invocation overhead on two grids (interleaved r5 A/B at the
-    bench shape: 26.4 → 19.2 ms/layer; blk=512 beat 256 by ~8%)."""
+    whole sequence stays resident in VMEM, a ``fori_loop`` walks kv blocks,
+    and a triangular ``fori_loop`` walks the q blocks at-or-below the
+    diagonal sharing one recomputed probability tile for all three grads —
+    the two-kernel (dq then dkv) structure recomputes p (and the mask, the
+    exp, dp - delta) twice, seven matmuls a tile for five, and pays
+    per-invocation overhead on two grids. At (4 x 32, 4096, 128) bf16 on a
+    v5e: 15.8 ms a layer for the pair, 9.2 for this kernel (PERF.md §6,
+    PR 34; blk=512 beat 256 by ~8% in r5).
+
+    The diagonal tile (q block == kv block) is peeled off the inner loop:
+    it alone has anything to mask, so the 28 of 36 tiles strictly under it
+    skip the iotas, the compare and the select (−0.17 ms a layer). The kv
+    loop is a ``fori_loop`` and not a Python loop: unrolled it is 0.3 ms a
+    layer faster (static trip counts inside) and 8x the code — 6.2 s of
+    Mosaic compile against 0.7."""
     hd = q_ref.shape[-1]
     dq_acc[...] = jnp.zeros_like(dq_acc)
     # delta = rowsum(g * o) depends only on the q row: compute ONCE for the
@@ -519,11 +529,13 @@ def _sdpa_bwd_kernel_causal_resident(g_ref, q_ref, k_ref, v_ref, o_ref,
     delta_acc[...] = jnp.sum(g_ref[0].astype(jnp.float32)
                              * o_ref[0].astype(jnp.float32),
                              axis=-1, keepdims=True)
-    for j in range(nb):                                # kv blocks
+    zeros = jnp.zeros((blk, hd), jnp.float32)
+
+    def kv_block(j, _):
         kj = k_ref[0, pl.ds(j * blk, blk), :]
         vj = v_ref[0, pl.ds(j * blk, blk), :]
 
-        def body(i, carry, j=j, kj=kj, vj=vj):
+        def tile(i, carry, diagonal=False):
             dk_j, dv_j = carry
             qi = q_ref[0, pl.ds(i * blk, blk), :]
             gi = g_ref[0, pl.ds(i * blk, blk), :]
@@ -531,7 +543,8 @@ def _sdpa_bwd_kernel_causal_resident(g_ref, q_ref, k_ref, v_ref, o_ref,
             delta_i = delta_acc[pl.ds(i * blk, blk), :]
             s = jax.lax.dot_general(qi, kj, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
-            s = _causal_mask(s, i * blk, j * blk)
+            if diagonal:
+                s = _causal_mask(s, 0, 0)         # same offset, rows and cols
             p = jnp.exp(s - lse_i)
             dp = jax.lax.dot_general(gi, vj, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
@@ -547,24 +560,78 @@ def _sdpa_bwd_kernel_causal_resident(g_ref, q_ref, k_ref, v_ref, o_ref,
             return dk_j, dv_j
 
         dk_j, dv_j = jax.lax.fori_loop(
-            j, nb, body, (jnp.zeros((blk, hd), jnp.float32),
-                          jnp.zeros((blk, hd), jnp.float32)))
+            j + 1, nb, tile, tile(j, (zeros, zeros), diagonal=True))
         dk_ref[0, pl.ds(j * blk, blk), :] = dk_j.astype(dk_ref.dtype)
         dv_ref[0, pl.ds(j * blk, blk), :] = dv_j.astype(dv_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, nb, kv_block, 0)
     dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-# VMEM caps for the causal backward variants (elements of ONE (T, hd)
-# sequence). The combined one-kernel backward stages 9 resident (T, hd)
-# blocks + a (T, hd) f32 scratch — T*hd = 4096*128 measured 17.63M of
-# scoped VMEM on v5e (chip error, r5), so it caps at 2048*128. The
-# resident-K/V PAIR below keeps only 2-3 sequence-length tensors resident
-# per kernel, which admits the forward's 4096*128 window — sequences in
-# (2048*128, 4096*128] previously fell all the way back to the
-# grid-streaming kernels that compute (then mask) the full upper triangle.
-_RESIDENT_BWD_COMBINED_ELEMS = 2048 * 128
+# The causal backward is a ladder of three rungs, chosen from the shape and
+# the dtype alone (``_sdpa_bwd_rung``):
+#   one_pass   the kernel above: every (T, hd) operand of one batch·head
+#              resident, one probability tile for dq, dk and dv
+#   pair       the resident-K/V dq kernel + resident-Q/G dkv kernel below:
+#              2-3 sequence-length tensors resident per kernel, every tile's
+#              probabilities (and mask, exp, dp - delta) computed twice
+#   streaming  the grid-streaming dq / dkv kernels: no residency, so no
+#              sequence cap; causal tiles above the diagonal are skipped per
+#              grid step. Non-causal and cross attention (ring attention's
+#              local blocks) take this rung.
+# The one-pass gate counts the BYTES the kernel stages (below) and holds them
+# against the limit the kernel is compiled with (``_grid_params(planned_vmem
+# =True)``: cost_model.VMEM_LIMIT_BYTES, 32 MiB). History: the gate was a
+# count of elements, 2048*128, set when the kernel compiled under Mosaic's
+# default 16 MiB and T*hd = 4096*128 errored on the chip at 17.63M (r5).
+# The pair admits the forward's 4096*128 window; it is compiled with the same
+# limit (its dk/dv kernel stages 20 MiB in float32 at 4096*128).
 _RESIDENT_BWD_KV_ELEMS = 4096 * 128
 _RESIDENT_BWD_SUB = 512  # kv/q sub-block width inside the fori_loops
+
+
+def _one_pass_block(T: int) -> int:
+    """The one-pass kernel's tile edge (0: T does not tile, no one-pass)."""
+    return 512 if T % 512 == 0 else (256 if T % 256 == 0 else 0)
+
+
+def _one_pass_staged_bytes(T: int, hd: int, itemsize: int) -> int:
+    """VMEM the one-pass backward stages for one batch·head, as Mosaic
+    allocates it: 5 inputs and 3 outputs of (T, hd), double-buffered by the
+    pipeliner; ``lse`` as a (T, 1) f32 column, which pads every row to a
+    128-lane tile, double-buffered; the (T, hd) f32 dq scratch; the (T, 1)
+    f32 delta scratch, padded alike. At T=4096, hd=128, bf16 this is the
+    compiler's own 24.00 MiB (16 + 4 + 2 + 2)."""
+    lanes = -(-hd // 128) * 128
+    seq = T * lanes * itemsize
+    column = T * 128 * 4
+    return 2 * 8 * seq + 2 * column + T * lanes * 4 + column
+
+
+def _sdpa_bwd_rung(T: int, S: int, hd: int, itemsize: int,
+                   is_causal: bool) -> tuple[str, int]:
+    """(rung, staged_bytes) of the backward ladder for one shape and dtype;
+    ``staged_bytes`` is the one-pass kernel's staging where it engages, else
+    0. The margin under the compile limit is what the kernel's loop body
+    keeps live beside the staged blocks (Mosaic spills it to VMEM): four
+    (blk, blk) f32 tiles — s, p, dp, ds — and the two (blk, hd) f32
+    carries. bf16 at 4096*128 stages 24 MiB + 4.5 and engages; f32 there
+    stages 40 MiB and takes the pair, as does anything longer."""
+    from thunder_tpu.core.cost_model import VMEM_LIMIT_BYTES
+
+    if not (is_causal and T == S):
+        return "streaming", 0
+    blk = _one_pass_block(T)
+    if blk:
+        staged = _one_pass_staged_bytes(T, hd, itemsize)
+        body = 4 * blk * blk * 4 + 2 * blk * hd * 4
+        if staged + body <= VMEM_LIMIT_BYTES:
+            return "one_pass", staged
+    if T * hd <= _RESIDENT_BWD_KV_ELEMS:
+        # _pick_block(T, sub) always divides T: ragged and T=1 shapes land here
+        return "pair", 0
+    return "streaming", 0
 
 
 def _sdpa_dq_kernel_causal_kvres(g_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -663,11 +730,14 @@ def pallas_sdpa_bwd(g, q, k, v, out, lse, is_causal=False, scale=None):
     o3 = out.reshape(bh, T, hd)
     lse3 = lse.reshape(bh, T, 1)
 
-    blk = 512 if T % 512 == 0 else (256 if T % 256 == 0 else 0)
-    # scoped-VMEM budget 16MB: 9 resident (T, hd) bf16 blocks + (T, hd) f32
-    # + (T, 1) f32 scratch — see _RESIDENT_BWD_COMBINED_ELEMS above; longer
-    # sequences take the resident-K/V pair, then the streaming kernels
-    if is_causal and T == S and T * hd <= _RESIDENT_BWD_COMBINED_ELEMS and blk:
+    rung, staged = _sdpa_bwd_rung(T, S, hd, q.dtype.itemsize, bool(is_causal))
+    # recorded at dispatch, which is trace time: once a call site a compile,
+    # nothing a step (which kernel RAN is read from the device trace)
+    _observe.inc(f"pallas.sdpa_bwd.{rung}")
+    _observe.event("kernel_path", op="nn.sdpa_bwd", rung=rung, T=T, hd=hd,
+                   staged_bytes=staged)
+    if rung == "one_pass":
+        blk = _one_pass_block(T)
         dq, dk, dv = pl.pallas_call(
             functools.partial(_sdpa_bwd_kernel_causal_resident, scale=scale_v,
                               blk=blk, nb=T // blk),
@@ -681,11 +751,12 @@ def pallas_sdpa_bwd(g, q, k, v, out, lse, is_causal=False, scale=None):
             scratch_shapes=[pltpu.VMEM((T, hd), jnp.float32),
                             pltpu.VMEM((T, 1), jnp.float32)],
             interpret=_interpret(),
+            **_grid_params(planned_vmem=True),
         )(g3, q3, k3, v3, o3, lse3)
         return (dq.reshape(orig_shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
-    sub = _pick_block(T, _RESIDENT_BWD_SUB)
-    if is_causal and T == S and T * hd <= _RESIDENT_BWD_KV_ELEMS and T % sub == 0:
+    if rung == "pair":
+        sub = _pick_block(T, _RESIDENT_BWD_SUB)
         # resident-K/V diagonal-stopping pair: the r5 forward recipe applied
         # to both backward kernels. dq keeps K/V whole in VMEM and its inner
         # loop stops AT the diagonal; dk/dv keeps Q/G whole and its loop
@@ -704,6 +775,7 @@ def pallas_sdpa_bwd(g, q, k, v, out, lse, is_causal=False, scale=None):
             out_specs=blk_spec,
             out_shape=jax.ShapeDtypeStruct((bh, T, hd), q.dtype),
             interpret=_interpret(),
+            **_grid_params(planned_vmem=True),
         )(g3, q3, k3, v3, o3, lse3)
         dk, dv = pl.pallas_call(
             functools.partial(_sdpa_dkv_kernel_causal_qres, scale=scale_v,
@@ -716,6 +788,7 @@ def pallas_sdpa_bwd(g, q, k, v, out, lse, is_causal=False, scale=None):
                        jax.ShapeDtypeStruct((bh, S, hd), v.dtype)],
             scratch_shapes=[pltpu.VMEM((T, 1), jnp.float32)],
             interpret=_interpret(),
+            **_grid_params(planned_vmem=True),
         )(g3, q3, k3, v3, o3, lse3)
         return (dq.reshape(orig_shape), dk.reshape(k.shape), dv.reshape(v.shape))
     # v5e-swept tiles at (8,32,2048,128) bf16 causal: dq 512/512 = 13.2ms vs

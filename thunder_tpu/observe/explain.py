@@ -40,6 +40,24 @@ def _fmt_cost(cost: dict | None) -> str:
     return " (" + ", ".join(f"{k}={v}" for k, v in cost.items()) + ")"
 
 
+def _kernel_path_lines() -> list[str]:
+    """Which rung of a kernel's dispatch ladder engaged, from the flight
+    ring's ``kernel_path`` events (the kernels record them at dispatch, which
+    is when JAX traces the program: after ``tt.jit``'s own compile has
+    closed its decision log, so they are the process's, not one function's).
+    Repeats collapse; empty when no laddered kernel was dispatched."""
+    from collections import Counter
+
+    from thunder_tpu.observe import flight as _flight
+
+    seen = Counter((r["op"], r["rung"], r["T"], r["hd"], r["staged_bytes"])
+                   for r in _flight.snapshot()
+                   if r["type"] == "event" and r.get("kind") == "kernel_path")
+    return [f"  kernel path: {op} -> {rung} (T={T}, hd={hd}, "
+            f"staged_bytes={staged})" + (f"  x{n}" if n > 1 else "")
+            for (op, rung, T, hd, staged), n in seen.items()]
+
+
 _TIMELINE_MAX_REQUESTS = 16
 
 
@@ -292,6 +310,7 @@ def explain(jfn) -> str:
     lines.append("")
     lines.append("== compile ==")
     lines.append(stats.summary())
+    lines.extend(_kernel_path_lines())
 
     # -- executor assignment ------------------------------------------------
     exec_trc = stats.last_traces[-1]
