@@ -88,6 +88,13 @@ _COMPOSITE_GRAD_EXEMPT_REASONED = {
     "nn.paged_decode_attention": "inference-only serving decode path "
                                  "(thunder_tpu/serving/) — training traces use "
                                  "nn.scaled_dot_product_attention, which has a rule",
+    "nn.banded_attention": "inference-only: a serving prefill chunk's attention "
+                           "over keys gathered from the page pools "
+                           "(models/cohere2_moe.py); training traces use "
+                           "nn.scaled_dot_product_attention, which has a rule",
+    "nn.moe_experts": "inference-only: the serving expert layer told which "
+                      "experts it holds (no backward, no load-balancing term; "
+                      "ROADMAP R2 lists training through it as left)",
     "nn.sdpa_bwd": "backward half; differentiating it is second-order autodiff",
     "ops.fmod": "prim classified non-differentiable (matches reference: grads stop)",
     "ops.remainder": "prim classified non-differentiable (matches reference)",

@@ -218,3 +218,43 @@ def test_partitioning_plans_under_a_mesh(v5e):
     with pytest.raises(NotImplementedError, match="automatically partitioned"):
         _compile(v5e, px.pallas_rms_norm, sds((512, 256)), sds((256,)),
                  sharding=ns())
+
+
+@pytest.mark.parametrize("kind,pages,table,window", [
+    ("window", 32 * 257 + 1, 257, 4096),
+    ("global", 32 * 1024 + 1, 1024, None),
+])
+def test_window_page_walk_at_the_agent_cell_widths(v5e, kind, pages, table,
+                                                   window):
+    """``commandaplus_serve_agent_sat``'s decode attention: 32 slots, 128
+    query heads over 8 KV heads x 128, pages of 16; a window layer's ring of
+    257 pages and a global layer's 1,024-page table."""
+    pool = sds((8, pages, 16, 128))
+    _compile(v5e, functools.partial(px.pallas_paged_decode_attention,
+                                    window=window),
+             sds((32, 128, 1, 128)), pool, pool, sds((32, table), i32),
+             sds((32,), i32))
+
+
+@pytest.mark.parametrize("chunk,keys,window", [
+    (512, 4608, 4096),      # the ring's 256 pages in order + the chunk
+    (512, 16384, None),     # a global layer's whole table
+    (16, 4224, 4096),       # the smallest ladder rung, keys padded to 128
+    (16, 16384, None),
+])
+def test_banded_flash_forward_at_the_agent_cell_widths(v5e, chunk, keys,
+                                                       window):
+    _compile(v5e, functools.partial(px.pallas_banded_attention, window=window),
+             sds((128, chunk, 128)), sds((8, keys, 128)), sds((8, keys, 128)),
+             sds((), i32), sds((), i32))
+
+
+@pytest.mark.parametrize("rows", [16, 32, 512])
+def test_grouped_expert_matmul_at_the_agent_cell_widths(v5e, rows):
+    """16 held + 4 shared experts of 4096 x 4096 x 3; a ladder rung's rows,
+    a decode step's 32 (one row tile, 512-wide feed-forward blocks: 24 MiB
+    of tiles, inside the planned VMEM limit) and a chunk's 512 (256-row
+    tiles); 8 picks + 4 shared + 1 keep-warm assignment a row."""
+    w = sds((20, 4096, 4096))
+    _compile(v5e, px.pallas_moe_experts, sds((rows, 4096)), w, w, w,
+             sds((rows, 13), i32), sds((rows, 13), f32))

@@ -88,6 +88,13 @@ BLOCK_DECISION_KINDS = {
                         "cannot auto-partition under GSPMD, so fusion is "
                         "capped at the attention/MLP sub-block rung — one "
                         "quarantine rung down, never per-op XLA",
+    "parallel-block": "one norm's output feeds BOTH an attention sub-block "
+                      "and an expert layer (nn.moe_experts), and the "
+                      "residual takes both sums; recorded by the model "
+                      "description that builds the layer (the planner has "
+                      "no fused form of it to choose): the two sub-blocks "
+                      "keep their own launches (they share only the normed "
+                      "rows, a sliver of the weights each streams)",
 }
 
 

@@ -1373,7 +1373,8 @@ def _mlp_subblock_checker(residual, x, w_norm, w_gate, w_up, w_down,
 
 def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
                      m_ref, l_ref, acc_ref, *, S: int, npg: int, ps: int,
-                     ppb: int, scale: float, q_of, emit, fresh_of=None):
+                     ppb: int, scale: float, q_of, emit, fresh_of=None,
+                     window: int | None = None):
     """Online-softmax decode attention of KV head ``kvh`` over every slot's
     live pages. ``q_of(b)`` gives slot b's (G, hd) grouped query rows,
     ``emit(b, out)`` takes its normalized (G, hd) f32 result (zeros for a
@@ -1381,16 +1382,32 @@ def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
     and V rows, patched in at position length-1 because the pool still holds
     the pre-append contents. ``bt_ref`` is the flattened (S * npg,) block
     table; ``kbuf`` / ``vbuf`` are (2, ppb * ps, hd) VMEM buffers and
-    ``sem`` a (2, 2) DMA semaphore array (pool x buffer)."""
+    ``sem`` a (2, 2) DMA semaphore array (pool x buffer).
+
+    ``window=W``: the table is a ring (logical page ``p`` in column
+    ``p % npg``) and the walk starts at the first page the window still
+    reaches, ``max(length - W, 0) // ps``, so it covers at most
+    ``cdiv(W, ps) + 1`` pages whatever the context; rows below
+    ``length - W`` are masked like the rows past the length."""
     bk = ppb * ps
+
+    def first_page(b):
+        """The first logical page slot ``b``'s walk reads."""
+        if window is None:
+            return 0
+        return jnp.maximum(ln_ref[b] - window, 0) // ps
 
     def copies(b, j, buf, do):
         """``do`` (start or wait) on the copies of block ``j`` of slot
         ``b``: its live pages only, into buffer ``buf``."""
-        live = jnp.minimum(ppb, (ln_ref[b] + ps - 1) // ps - j * ppb)
+        fp = first_page(b)
+        live = jnp.minimum(ppb, (ln_ref[b] + ps - 1) // ps - fp - j * ppb)
 
         def page(p, carry):
-            pid = bt_ref[b * npg + j * ppb + p]
+            col = fp + j * ppb + p
+            if window is not None:
+                col = col % npg
+            pid = bt_ref[b * npg + col]
             rows = pl.ds(pl.multiple_of(p * ps, ps), ps)
             do(pltpu.make_async_copy(kp_ref.at[kvh, pid],
                                      kbuf.at[buf, rows, :], sem.at[0, buf]))
@@ -1405,7 +1422,9 @@ def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
 
     def slot(b, buf0):
         ln = ln_ref[b]
-        nblk = (ln + bk - 1) // bk
+        base = first_page(b) * ps       # position of the walk's first row
+        lo = 0 if window is None else jnp.maximum(ln - window, 0)
+        nblk = (ln - base + bk - 1) // bk
         nxt = jnp.minimum(b + 1, S - 1)
 
         # the first block is already in flight when the slot before had a
@@ -1434,16 +1453,22 @@ def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
             copies(b, j, buf, wait)
             k = kbuf[buf]                              # (bk, hd)
             v = vbuf[buf]
-            row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-            v = jnp.where(row < ln, v, jnp.zeros_like(v))   # dead rows
+            row = base + j * bk + jax.lax.broadcasted_iota(jnp.int32,
+                                                           (bk, 1), 0)
+            live_row = row < ln if window is None \
+                else (row < ln) & (row >= lo)
+            v = jnp.where(live_row, v, jnp.zeros_like(v))   # dead rows
             if fresh is not None:
                 fk, fv = fresh
                 k = jnp.where(row == ln - 1, fk, k)
                 v = jnp.where(row == ln - 1, fv, v)
             s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32) * scale
-            col = j * bk + jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
-            s_ = jnp.where(col < ln, s_, -jnp.inf)     # ragged tail mask
+            col = base + j * bk + jax.lax.broadcasted_iota(jnp.int32,
+                                                           s_.shape, 1)
+            live_col = col < ln if window is None \
+                else (col < ln) & (col >= lo)
+            s_ = jnp.where(live_col, s_, -jnp.inf)     # ragged tail mask
             m = m_ref[...]
             m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -1483,7 +1508,8 @@ def _walk_scratch(ppb: int, ps: int, hd: int, G: int, dtype):
 
 def _paged_decode_kernel(bt_ref, ln_ref, q_ref, kp_ref, vp_ref, o_ref,
                          kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
-                         scale: float, ps: int, npg: int, ppb: int):
+                         scale: float, ps: int, npg: int, ppb: int,
+                         window: int | None = None):
     """One KV head of every request: q block (B, 1, G, hd) where G =
     n_heads // kv_heads grouped rows of the single decode position."""
     def emit(b, out):
@@ -1492,24 +1518,25 @@ def _paged_decode_kernel(bt_ref, ln_ref, q_ref, kp_ref, vp_ref, o_ref,
     _walk_live_pages(pl.program_id(0), bt_ref, ln_ref, kp_ref, vp_ref, kbuf,
                      vbuf, sem, m_ref, l_ref, acc_ref, S=q_ref.shape[0],
                      npg=npg, ps=ps, ppb=ppb, scale=scale,
-                     q_of=lambda b: q_ref[b, 0], emit=emit)
+                     q_of=lambda b: q_ref[b, 0], emit=emit, window=window)
 
 
 def pallas_paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
-                                  scale=None):
+                                  scale=None, window=None):
     if _gspmd_mesh.get() is not None:
         # plan: heads are embarrassingly parallel — q by head, the pool by
         # kv-head, no reduction; each shard pages its own heads' K/V
         heads = (None, "tp", None, None)
         return _under_plan(
-            lambda ax, *t: _paged_decode_call(*t, scale=scale),
+            lambda ax, *t: _paged_decode_call(*t, scale=scale, window=window),
             (heads, _POOL, _POOL, _REP, _REP), heads,
             q, k_pages, v_pages, block_tables, lengths)
     return _paged_decode_call(q, k_pages, v_pages, block_tables, lengths,
-                              scale=scale)
+                              scale=scale, window=window)
 
 
-def _paged_decode_call(q, k_pages, v_pages, block_tables, lengths, scale=None):
+def _paged_decode_call(q, k_pages, v_pages, block_tables, lengths, scale=None,
+                       window=None):
     B, H, T, hd = q.shape
     if T != 1:
         # the kernel's single ragged mask (col < length) is only the causal
@@ -1524,6 +1551,10 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, lengths, scale=None):
     G = (H // KV) * T                                  # grouped decode rows
     scale_v = scale if scale is not None else 1.0 / math.sqrt(hd)
     ppb = decode_pages_per_block(ps, hd, q.dtype.itemsize, npg)
+    if window is not None:
+        _observe.event("kernel_path", op="nn.paged_decode_attention",
+                       rung=f"ring_walk_{npg}p", T=window, hd=hd,
+                       staged_bytes=4 * ppb * ps * hd * q.dtype.itemsize)
     q4 = q.reshape(B, KV, G, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                         # block_tables, lengths
@@ -1538,7 +1569,7 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, lengths, scale=None):
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale_v, ps=ps,
-                          npg=npg, ppb=ppb),
+                          npg=npg, ppb=ppb, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=_interpret(),
@@ -1548,7 +1579,7 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, lengths, scale=None):
 
 
 def _paged_decode_checker(q, k_pages, v_pages, block_tables, lengths,
-                          scale=None):
+                          scale=None, window=None):
     if not _enabled():
         return False
     if q.ndim != 4 or k_pages.ndim != 4 or v_pages.ndim != 4:
@@ -1578,6 +1609,288 @@ def _paged_decode_checker(q, k_pages, v_pages, block_tables, lengths,
     # tiles (a page is one DMA into a row slice of the walk's buffer). The
     # claim stays cost-model gated either way.
     return hd % 128 == 0 and _whole_tiles(ps, q.dtype.bytes)
+
+
+# ---------------------------------------------------------------------------
+# banded flash forward (serving prefill chunks): a chunk's rows against keys
+# gathered in position order, for both cache kinds. Positions ride as
+# scalar-prefetch operands, so one program serves every chunk: row i sits at
+# q_pos0 + i, key j at k_pos0 + j, and key b is visible to row a iff
+# 0 <= b <= a and (window) b > a - W. Grid (kv head, group row, key block):
+# the key blocks wholly outside the band skip their compute, and their index
+# is clamped into the band, so a revisited block costs no DMA either.
+# ---------------------------------------------------------------------------
+
+def _band_blocks(qp0, kp0, *, Tq: int, bk: int, nkb: int, window):
+    """First and last key block the chunk's band touches."""
+    lo_pos = 0 if window is None else jnp.maximum(qp0 - window + 1, 0)
+    j_lo = jnp.clip((lo_pos - kp0) // bk, 0, nkb - 1)
+    j_hi = jnp.clip((qp0 + Tq - 1 - kp0) // bk, 0, nkb - 1)
+    return j_lo, j_hi
+
+
+def _banded_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                   l_ref, *, scale: float, bk: int, nkb: int, window):
+    j = pl.program_id(2)
+    qp0, kp0 = qp_ref[0], kp_ref[0]
+    Tq = q_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    j_lo, j_hi = _band_blocks(qp0, kp0, Tq=Tq, bk=bk, nkb=nkb, window=window)
+
+    @pl.when((j >= j_lo) & (j <= j_hi))
+    def _compute():
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        a = qp0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        b = kp0 + j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ok = (b >= 0) & (b <= a)
+        if window is not None:
+            ok = ok & (b > a - window)
+        s = jnp.where(ok, s, -jnp.inf)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        # a row the block leaves wholly masked keeps m at -inf: exp(-inf -
+        # (-inf)) is NaN, so shift by 0 there (its p and alpha are 0 anyway)
+        m_use = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        alpha = jnp.exp(m - m_use)
+        p = jnp.exp(s - m_use)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == nkb - 1)
+    def _finalize():
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+
+def _banded_key_block(Lk: int) -> int | None:
+    return next((b for b in (512, 256, 128) if Lk % b == 0), None)
+
+
+def pallas_banded_attention(q, k, v, q_pos0, k_pos0, window=None, scale=None):
+    H, Tq, hd = q.shape
+    KV, Lk, _ = k.shape
+    n_rep = H // KV
+    scale_v = scale if scale is not None else 1.0 / math.sqrt(hd)
+    bk = _banded_key_block(Lk) or Lk
+    nkb = Lk // bk
+    # recorded at dispatch, which is trace time (see pallas_sdpa_bwd)
+    _observe.event("kernel_path", op="nn.banded_attention",
+                   rung=f"{'band' if window else 'causal'}_{nkb}x{bk}", T=Tq,
+                   hd=hd, staged_bytes=(2 * Tq + 4 * bk) * hd * q.dtype.itemsize
+                   + Tq * (hd + 2) * 4)
+
+    def kmap(h, r, j, qp, kp):
+        j_lo, j_hi = _band_blocks(qp[0], kp[0], Tq=Tq, bk=bk, nkb=nkb,
+                                  window=window)
+        return (h, jnp.clip(j, j_lo, j_hi), 0)
+
+    qmap = lambda h, r, j, qp, kp: (h * n_rep + r, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(KV, n_rep, nkb),
+        in_specs=[pl.BlockSpec((1, Tq, hd), qmap),
+                  pl.BlockSpec((1, bk, hd), kmap),
+                  pl.BlockSpec((1, bk, hd), kmap)],
+        out_specs=pl.BlockSpec((1, Tq, hd), qmap),
+        scratch_shapes=[pltpu.VMEM((Tq, hd), jnp.float32),
+                        pltpu.VMEM((Tq, 1), jnp.float32),
+                        pltpu.VMEM((Tq, 1), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_banded_kernel, scale=scale_v, bk=bk, nkb=nkb,
+                          window=window),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((H, Tq, hd), q.dtype),
+        interpret=_interpret(),
+    )(jnp.asarray(q_pos0, jnp.int32).reshape(1),
+      jnp.asarray(k_pos0, jnp.int32).reshape(1), q, k, v)
+
+
+def _banded_checker(q, k, v, q_pos0, k_pos0, window=None, scale=None):
+    if not _enabled():
+        return False
+    if q.ndim != 3 or k.ndim != 3 or tuple(k.shape) != tuple(v.shape):
+        return False
+    if q.dtype != k.dtype or v.dtype != k.dtype:
+        return False
+    if not q.dtype.is_float or q.dtype.bytes > 4:
+        return False
+    if q.shape[0] % k.shape[0] or q.shape[2] != k.shape[2]:
+        return False
+    if _interpret():
+        return True
+    return (q.shape[2] % 128 == 0 and _whole_tiles(q.shape[1], q.dtype.bytes)
+            and _banded_key_block(k.shape[1]) is not None)
+
+
+# ---------------------------------------------------------------------------
+# the experts an expert layer holds (serving: decode steps and prefill
+# chunks alike): sort the assignments by expert, ONE grouped matmul kernel
+# over the ragged groups, unsort. Every held expert's group is padded to
+# whole row tiles, so a tile belongs to one expert; the tile -> expert map
+# and the count of live tiles ride as scalar-prefetch operands. An expert no
+# row hit has no tile and costs nothing; an expert's three matrices stream
+# once a row tile (at decode every group fits one tile: once a step). A
+# dead tile (past the live count) skips its compute and pins every index to
+# the last live tile's, so it moves no bytes. Dropless: the buffer holds
+# every assignment whatever the skew.
+# ---------------------------------------------------------------------------
+
+def _moe_tiles(N: int, D: int, F: int, itemsize: int) -> tuple[int, int]:
+    """Row tile and feed-forward block of the grouped kernel: the rows of a
+    decode step in one tile (every group then fits one), 256-row tiles for
+    a chunk (where a tile's GEMMs take as long as its weights' stream), and
+    the largest feed-forward block whose staging stays inside the VMEM the
+    kernel is compiled with."""
+    from thunder_tpu.core.cost_model import VMEM_LIMIT_BYTES
+
+    tm = N if N <= 256 else 256
+    for bf in (512, 256, 128):
+        if F % bf:
+            continue
+        rows = tm * D * (4 * itemsize + 4)      # x, out (double) + f32 acc
+        tiles = 2 * 3 * bf * D * itemsize       # gate/up/down, double
+        if rows + tiles <= int(0.8 * VMEM_LIMIT_BYTES):
+            return tm, bf
+    return tm, min(F, 128)
+
+
+def _moe_kernel(te_ref, nl_ref, x_ref, w_ref, wg_ref, wu_ref, wd_ref, o_ref,
+                acc_ref, *, act: str, nf: int, cast):
+    t, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(t < nl_ref[0])
+    def _live():
+        @pl.when(f == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        x = x_ref[...]
+        g = jax.lax.dot_general(x, wg_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        ga = _ACT_IMPLS[act](g).astype(cast)
+        u = jax.lax.dot_general(x, wu_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32).astype(cast)
+        acc_ref[...] += jax.lax.dot_general(
+            ga * u, wd_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(f == nf - 1)
+        def _finalize():
+            o_ref[...] = (acc_ref[...] * w_ref[...]).astype(o_ref.dtype)
+
+
+def moe_group_layout(expert_ids, E: int, tm: int):
+    """Where each assignment goes in the sorted, tile-padded buffer.
+
+    ``expert_ids`` (N, K) -> ``dest`` (N*K,) buffer row of every assignment
+    (``n_tiles * tm``, past the buffer, for one whose expert is not held),
+    ``tile_expert`` (n_tiles,), ``n_live`` (1,) and the static ``n_tiles``."""
+    A = expert_ids.size
+    n_tiles = A // tm + E
+    flat = expert_ids.reshape(A)
+    held = (flat >= 0) & (flat < E)
+    key = jnp.where(held, flat, E).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    skey = key[order]
+    counts = jnp.sum(key[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
+                     axis=0, dtype=jnp.int32)                     # (E,)
+    tiles = (counts + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    n_live = tile_end[-1]
+    grp_start = jnp.cumsum(counts) - counts
+    e_of = jnp.minimum(skey, E - 1)
+    dest_sorted = jnp.where(
+        skey < E,
+        (tile_end - tiles)[e_of] * tm + jnp.arange(A, dtype=jnp.int32)
+        - grp_start[e_of], n_tiles * tm)
+    dest = jnp.zeros(A, jnp.int32).at[order].set(dest_sorted)
+    last = jnp.maximum(n_live - 1, 0)
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), last),
+        side="right"), E - 1).astype(jnp.int32)
+    return dest, tile_expert, n_live.reshape(1).astype(jnp.int32), n_tiles
+
+
+def pallas_moe_experts(x, w_gate, w_up, w_down, expert_ids, expert_weights,
+                       act: str = "silu"):
+    N, D = x.shape
+    E, F, _ = w_gate.shape
+    K = expert_ids.shape[1]
+    tm, bf = _moe_tiles(N, D, F, x.dtype.itemsize)
+    nf = F // bf
+    _observe.event("kernel_path", op="nn.moe_experts",
+                   rung=f"grouped_{tm}x{bf}", T=N, hd=D,
+                   staged_bytes=tm * D * (4 * x.dtype.itemsize + 4)
+                   + 6 * bf * D * x.dtype.itemsize)
+    dest, tile_expert, n_live, n_tiles = moe_group_layout(expert_ids, E, tm)
+    R = n_tiles * tm
+    src = jnp.zeros(R, jnp.int32).at[dest].set(
+        jnp.arange(N * K, dtype=jnp.int32) // K, mode="drop")
+    w_sorted = jnp.zeros((R, 1), jnp.float32).at[dest, 0].set(
+        expert_weights.reshape(N * K).astype(jnp.float32), mode="drop")
+    xs = x[src]                                                    # (R, D)
+
+    live_t = lambda t, nl: jnp.maximum(jnp.minimum(t, nl[0] - 1), 0)
+    live_f = lambda t, f, nl: jnp.where(t < nl[0], f, nf - 1)
+    rows = lambda t, f, te, nl: (live_t(t, nl), 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(n_tiles, nf),
+        in_specs=[
+            pl.BlockSpec((tm, D), rows),
+            pl.BlockSpec((tm, 1), rows),
+            pl.BlockSpec((1, bf, D),
+                         lambda t, f, te, nl: (te[t], live_f(t, f, nl), 0)),
+            pl.BlockSpec((1, bf, D),
+                         lambda t, f, te, nl: (te[t], live_f(t, f, nl), 0)),
+            pl.BlockSpec((1, D, bf),
+                         lambda t, f, te, nl: (te[t], 0, live_f(t, f, nl))),
+        ],
+        out_specs=pl.BlockSpec((tm, D), rows),
+        scratch_shapes=[pltpu.VMEM((tm, D), jnp.float32)])
+    ys = pl.pallas_call(
+        functools.partial(_moe_kernel, act=act, nf=nf, cast=x.dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
+        interpret=_interpret(), **_grid_params(planned_vmem=True),
+    )(tile_expert, n_live, xs, w_sorted, w_gate, w_up, w_down)
+    # unsort: every row sums what its held assignments produced (a row of a
+    # dead tile was never written: select, never multiply)
+    picked = ys[jnp.minimum(dest, R - 1)].reshape(N, K, D)
+    held = (dest < R).reshape(N, K, 1)
+    out = jnp.sum(jnp.where(held, picked.astype(jnp.float32), 0.0), axis=1)
+    return out.astype(x.dtype)
+
+
+def _moe_experts_checker(x, w_gate, w_up, w_down, expert_ids, expert_weights,
+                         act: str = "silu"):
+    if not _enabled() or act not in _ACT_IMPLS:
+        return False
+    if x.ndim != 2 or w_gate.ndim != 3 or expert_ids.ndim != 2:
+        return False
+    if not x.dtype.is_float or x.dtype.bytes > 4:
+        return False
+    if any(w.dtype != x.dtype for w in (w_gate, w_up, w_down)):
+        return False
+    if not expert_ids.dtype.is_int:
+        return False
+    if _interpret():
+        return True
+    N, D = x.shape
+    F = w_gate.shape[1]
+    tm, _ = _moe_tiles(int(N), int(D), int(F), x.dtype.bytes)
+    return (D % 128 == 0 and F % 128 == 0 and N % tm == 0
+            and _whole_tiles(tm, x.dtype.bytes))
 
 
 # ---------------------------------------------------------------------------
@@ -2399,6 +2712,21 @@ ex.register_implementation("nn.attn_subblock", attn_subblock_op,
                            checker=_attn_subblock_checker)
 ex.register_implementation("nn.decode_layer", decode_layer_op,
                            checker=_decode_layer_checker)
+
+# serving prefill: the chunk's banded flash forward, and the grouped expert
+# matmul both serving programs use. No `profitable` hook: the decomposition
+# of either materialises what the kernel exists to avoid (the whole score
+# matrix; every held expert over every row).
+_banded_sym = get_op("nn.banded_attention")
+banded_attention_op = ex.register_operator(
+    "banded_attention", meta=_banded_sym.meta, fn=pallas_banded_attention)
+ex.register_implementation("nn.banded_attention", banded_attention_op,
+                           checker=_banded_checker)
+_moe_sym = get_op("nn.moe_experts")
+moe_experts_op = ex.register_operator(
+    "moe_experts", meta=_moe_sym.meta, fn=pallas_moe_experts)
+ex.register_implementation("nn.moe_experts", moe_experts_op,
+                           checker=_moe_experts_checker)
 
 # inference-path SDPA (no lse output needed)
 def pallas_sdpa(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False, scale=None):

@@ -155,6 +155,27 @@ def _request_timeline_lines() -> list[str]:
         window = sum(a["window_pages"] for a in walks)
         out.append(f"  decode walk: {live} live of {window} window pages "
                    f"({100.0 * live / window:.1f}%) over {len(walks)} steps")
+    # an engine with a window cache kind: what each kind's walk covered and
+    # what the ring gave back (``schedule`` carries the step's recycled pages)
+    kinds = [a for a in walks if "live_pages_full" in a]
+    if kinds:
+        recycled = sum(r["args"].get("window_pages_recycled", 0) for r in recs
+                       if r["type"] == "span" and r["name"] == "schedule")
+        out.append(f"  cache kinds: full walked "
+                   f"{sum(a['live_pages_full'] for a in kinds)} pages, window "
+                   f"{sum(a['live_pages_window'] for a in kinds)} over "
+                   f"{len(kinds)} steps; {recycled} window pages recycled")
+    routes = [r for r in recs
+              if r["type"] == "event" and r.get("kind") == "moe_route"]
+    if routes:
+        n = len(routes)
+        out.append(f"  expert routing: {sum(r['hit'] for r in routes) / n:.1f} "
+                   f"held experts hit "
+                   f"({sum(r.get('streamed', r['hit']) for r in routes) / n:.1f} "
+                   f"streamed), "
+                   f"{sum(r['local_picks'] for r in routes) / n:.1f} local "
+                   f"picks, largest load {max(r['max_load'] for r in routes)} "
+                   f"over {n} layer-steps")
     return out
 
 

@@ -272,7 +272,8 @@ def sdpa_bwd(g, q, k, v, out, lse, is_causal: bool = False, scale: float | None 
 
 @opsymbol(id="nn.paged_decode_attention")
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
-                           scale: float | None = None):
+                           scale: float | None = None,
+                           window: int | None = None):
     """Ragged-batch attention over a block-allocated paged KV cache — the
     serving engine's decode attention (``thunder_tpu/serving/``): every
     request in the batch reads its OWN context length through its OWN block
@@ -291,6 +292,13 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
 
     Head grouping is GQA-contiguous, matching ``models/llama.forward_step``:
     query head ``h`` reads kv head ``h // (n_heads // kv_heads)``.
+
+    ``window=W`` (decode rows only, T == 1) makes the table a RING: logical
+    page ``p`` of a request sits in column ``p % pages_per_request``, the
+    host having recycled the pages that fell wholly out of the window, and
+    key ``j`` is visible to the row at position ``i = lengths - 1`` iff
+    ``i - W < j <= i``. The walk starts at the first page the window still
+    reaches, so its cost does not grow with the context.
 
     The decomposition below (gather pages through the block table, mask,
     softmax) is the always-available XLA fallback — the Pallas executor
@@ -320,6 +328,10 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     npg = block_tables.shape[1]
     L = npg * ps
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
+    check(window is None or (T == 1 and int(window) >= 1),
+          lambda: f"paged_decode_attention: window={window} takes decode "
+                  f"rows (T == 1) over a ring table; got T={T} (a prefill "
+                  f"chunk attends through nn.banded_attention)")
 
     # gather each request's context from the shared pools via its block
     # table: (KV, P, ps, hd) indexed along the page dim by the flattened
@@ -337,18 +349,41 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     vf = ops.convert_element_type(v, dtypes.float32)
     scores = ops.mul(ops.matmul(qf, kf.mT), scale)        # (B, KV, n_rep*T, L)
     scores = ops.reshape(scores, (B, H, T, L))
-    # ragged causal mask: key j valid for row r iff j <= lengths - T + r
-    col = ops.arange(L)                                   # (L,)
-    row_pos = ops.add(ops.unsqueeze(ops.sub(lengths, T), 1),
-                      ops.unsqueeze(ops.arange(T), 0))    # (B, T)
-    valid = ops.le(ops.unsqueeze(ops.unsqueeze(col, 0), 0),
-                   ops.unsqueeze(row_pos, 2))             # (B, T, L)
+    if window is None:
+        # ragged causal mask: key j valid for row r iff j <= lengths - T + r
+        col = ops.arange(L)                               # (L,)
+        row_pos = ops.add(ops.unsqueeze(ops.sub(lengths, T), 1),
+                          ops.unsqueeze(ops.arange(T), 0))  # (B, T)
+        valid = ops.le(ops.unsqueeze(ops.unsqueeze(col, 0), 0),
+                       ops.unsqueeze(row_pos, 2))         # (B, T, L)
+    else:
+        valid = ops.unsqueeze(
+            _ring_valid(lengths, npg, ps, int(window)), 1)  # (B, 1, L)
     neg = ops.full((), float("-inf"), dtype=dtypes.float32)
     scores = ops.where(ops.expand_to(ops.unsqueeze(valid, 1), scores.shape),
                        scores, neg)
     probs = ops.softmax(scores, -1)
     attn = ops.matmul(ops.reshape(probs, (B, KV, n_rep * T, L)), vf)
     return ops.convert_element_type(ops.reshape(attn, (B, H, T, hd)), q.dtype)
+
+
+def _ring_valid(lengths, npg: int, ps: int, window: int):
+    """(B, npg * ps) mask of the ring slots a decode row may read. Column
+    ``c`` of the ring holds the newest logical page ``p <= last`` with
+    ``p % npg == c``; its token ``o`` sits at position ``p * ps + o``, and
+    the row at ``lengths - 1`` sees positions in ``[lengths - window,
+    lengths)`` (never below 0: a column no page has reached yet maps to a
+    negative position)."""
+    last = ops.unsqueeze(ops.floor_divide(ops.sub(lengths, 1), ps), 1)  # (B,1)
+    col = ops.unsqueeze(ops.arange(npg), 0)                          # (1,npg)
+    back = ops.remainder(ops.add(ops.sub(last, col), npg), npg)
+    page = ops.sub(last, back)                                       # (B,npg)
+    pos = ops.add(ops.unsqueeze(ops.mul(page, ps), 2),
+                  ops.reshape(ops.arange(ps), (1, 1, ps)))           # (B,npg,ps)
+    pos = ops.reshape(pos, (lengths.shape[0], npg * ps))
+    ln = ops.unsqueeze(lengths, 1)
+    lo = ops.maximum(ops.sub(ln, window), 0)
+    return ops.logical_and(ops.ge(pos, lo), ops.lt(pos, ln))
 
 
 def decode_row_write(pool_flat, rows, flat_positions):
@@ -1285,3 +1320,100 @@ def ctc_loss(log_probs, targets, input_lengths, target_lengths, blank: int = 0,
         return ops.sum(loss, None)
     denom = ops.convert_element_type(ops.maximum(tlen, 1), f32)
     return ops.mean(ops.true_divide(loss, denom), None)
+
+
+@opsymbol(id="nn.banded_attention")
+def banded_attention(q, k, v, q_pos0, k_pos0, *, window: int | None = None,
+                     scale: float | None = None):
+    """Attention of a prefill chunk over keys gathered in position order —
+    the serving engine's chunk attention for both cache kinds.
+
+    - ``q``: ``(n_heads, Tq, hd)``, row ``i`` at absolute position
+      ``q_pos0 + i``; ``k`` / ``v``: ``(kv_heads, Lk, hd)``, key ``j`` at
+      absolute position ``k_pos0 + j`` (GQA-contiguous grouping);
+    - ``q_pos0`` / ``k_pos0``: int32 scalars, traced (one program serves
+      every chunk of every prompt);
+    - key at position ``b`` is visible to the row at position ``a`` iff
+      ``0 <= b <= a`` and, with ``window=W``, ``b > a - W``. Keys gathered
+      from below position 0 (a window that reaches past the start) or from
+      beyond the chunk (pages not written yet) are masked by those bounds.
+
+    The decomposition below is the masked softmax; the Pallas executor
+    claims it as a flash forward that skips the key blocks wholly outside
+    the band."""
+    _tensor_like(q, "banded_attention")
+    check(q.ndim == 3 and k.ndim == 3 and tuple(k.shape) == tuple(v.shape)
+          and k.shape[-1] == q.shape[-1] and q.shape[0] % k.shape[0] == 0,
+          lambda: f"banded_attention: q (H, Tq, hd) {tuple(q.shape)} / "
+                  f"k, v (KV, Lk, hd) {tuple(k.shape)}, {tuple(v.shape)}")
+    H, Tq, hd = q.shape
+    KV, Lk, _ = k.shape
+    n_rep = H // KV
+    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
+    qf = ops.convert_element_type(ops.reshape(q, (KV, n_rep * Tq, hd)),
+                                  dtypes.float32)
+    kf = ops.convert_element_type(k, dtypes.float32)
+    vf = ops.convert_element_type(v, dtypes.float32)
+    scores = ops.reshape(ops.mul(ops.matmul(qf, kf.mT), scale),
+                         (KV, n_rep, Tq, Lk))
+    a = ops.unsqueeze(ops.add(ops.arange(Tq), q_pos0), 1)            # (Tq, 1)
+    b = ops.unsqueeze(ops.add(ops.arange(Lk), k_pos0), 0)            # (1, Lk)
+    valid = ops.logical_and(ops.ge(b, 0), ops.le(b, a))
+    if window is not None:
+        valid = ops.logical_and(valid, ops.gt(b, ops.sub(a, int(window))))
+    neg = ops.full((), float("-inf"), dtype=dtypes.float32)
+    scores = ops.where(ops.expand_to(ops.reshape(valid, (1, 1, Tq, Lk)),
+                                     scores.shape), scores, neg)
+    probs = ops.softmax(scores, -1)
+    out = ops.matmul(ops.reshape(probs, (KV, n_rep * Tq, Lk)), vf)
+    return ops.convert_element_type(ops.reshape(out, (H, Tq, hd)), q.dtype)
+
+
+@opsymbol(id="nn.moe_experts")
+def moe_experts(x, w_gate, w_up, w_down, expert_ids, expert_weights, *,
+                act: str = "silu"):
+    """The gated experts an expert layer HOLDS, applied to the rows routed
+    to them, dropless::
+
+        out[n] = sum_k  expert_weights[n, k] * E_{expert_ids[n, k]}(x[n])
+        E_e(h) = (act(h @ w_gate[e].T) * (h @ w_up[e].T)) @ w_down[e].T
+
+    - ``x``: ``(N, D)``; ``w_gate`` / ``w_up``: ``(E, F, D)``; ``w_down``:
+      ``(E, D, F)`` — the experts held here, however many the router
+      chooses among;
+    - ``expert_ids``: ``(N, K)`` int32 LOCAL ids; an id outside ``[0, E)``
+      is an assignment to an expert held elsewhere and adds nothing;
+    - ``expert_weights``: ``(N, K)`` float32 combine weights.
+
+    The decomposition runs every held expert over every row and combines
+    with a dense ``(N, E)`` weight matrix (exact, E x the work); the Pallas
+    executor claims it as sort-by-expert, one grouped matmul kernel over
+    the ragged groups (an expert no row hit costs nothing, an expert's
+    weights stream once a row tile), unsort."""
+    _tensor_like(x, "moe_experts")
+    check(act in _SUBBLOCK_ACTS, lambda: f"moe_experts: unknown act {act!r}")
+    check(x.ndim == 2 and w_gate.ndim == 3
+          and tuple(w_up.shape) == tuple(w_gate.shape)
+          and tuple(w_down.shape) == (w_gate.shape[0], x.shape[1],
+                                      w_gate.shape[1])
+          and w_gate.shape[2] == x.shape[1],
+          lambda: f"moe_experts: x {tuple(x.shape)}, w_gate "
+                  f"{tuple(w_gate.shape)}, w_down {tuple(w_down.shape)}")
+    check(expert_ids.ndim == 2 and expert_ids.shape[0] == x.shape[0]
+          and tuple(expert_weights.shape) == tuple(expert_ids.shape),
+          lambda: f"moe_experts: ids {tuple(expert_ids.shape)} / weights "
+                  f"{tuple(expert_weights.shape)} for {x.shape[0]} rows")
+    N, D = x.shape
+    E = w_gate.shape[0]
+    hit = ops.eq(ops.unsqueeze(expert_ids, 2),
+                 ops.reshape(ops.arange(E), (1, 1, E)))              # (N,K,E)
+    combine = ops.sum(ops.where(
+        hit, ops.expand_to(ops.unsqueeze(expert_weights, 2), hit.shape),
+        ops.full((), 0.0, dtype=dtypes.float32)), 1)                 # (N, E)
+    xe = ops.unsqueeze(x, 0)                                         # (1,N,D)
+    g = _LINEAR_ACT_FNS[act](ops.matmul(xe, w_gate.mT))              # (E,N,F)
+    y = ops.matmul(ops.mul(g, ops.matmul(xe, w_up.mT)), w_down.mT)   # (E,N,D)
+    yf = ops.convert_element_type(y, dtypes.float32)
+    out = ops.sum(ops.mul(yf, ops.unsqueeze(ops.transpose(combine, (1, 0)),
+                                            2)), 0)
+    return ops.convert_element_type(out, x.dtype)

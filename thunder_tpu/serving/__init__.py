@@ -17,6 +17,12 @@ The layers (ROADMAP item 1 + the serving containment story):
   page-granularity token trie; admission probes it, completed requests
   donate their prompt pages, the allocator evicts parked pages under
   pressure (the cache can never starve live traffic).
+- :mod:`thunder_tpu.serving.description` — the model's side of the engine:
+  a :class:`~description.ModelDescription` names every layer's cache kind
+  (``full``, or ``window(W)`` kept as a ring of pages the scheduler
+  recycles) and brings the traced decode and prefill functions. The Llama
+  family's is the first; ``models/cohere2_moe.py`` brings a second
+  (window and global layers, a parallel attention + expert block).
 - :mod:`thunder_tpu.serving.runner` — the compiled paged prefill/decode
   step programs (``bind()``-dispatched decode; ``LengthBucketer``-laddered
   prefill chunks; ragged attention via ``nn.paged_decode_attention``,
@@ -101,7 +107,13 @@ from thunder_tpu.serving.router import (  # noqa: F401
     RandomPlacement,
     RoutingPolicy,
 )
-from thunder_tpu.serving.runner import PagedLlamaRunner  # noqa: F401
+from thunder_tpu.serving.description import (  # noqa: F401
+    CacheKind,
+    LlamaDescription,
+    ModelDescription,
+    describe,
+)
+from thunder_tpu.serving.runner import PagedLlamaRunner, PagedRunner  # noqa: F401
 from thunder_tpu.serving.sampling import (  # noqa: F401
     GREEDY,
     SamplingParams,

@@ -68,10 +68,15 @@ class RestartState:
     mesh: Any = None
 
     def describe(self) -> dict:
-        d = {"n_layers": self.geometry.n_layers,
-             "kv_heads": self.geometry.kv_heads,
-             "num_pages": self.geometry.num_pages,
+        # one geometry a cache kind; a one-kind engine carries it bare
+        kinds = self.geometry if isinstance(self.geometry, tuple) \
+            else (self.geometry,)
+        d = {"n_layers": sum(g.n_layers for g in kinds),
+             "kv_heads": kinds[0].kv_heads,
+             "num_pages": kinds[0].num_pages,
              "tp_degree": 1, "mesh_shape": [1]}
+        if len(kinds) > 1:
+            d["num_pages_by_kind"] = [g.num_pages for g in kinds]
         if self.mesh is not None:
             md = self.mesh.describe()
             d["tp_degree"] = int(md["tp"])

@@ -7,6 +7,11 @@ requests at wildly different sequence lengths share one device allocation,
 so the compiled decode step has ONE shape regardless of who is resident
 (no per-request recompiles, no per-request max_len buffers).
 
+One ``PagedKVCache`` holds the layers of ONE cache kind (``PageGeometry``:
+``window`` None keeps the whole context, ``window=W`` the last W positions
+in a ring of ``ceil(W / page) + 1`` pages a request, which the scheduler
+recycles as the context slides); the engine keeps one a kind.
+
 Page 0 is reserved as the scratch page: it is never allocated, inactive
 decode slots write their (discarded) K/V there, and unallocated block-table
 entries point at it — every table entry is always a valid pool index, which
@@ -43,7 +48,9 @@ class PageGeometry:
     head_dim: int
     page_size: int       # tokens per page
     num_pages: int       # pool pages per layer, INCLUDING the reserved page 0
-    pages_per_request: int  # block-table width (max context / page_size)
+    pages_per_request: int  # block-table width (max context / page_size;
+    #                         a window kind's ring: ceil(window / page) + 1)
+    window: int | None = None  # positions a window kind keeps (None: all)
 
     @property
     def max_context(self) -> int:

@@ -45,76 +45,37 @@ and re-creates it), never stored here.
 
 from __future__ import annotations
 
-from thunder_tpu.core import dtypes, prims
-from thunder_tpu import ops
-from thunder_tpu.ops import nn as tnn
-from thunder_tpu.serving.sampling import sample_tokens
+from thunder_tpu.serving.description import ModelDescription, describe
 
 
-def _rope_tables_at(cfg, positions, dtype):
-    """Per-request rotary tables: ``positions`` (S,) int32 -> cos/sin
-    ``(S, 1, 1, hd/2)``, broadcasting over heads and the single decode row.
-    The frequency math lives in ``models.llama._rope_tables`` — ONE owner
-    shared with training and prefill, so rope changes can't silently break
-    the engine's token-identity with ``generate()``."""
-    from thunder_tpu.models.llama import _rope_tables
+class PagedRunner:
+    """Builds + owns the compiled paged step functions for one engine. The
+    traced bodies are the model description's (``serving/description.py``);
+    the runner compiles them, binds the decode step and publishes its
+    fusion shape."""
 
-    cos, sin = _rope_tables(cfg, positions, dtype)     # (S, hd/2)
-    shape = (positions.shape[0], 1, 1, cfg.head_dim // 2)
-    return ops.reshape(cos, shape), ops.reshape(sin, shape)
-
-
-def _write_rows(pool, rows, flat_positions):
-    """Scatter every slot's K/V row into a flattened page pool in ONE
-    scatter op.
-
-    ``pool``: (KV, P*ps, hd); ``rows``: (S, KV, 1, hd); ``flat_positions``:
-    (S,) int32 of page*ps+offset. Replace semantics (``prims.scatter``) —
-    freed pages hold stale values, so add-style scatters would corrupt.
-    Idle slots all target position 0 (the reserved scratch page); duplicate
-    indices there are benign (any write wins, nobody reads it). One scatter
-    beats S chained dynamic_update_slices: XLA copies the input pool once
-    either way, but the chain pays S update kernels.
-
-    The op emission lives in ``ops.nn.decode_row_write`` — ONE owner shared
-    with the ``nn.attn_subblock`` decomposition, so the block planner's
-    chain matcher and the quarantine fallback always see the exact sequence
-    this runner traces."""
-    return tnn.decode_row_write(pool, rows, flat_positions)
-
-
-def _write_pages(pool, rows, page_positions, ps: int):
-    """Scatter a prefill chunk's K/V into its pages. ``rows``: (KV, C, hd)
-    with C a multiple of ps; ``page_positions``: (C//ps,) int32 flat
-    positions (page*ps) — chunks start page-aligned by construction."""
-    zero = ops.full((), 0, dtype=dtypes.int32)
-    C = rows.shape[1]
-    for i in range(C // ps):
-        pos = ops.getitem(page_positions, i)
-        pool = prims.dynamic_update_slice(pool, ops.narrow(rows, 1, i * ps, ps),
-                                          (zero, pos, zero))
-    return pool
-
-
-class PagedLlamaRunner:
-    """Builds + owns the compiled paged step functions for one engine."""
-
-    def __init__(self, cfg, geometry, *, n_layers: int | None = None,
+    def __init__(self, model, geometry, *, n_layers: int | None = None,
                  executors=None, block_fusion=None,
                  launch_budget_per_layer: float | None = None, mesh=None,
                  engine_id: str | None = None):
         import thunder_tpu as tt
         from thunder_tpu.observe import registry as _observe
 
-        self.cfg = cfg
-        self.geom = geometry
+        # ``model``: a description, or a config ``describe`` knows;
+        # ``geometry``: one PageGeometry a cache kind (a bare one for a
+        # one-kind model)
+        self.desc: ModelDescription = describe(model, n_layers)
+        self.cfg = self.desc.cfg
+        self.geoms = tuple(geometry) if isinstance(geometry, (tuple, list)) \
+            else (geometry,)
+        self.geom = self.geoms[0]
         self.mesh = mesh  # distributed.gspmd.TensorParallelMesh or None
         # owning engine's label: the runner's gauge/event emissions (decode
         # bind shape) must land in that engine's series, not a shared one
         self.engine_id = engine_id
         self.obs = (_observe.labeled(engine=engine_id)
                     if engine_id is not None else None)
-        self.n_layers = n_layers if n_layers is not None else cfg.n_layers
+        self.n_layers = self.desc.n_layers
         # decode-launch budget: when set (via census_context below), a
         # decode program dispatching more Pallas launches per layer per
         # token than the budget yields a typed `decode-launch-growth`
@@ -158,104 +119,20 @@ class PagedLlamaRunner:
             self.decode_jit._stats.census_context.update(md)
             self.prefill_jit._stats.census_context = dict(md)
 
-    # -- traced bodies ------------------------------------------------------
-    def _attn_block(self, h, layer, q, block_tables, lengths, pools_kv):
-        """Shared attention tail: this step's K/V rows are already written
-        into the pools; run paged attention and the residual + MLP."""
-        cfg = self.cfg
-        B, T = h.shape[0], h.shape[1]
-        attn = tnn.paged_decode_attention(q, pools_kv["k"], pools_kv["v"],
-                                          block_tables, lengths)
-        attn = ops.reshape(ops.transpose(attn, (0, 2, 1, 3)),
-                           (B, T, cfg.n_heads * cfg.head_dim))
-        h = ops.add(h, ops.linear(attn, layer["wo"]))
-        from thunder_tpu.models.llama import _mlp
-
-        return _mlp(h, layer, cfg)
-
+    # -- traced bodies: the description's, with every per-kind argument as a
+    # tuple in ``cache_kinds`` order -----------------------------------------
     def _decode_fn(self, params, tokens, block_tables, lengths, write_pos,
                    pools, temps, top_ks, top_ps, rng):
-        """One continuous-batching decode step for every slot.
-
-        tokens (S, 1) int32; block_tables (S, npg) int32; lengths (S,) int32
-        context length INCLUDING this token; write_pos (S,) int32 flat pool
-        position of this token's K/V row (the scratch position 0 for replay
-        rows, whose K/V already exists). Sampling inputs: temps (S,) f32,
-        top_ks (S,) int32, top_ps (S,) f32, rng (S, 2) uint32 raw threefry
-        keys. Returns (sampled token ids (S,) int32, logits (S, V), pools)
-        — the logits output exists for parity tests and future logprob
-        surfacing; the scheduler fetches only the token ids."""
-        cfg = self.cfg
-        g = self.geom
-        h = ops.embedding(tokens, params["tok_embedding"])             # (S,1,D)
-        cos, sin = _rope_tables_at(cfg, ops.sub(lengths, 1), h.dtype)
-        new_pools = []
-        flat = (g.kv_heads, g.num_pages * g.page_size, g.head_dim)
-        paged = (g.kv_heads, g.num_pages, g.page_size, g.head_dim)
-        for layer, kv in zip(params["layers"], pools):
-            x = ops.rms_norm(h, layer["attn_norm"], eps=cfg.norm_eps)
-            q, k, v = self._qkv(x, layer, cos, sin)
-            kp = _write_rows(ops.reshape(kv["k"], flat), k, write_pos)
-            vp = _write_rows(ops.reshape(kv["v"], flat), v, write_pos)
-            kv = {"k": ops.reshape(kp, paged), "v": ops.reshape(vp, paged)}
-            new_pools.append(kv)
-            h = self._attn_block(h, layer, q, block_tables, lengths, kv)
-        h = ops.rms_norm(h, params["norm_f"], eps=cfg.norm_eps)
-        logits = ops.squeeze(ops.linear(h, params["lm_head"]), 1)      # (S,V)
-        # in-graph sampling epilogue: one more fused tail on the program we
-        # already dispatch once per token (greedy == temperature 0)
-        toks = sample_tokens(logits, temps, top_ks, top_ps, rng)
-        return toks, logits, new_pools
-
-    def _qkv(self, x, layer, cos, sin):
-        """RoPE'd q/k/v heads (decode layout: T == x.shape[1])."""
-        from thunder_tpu.models.llama import _apply_rope
-
-        cfg = self.cfg
-        B, T = x.shape[0], x.shape[1]
-        hd = cfg.head_dim
-        q = ops.transpose(ops.reshape(ops.linear(x, layer["wq"]),
-                                      (B, T, cfg.n_heads, hd)), (0, 2, 1, 3))
-        k = ops.transpose(ops.reshape(ops.linear(x, layer["wk"]),
-                                      (B, T, cfg.kv_heads, hd)), (0, 2, 1, 3))
-        v = ops.transpose(ops.reshape(ops.linear(x, layer["wv"]),
-                                      (B, T, cfg.kv_heads, hd)), (0, 2, 1, 3))
-        return _apply_rope(q, cos, sin), _apply_rope(k, cos, sin), v
+        return self.desc.decode(self.geoms, params, tokens,
+                                _per_kind(block_tables), lengths,
+                                _per_kind(write_pos), pools, temps, top_ks,
+                                top_ps, rng)
 
     def _prefill_fn(self, params, tokens, block_tables, lengths, page_writes,
                     pools):
-        """One prefill chunk of one request — K/V writes only, no logits.
-
-        tokens (1, C) int32 (C from the bucket ladder, multiple of the page
-        size; padded past the prompt tail); block_tables (1, npg); lengths
-        (1,) int32 = chunk_start + C (context including the padded chunk);
-        page_writes (C//ps,) int32 flat positions of the chunk's pages.
-        Returns the updated pools. The first token is sampled by a decode
-        REPLAY row of the same iteration's decode step, dispatched behind
-        the final chunk, so prefill carries no lm_head work at all (the
-        old last-row logits slice is gone with its host argmax)."""
-        cfg = self.cfg
-        g = self.geom
-        C = tokens.shape[1]
-        from thunder_tpu.models.llama import _project_qkv, _rope_cos_sin
-
-        h = ops.embedding(tokens, params["tok_embedding"])             # (1,C,D)
-        pos0 = ops.sub(ops.getitem(lengths, 0), C)
-        cos, sin = _rope_cos_sin(cfg, C, h.dtype, pos_offset=pos0)
-        new_pools = []
-        flat = (g.kv_heads, g.num_pages * g.page_size, g.head_dim)
-        paged = (g.kv_heads, g.num_pages, g.page_size, g.head_dim)
-        for layer, kv in zip(params["layers"], pools):
-            x = ops.rms_norm(h, layer["attn_norm"], eps=cfg.norm_eps)
-            q, k, v = _project_qkv(x, layer, cfg, cos, sin)
-            kp = _write_pages(ops.reshape(kv["k"], flat), ops.squeeze(k, 0),
-                              page_writes, g.page_size)
-            vp = _write_pages(ops.reshape(kv["v"], flat), ops.squeeze(v, 0),
-                              page_writes, g.page_size)
-            kv = {"k": ops.reshape(kp, paged), "v": ops.reshape(vp, paged)}
-            new_pools.append(kv)
-            h = self._attn_block(h, layer, q, block_tables, lengths, kv)
-        return new_pools
+        return self.desc.prefill(self.geoms, params, tokens,
+                                 _per_kind(block_tables), lengths,
+                                 _per_kind(page_writes), pools)
 
     # -- dispatch -----------------------------------------------------------
     def bind_decode(self, *args):
@@ -307,3 +184,10 @@ class PagedLlamaRunner:
         # fallback rung was bound when the fault hit)
         rec.event("serving_decode_bind", launches=launches,
                   decode_layer_fusions=layers)
+
+
+def _per_kind(arg) -> tuple:
+    return tuple(arg) if isinstance(arg, (tuple, list)) else (arg,)
+
+
+PagedLlamaRunner = PagedRunner      # the name the first description shipped under

@@ -122,6 +122,18 @@ different sequence lengths served by ONE compiled decode program.
   pressure, so the cache can never starve live traffic. A warm hit
   collapses TTFT to one tail-chunk prefill
   (``serving.prefix_hit_rate`` / ``serving.cached_pages``).
+- **Cache kinds**: the model's description (``serving/description.py``)
+  names every layer's kind, and each distinct kind has its own pool,
+  allocator and block table. A ``full`` kind's table spans the context; a
+  ``window(W)`` kind's is a RING of ``ceil(W / page) + 1`` columns (logical
+  page ``p`` in column ``p mod width``): ``schedule`` returns the page that
+  fell wholly out of a request's window to the free list before the next
+  one is taken (``kv.window_pages_recycled``; ``decode_dispatch`` then
+  carries ``live_pages_full`` / ``live_pages_window``), and a prefill chunk
+  gathers the ring BEFORE it writes, so its own pages take recycled
+  columns. Admission, preemption and ``assert_quiescent`` count every
+  kind; best-of forks and the prefix cache are refused
+  (``InfeasibleRequest``) on a model with a window kind.
 """
 
 from __future__ import annotations
@@ -146,9 +158,10 @@ from thunder_tpu.serving.errors import (
     RestartState,
     ShardingGeometryError,
 )
+from thunder_tpu.serving.description import describe
 from thunder_tpu.serving.kv_cache import OutOfPages, PagedKVCache, PageGeometry
 from thunder_tpu.serving.prefix_cache import PrefixCache
-from thunder_tpu.serving.runner import PagedLlamaRunner
+from thunder_tpu.serving.runner import PagedRunner
 from thunder_tpu.serving.sampling import GREEDY, SamplingParams
 
 QUEUED, PREFILL, DECODE, DONE, SHED = \
@@ -166,31 +179,6 @@ _REQUEST_IDS = itertools.count()
 _ENGINE_IDS = itertools.count()
 
 
-def _as_tp_mesh(mesh, cfg):
-    """Normalize the engine's ``mesh=`` argument (None, an int tp degree,
-    or a ``TensorParallelMesh``) and validate the model config against it
-    with typed errors — a bad split must fail HERE, not as an opaque XLA
-    partitioner error three layers down."""
-    if mesh is None:
-        return None
-    from thunder_tpu.distributed.gspmd import TensorParallelMesh
-    from thunder_tpu.models.llama import TP_COLUMN_PATTERNS, TP_ROW_PATTERNS
-
-    if isinstance(mesh, int):
-        mesh = TensorParallelMesh(tp=mesh,
-                                  column_patterns=TP_COLUMN_PATTERNS,
-                                  row_patterns=TP_ROW_PATTERNS)
-    if mesh.tp <= 1:
-        return None
-    for name, n in (("n_heads", cfg.n_heads), ("kv_heads", cfg.kv_heads),
-                    ("intermediate_size", cfg.intermediate_size)):
-        if n % mesh.tp != 0:
-            raise ShardingGeometryError(
-                f"config {cfg.name}: {name}={n} not divisible by "
-                f"tp={mesh.tp}", kv_heads=cfg.kv_heads, tp=mesh.tp)
-    return mesh
-
-
 @dataclass(eq=False)  # identity semantics: requests live in slot lists
 class Request:
     """One generation request and its full lifecycle state."""
@@ -204,7 +192,11 @@ class Request:
     submitted_s: float = 0.0
     state: str = QUEUED
     error: BaseException | None = None  # set when state == SHED
-    pages: list = field(default_factory=list)   # allocated page ids, in order
+    # allocated page ids a cache kind, in logical-page order; kind k's list
+    # holds logical pages ``page_base[k] ..`` (a window kind's ring drops
+    # the pages its window has left; a full kind's base stays 0)
+    kind_pages: list = field(default_factory=lambda: [[]])
+    page_base: list = field(default_factory=lambda: [0])
     prefilled: int = 0                  # work-prompt tokens written so far
     length: int = 0                     # context tokens written into the cache
     next_token: int | None = None       # sampled, not yet fed to decode
@@ -237,6 +229,22 @@ class Request:
     _phase_t0_us: float = 0.0
 
     @property
+    def pages(self) -> list:
+        """The first cache kind's pages (the only kind of a one-kind
+        model)."""
+        return self.kind_pages[0]
+
+    @pages.setter
+    def pages(self, value: list) -> None:
+        self.kind_pages[0] = value
+
+    def drop_pages(self, n_kinds: int) -> None:
+        """Forget every page (they were freed, or died with their pool)."""
+        self.kind_pages = [[] for _ in range(n_kinds)]
+        self.page_base = [0] * n_kinds
+        self.pages_version += 1
+
+    @property
     def work_prompt(self) -> np.ndarray:
         """What prefill must write: the original prompt plus any tokens
         generated before a preemption or engine restart
@@ -261,7 +269,10 @@ class Request:
 
 
 class ServingEngine:
-    """Continuous-batching serving runtime for a Llama-family model.
+    """Continuous-batching serving runtime. ``cfg`` names the model: its
+    description (``serving/description.py::describe``) brings the per-layer
+    cache kinds and the two step functions; a config without one of its own
+    is a Llama-family config.
 
     >>> eng = ServingEngine(params, cfg, max_slots=8, page_size=16,
     ...                     max_context=256, n_layers=2)
@@ -293,15 +304,16 @@ class ServingEngine:
         self.engine_id = engine_id if engine_id is not None \
             else f"e{next(_ENGINE_IDS)}"
         self.obs = _observe.labeled(engine=self.engine_id)
-        self.mesh = _as_tp_mesh(mesh, cfg)
+        self.desc = describe(cfg, n_layers)
+        self.mesh = self.desc.tp_mesh(mesh)
         if self.mesh is not None:
             from thunder_tpu.distributed.gspmd import shard_params
 
             params = shard_params(params, self.mesh)
         self.params = params
         self.cfg = cfg
-        n_layers_eff = n_layers if n_layers is not None else cfg.n_layers
-        max_context = int(max_context or cfg.max_seq_len)
+        desc = self.desc
+        max_context = int(max_context or desc.max_seq_len)
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         # prefill chunk ladder: powers-of-two multiples of the page size —
@@ -322,25 +334,51 @@ class ServingEngine:
         # chunk-padded prefill can never outrun the block table
         max_context = -(-max_context // self.max_chunk) * self.max_chunk
         self.max_context = max_context
-        pages_per_req = -(-max_context // page_size)
-        if num_pages is None:
-            num_pages = max_slots * pages_per_req + 1  # + reserved page 0
-        geometry = PageGeometry(
-            n_layers=n_layers_eff, kv_heads=cfg.kv_heads, head_dim=cfg.head_dim,
-            page_size=page_size, num_pages=int(num_pages),
-            pages_per_request=pages_per_req)
-        self.geom = geometry
+        # one pool and one block table a cache KIND (description.CacheKind):
+        # a full kind's table spans the context, a window kind's is the ring
+        # ``ceil(W / page) + 1``. ``num_pages`` sizes the first kind's pool,
+        # or every kind's by name ({"full": n, "window": m}); default: full
+        # residency for every slot (+ the reserved page 0)
+        self.kinds = desc.cache_kinds
+        sized = num_pages if isinstance(num_pages, dict) \
+            else {self.kinds[0].name: num_pages}
+        geoms = []
+        for k, kind in enumerate(self.kinds):
+            width = kind.pages_per_request(max_context, page_size)
+            n = sized.get(kind.name)
+            geoms.append(PageGeometry(
+                n_layers=desc.layer_kinds.count(k), kv_heads=desc.kv_heads,
+                head_dim=desc.head_dim, page_size=page_size,
+                num_pages=int(n if n is not None else max_slots * width + 1),
+                pages_per_request=width, window=kind.window))
+        self.geoms = tuple(geoms)
+        self.geom = geometry = geoms[0]
+        self._windowed = any(kind.window is not None for kind in self.kinds)
         # the typed restart state: everything a supervisor rebuild needs to
         # recreate the pool EXACTLY — geometry + dtype + mesh — carried on
         # every EngineFault so recovery is sharding-identical
         self._restart_state = RestartState(
-            geometry=geometry, dtype=cfg.dtype.jax, mesh=self.mesh)
-        self.cache = PagedKVCache(geometry, cfg.dtype.jax, sharding=self.mesh)
+            geometry=geometry if len(geoms) == 1 else self.geoms,
+            dtype=desc.dtype, mesh=self.mesh)
+        self.caches = [PagedKVCache(g, desc.dtype, sharding=self.mesh)
+                       for g in geoms]
+        self.cache = self.caches[0]
+        # an engine of several kinds publishes its page gauge a kind too
+        self._kind_obs = [
+            _observe.labeled(engine=self.engine_id, kind=kind.name)
+            for kind in self.kinds] if len(geoms) > 1 else []
         # cross-request prefix cache (opt-in): completed prompts donate
-        # their full pages into a token trie; admission probes it
+        # their full pages into a token trie; admission probes it. A window
+        # ring recycles the very pages a later prompt would want to reuse:
+        # refused, typed, like a best-of fork of one (``submit``)
+        if prefix_cache and self._windowed:
+            raise InfeasibleRequest(
+                f"model {cfg.name}: prefix reuse over a window ring is not "
+                f"supported (the ring recycles the pages of a prompt's head)",
+                engine_id=self.engine_id)
         self.prefix = PrefixCache(self.cache) if prefix_cache else None
-        self.runner = PagedLlamaRunner(
-            cfg, geometry, n_layers=n_layers, executors=executors,
+        self.runner = PagedRunner(
+            desc, self.geoms, executors=executors,
             block_fusion=block_fusion,
             launch_budget_per_layer=launch_budget_per_layer, mesh=self.mesh,
             engine_id=self.engine_id)
@@ -376,9 +414,12 @@ class ServingEngine:
         # per-step host work stays O(active), not O(slots * table width)
         S = self.max_slots
         self._np_tokens = np.zeros((S, 1), np.int32)
-        self._np_bt = np.zeros((S, pages_per_req), np.int32)
+        self._np_bts = [np.zeros((S, g.pages_per_request), np.int32)
+                        for g in geoms]
+        self._np_bt = self._np_bts[0]
         self._np_len = np.ones(S, np.int32)
-        self._np_wp = np.zeros(S, np.int32)
+        self._np_wps = [np.zeros(S, np.int32) for _ in geoms]
+        self._recycled = 0              # window pages recycled, this step
         self._bt_slot_version: list = [None] * S
         # per-slot sampling rows fed to the in-graph sampler: temperature /
         # top-k / top-p plus a raw threefry key [stream_seed, counter].
@@ -431,11 +472,18 @@ class ServingEngine:
         # a ladder size, which can transiently need more pages than the
         # final context — e.g. a 33-token prompt prefills as one 64 chunk)
         worst = max(total, self._padded_prefill_len(total))
-        if self.geom.pages_for(worst) > self.cache.pages_total:
+        for g, cache in zip(self.geoms, self.caches):
+            need = min(g.pages_for(worst), g.pages_per_request)
+            if need > cache.pages_total:
+                raise InfeasibleRequest(
+                    f"request needs up to {need} KV pages; the pool only "
+                    f"has {cache.pages_total} — enlarge num_pages",
+                    engine_id=self.engine_id)
+        if best_of > 1 and self._windowed:
             raise InfeasibleRequest(
-                f"request needs up to {self.geom.pages_for(worst)} KV pages; "
-                f"the pool only has {self.cache.pages_total} — enlarge "
-                f"num_pages", engine_id=self.engine_id)
+                f"best_of={best_of}: a copy-on-write fork of a window ring "
+                f"is not supported (the clones' rings would recycle shared "
+                f"pages)", engine_id=self.engine_id)
         now = time.perf_counter()
 
         def new_request(sp: SamplingParams, parent=None) -> Request:
@@ -528,7 +576,11 @@ class ServingEngine:
                     if r is not None and r.fork_pending:
                         worked = self._materialize_forks(r) or worked
                 worked = self._admit() or worked
+                self._recycled = 0
                 self._reserve_decode_pages()
+                if self._windowed and sched.live:
+                    sched.args = {**self._step_args,
+                                  "window_pages_recycled": self._recycled}
                 if not busy:
                     # idle polling steps stay out of the flight ring — a
                     # long idle stretch must not flush the last incident's
@@ -619,8 +671,7 @@ class ServingEngine:
         for req in residents:
             self.slots[self.slots.index(req)] = None
             self._phase_end(req, reason="engine_restart")
-            req.pages = []          # the pool they lived in is gone
-            req.pages_version += 1
+            req.drop_pages(len(self.kinds))  # the pools they lived in are gone
             req.prefilled = 0
             req.length = 0
             req.next_token = None
@@ -635,7 +686,9 @@ class ServingEngine:
         # (geometry alone would rebuild an unsharded pool and the next
         # dispatch would recompile or crash)
         rs = self._restart_state
-        self.cache = PagedKVCache(rs.geometry, rs.dtype, sharding=rs.mesh)
+        self.caches = [PagedKVCache(g, rs.dtype, sharding=rs.mesh)
+                       for g in self.geoms]
+        self.cache = self.caches[0]
         if self.mesh is not None:
             from thunder_tpu.distributed.gspmd import mesh_descriptor
 
@@ -648,7 +701,8 @@ class ServingEngine:
             self.prefix = PrefixCache(self.cache)
         self._decode_bound = None
         self._bound_epoch = -1
-        self._np_bt[:] = 0
+        for bt in self._np_bts:
+            bt[:] = 0
         self._bt_slot_version = [None] * self.max_slots
         self._gauges()
         return residents
@@ -662,7 +716,8 @@ class ServingEngine:
             raise AssertionError(
                 f"engine not idle: resident {busy}, "
                 f"queued {[r.request_id for r in self.queue]}")
-        self.cache.assert_quiescent(self._np_bt)
+        for cache, bt in zip(self.caches, self._np_bts):
+            cache.assert_quiescent(bt)
 
     def reset_slo_window(self) -> None:
         """Restart SLO-attainment accounting (benchmarks: exclude warmup)."""
@@ -704,7 +759,12 @@ class ServingEngine:
             "pages_free": self.cache.pages_free,
             "pages_total": self.cache.pages_total,
             "peak_pages_used": self.cache.peak_pages_used,
-            "pools_alive": self.cache.pools_alive(),
+            "pools_alive": self._pools_alive(),
+            "cache_kinds": [
+                {"kind": kind.name, "window": kind.window,
+                 "layers": g.n_layers, "pages_free": c.pages_free,
+                 "pages_total": c.pages_total}
+                for kind, g, c in zip(self.kinds, self.geoms, self.caches)],
             "cached_pages": self.cache.cached_pages,
             "cow_copies": self.cache.cow_copies,
             "prefix_hit_rate": (round(self.prefix.hit_rate(), 4)
@@ -758,6 +818,8 @@ class ServingEngine:
         self.obs.set_gauge("serving.queue_depth", len(self.queue))
         self.obs.set_gauge("serving.active_requests", self.active_requests)
         self.obs.set_gauge("serving.kv_pages_free", self.cache.pages_free)
+        for obs, cache in zip(self._kind_obs, self.caches):
+            obs.set_gauge("serving.kv_pages_free", cache.pages_free)
         if self.prefix is not None:
             self.obs.set_gauge("serving.cached_pages", self.cache.cached_pages)
         if self._slo_total:
@@ -830,11 +892,12 @@ class ServingEngine:
         """Return a resident request's pages and zero its block-table row
         (the quiescence invariant: idle rows reference only page 0)."""
         slot = self.slots.index(req)
-        self.cache.free(req.pages)
-        req.pages = []
-        req.pages_version += 1
+        for cache, pages in zip(self.caches, req.kind_pages):
+            cache.free(pages)
+        req.drop_pages(len(self.kinds))
         self.slots[slot] = None
-        self._np_bt[slot] = 0
+        for bt in self._np_bts:
+            bt[slot] = 0
         self._bt_slot_version[slot] = None
 
     def _admit(self) -> bool:
@@ -861,6 +924,14 @@ class ServingEngine:
             if self.cache.pages_free + self.cache.cached_pages \
                     - parked_hits < need_new:
                 break   # page back-pressure: wait for completions/evictions
+            # the other kinds' pages are taken chunk by chunk
+            # (``_prefill_one``); admission only waits until each pool
+            # could cover the first chunk
+            first = self._chunk_pages(0, min(len(wp), first_chunk),
+                                      first_chunk)
+            if any(not self.caches[k].can_alloc(len(first[k]))
+                   for k in range(1, len(self.kinds))):
+                break
             try:
                 _faults.maybe_fail("serving:admission", step=self._step_count)
             except _faults.InjectedFault as e:
@@ -879,8 +950,9 @@ class ServingEngine:
             # pages for the first uncached chunk
             chain = self.prefix.probe(wp, req.request_id, chain=hit) \
                 if self.prefix is not None else []
-            req.pages = chain + self.cache.alloc(need_new)
-            req.pages_version += 1
+            req.drop_pages(len(self.kinds))
+            if self.kinds[0].window is None:
+                req.pages = chain + self.cache.alloc(need_new)
             req.prefilled = len(chain) * self.geom.page_size
             req.prefix_hit_tokens = req.prefilled
             req.length = 0
@@ -908,10 +980,92 @@ class ServingEngine:
         rem = n - full
         return full + (self.chunker.bucket_for(rem) if rem else 0)
 
-    def _block_table(self, req: Request) -> np.ndarray:
-        bt = np.zeros(self.geom.pages_per_request, np.int32)
-        bt[:len(req.pages)] = req.pages
+    def _block_table(self, req: Request, k: int = 0,
+                     out: np.ndarray | None = None) -> np.ndarray:
+        """Kind ``k``'s table row: logical page ``p`` in column ``p`` (full)
+        or ``p mod width`` (a window kind's ring); the rest point at the
+        scratch page."""
+        width = self.geoms[k].pages_per_request
+        bt = np.zeros(width, np.int32) if out is None else out
+        pages, base = req.kind_pages[k], req.page_base[k]
+        bt[:] = 0
+        if self.kinds[k].window is None:
+            bt[:len(pages)] = pages
+        elif pages:
+            bt[(base + np.arange(len(pages))) % width] = pages
         return bt
+
+    def _first_kept_page(self, k: int, ln: int) -> int:
+        """The lowest logical page kind ``k`` still needs for a row whose
+        context, itself included, is ``ln`` tokens."""
+        w = self.kinds[k].window
+        return 0 if w is None else max(ln - w, 0) // self.geoms[k].page_size
+
+    def _chunk_pages(self, pos0: int, real: int, C: int) -> list:
+        """For each kind, the logical pages of chunk ``[pos0, pos0 + C)``
+        (``real`` prompt tokens, the rest ladder padding) it writes. A full
+        kind writes them all; a window kind only those that hold prompt
+        tokens AND that the window still reaches once the chunk is in —
+        the others go to the scratch page."""
+        ps = self.geom.page_size
+        a, b = pos0 // ps, (pos0 + C) // ps
+        out = []
+        for k, kind in enumerate(self.kinds):
+            if kind.window is None:
+                out.append(range(a, b))
+            else:
+                n = pos0 + real
+                out.append(range(max(a, self._first_kept_page(k, n)),
+                                 min(b, -(-n // ps))))
+        return out
+
+    def _ring_drop(self, req: Request, k: int, below: int) -> None:
+        """Return kind ``k``'s pages below logical page ``below`` to the
+        free list: they lie wholly outside the window."""
+        pages = req.kind_pages[k]
+        n = min(max(below - req.page_base[k], 0), len(pages))
+        if not n:
+            return
+        self.caches[k].free(pages[:n])
+        del pages[:n]
+        req.page_base[k] += n
+        req.pages_version += 1
+        self._recycled += n
+        self.obs.inc("kv.window_pages_recycled", n)
+
+    def _ring_extend(self, req: Request, k: int, first: int, n: int) -> bool:
+        """Append logical pages ``first .. first + n - 1`` to kind ``k``'s
+        list (contiguous with what it holds)."""
+        pages = req.kind_pages[k]
+        if not pages:
+            req.page_base[k] = first
+        assert req.page_base[k] + len(pages) == first, \
+            (req.page_base[k], len(pages), first)
+        return n == 0 or self._grow_pages(req, n, k)
+
+    def _pools(self) -> list:
+        """The pools as the step functions take them: one a layer."""
+        if len(self.caches) == 1:
+            return self.cache.pools
+        each = [iter(c.pools) for c in self.caches]
+        return [next(each[k]) for k in self.desc.layer_kinds]
+
+    def _store_pools(self, pools) -> None:
+        if len(self.caches) == 1:
+            self.cache.update_pools(pools)
+            return
+        for k, cache in enumerate(self.caches):
+            cache.update_pools([kv for kv, lk in zip(pools,
+                                                     self.desc.layer_kinds)
+                                if lk == k])
+
+    def _pools_alive(self) -> bool:
+        return all(c.pools_alive() for c in self.caches)
+
+    def _per_kind(self, arrays):
+        """Step-function argument of one array a kind: bare for a one-kind
+        model (its programs keep the signature they always had)."""
+        return arrays[0] if len(arrays) == 1 else tuple(arrays)
 
     def _dispatch_guarded(self, dispatch, domain: str):
         """Run a pool-donating dispatch under retry. A retryable failure
@@ -921,7 +1075,7 @@ class ServingEngine:
         the supervisor's restart signal."""
         def classify(exc):
             kind = _retry.classify(exc)
-            if kind == _retry.RETRYABLE and not self.cache.pools_alive():
+            if kind == _retry.RETRYABLE and not self._pools_alive():
                 return _retry.FATAL
             return kind
 
@@ -932,7 +1086,7 @@ class ServingEngine:
         except (KeyboardInterrupt, SystemExit, GeneratorExit):
             raise
         except BaseException as e:
-            if not self.cache.pools_alive():
+            if not self._pools_alive():
                 raise EngineFault(
                     f"{domain} dispatch consumed the donated page pools; "
                     f"in-place retry is impossible — supervisor restart "
@@ -959,19 +1113,35 @@ class ServingEngine:
             remaining = len(wp) - req.prefilled
             C = self._chunk_size(remaining)
             pos0 = req.prefilled                    # chunk/page aligned
-            need_total = (pos0 + C) // g.page_size
-            if len(req.pages) < need_total and \
-                    not self._grow_pages(req, need_total - len(req.pages)):
-                return False                        # preempted or must wait
             real = min(remaining, C)
+            first_page = pos0 // g.page_size
+            block_tables, page_writes = [], []
+            for k, keep in enumerate(self._chunk_pages(pos0, real, C)):
+                pages = req.kind_pages[k]
+                ring = self.kinds[k].window is not None
+                need = keep.stop - len(pages)
+                if not ring and need > 0 and \
+                        not self._grow_pages(req, need, k):
+                    return False                    # preempted or must wait
+                # a ring's table as it stands is what the chunk's gather
+                # reads; the pages the window leaves behind are recycled
+                # for the chunk's own (the program gathers before it writes)
+                block_tables.append(self._block_table(req, k)[None])
+                if ring:
+                    self._ring_drop(
+                        req, k, self._first_kept_page(k, pos0 + real))
+                    if not self._ring_extend(req, k, keep.start, len(keep)):
+                        return False
+                base = req.page_base[k]
+                page_writes.append(np.asarray(
+                    [pages[p - base] * g.page_size if p in keep else 0
+                     for p in range(first_page, first_page + C // g.page_size)],
+                    np.int32))
             chunk = np.zeros((1, C), np.int32)
             chunk[0, :real] = wp[pos0:pos0 + real]
             lengths = np.asarray([pos0 + C], np.int32)
-            first_page = pos0 // g.page_size
-            page_writes = np.asarray(
-                [req.pages[first_page + i] * g.page_size
-                 for i in range(C // g.page_size)], np.int32)
-            block_table = self._block_table(req)[None]
+            block_table = self._per_kind(block_tables)
+            page_writes = self._per_kind(page_writes)
 
         def dispatch():
             # the fault hook fires BEFORE the device dispatch, so a retried
@@ -979,7 +1149,7 @@ class ServingEngine:
             _faults.maybe_fail("serving:prefill", step=self._step_count)
             return self.runner.prefill_jit(
                 self.params, chunk, block_table, lengths, page_writes,
-                self.cache.pools)
+                self._pools())
 
         # the chunk's dispatch on the request's own lifecycle track (the
         # device runs the chunk behind it: this iteration's ``decode_wait``
@@ -990,7 +1160,7 @@ class ServingEngine:
                             "pos0": pos0, "step": self._step_count},
                            histogram="serving.prefill_ms"):
             pools = self._dispatch_guarded(dispatch, "serving:prefill")
-            self.cache.update_pools(pools)
+            self._store_pools(pools)
         with self.obs.span("prefill_deliver", "serving:sched", leaf_args,
                            ring=False):
             req.prefill_chunks += 1
@@ -1018,11 +1188,11 @@ class ServingEngine:
             self._admit()  # a completed prefill may free queue back-pressure
         return True
 
-    def _grow_pages(self, req: Request, n: int) -> bool:
-        """Allocate ``n`` more pages for ``req``, preempting the lowest-
-        priority newest resident request (possibly ``req`` itself) while
-        the pool is dry."""
-        while not self.cache.can_alloc(n):
+    def _grow_pages(self, req: Request, n: int, k: int = 0) -> bool:
+        """Allocate ``n`` more pages of kind ``k`` for ``req``, preempting
+        the lowest-priority newest resident request (possibly ``req``
+        itself) while that kind's pool is dry."""
+        while not self.caches[k].can_alloc(n):
             victim = min((r for r in self.slots
                           if r is not None and r.state in (DECODE, PREFILL)
                           and r is not req),
@@ -1035,7 +1205,7 @@ class ServingEngine:
                 self._preempt(req)
                 return False
             self._preempt(victim)
-        req.pages.extend(self.cache.alloc(n))
+        req.kind_pages[k].extend(self.caches[k].alloc(n))
         req.pages_version += 1
         return True
 
@@ -1070,10 +1240,18 @@ class ServingEngine:
             # existing context pages, not the next append page yet — so a
             # prompt that turns resident in this iteration's prefill needs
             # no pass of its own
-            need = (-(-req.length // g.page_size) if req._replay
-                    else req.length // g.page_size + 1)
-            if len(req.pages) < need:
-                self._grow_pages(req, need - len(req.pages))
+            ln = req.length if req._replay else req.length + 1
+            for k in range(len(self.kinds)):
+                if req.state != DECODE:
+                    break                   # a grow below evicted it
+                if self.kinds[k].window is not None:
+                    # the page that fell wholly out of the window goes back
+                    # to the free list before the next one is taken
+                    self._ring_drop(req, k, self._first_kept_page(k, ln))
+                have = req.page_base[k] + len(req.kind_pages[k])
+                need = -(-ln // g.page_size)
+                if have < need:
+                    self._ring_extend(req, k, have, need - have)
 
     def _decode_step(self) -> int:
         """One batched decode step over every resident DECODE request, the
@@ -1090,7 +1268,7 @@ class ServingEngine:
                 build.cancel()
                 return 0
             tokens, bt = self._np_tokens, self._np_bt
-            lengths, write_pos = self._np_len, self._np_wp
+            lengths, bts, wps = self._np_len, self._np_bts, self._np_wps
             temps, topk = self._np_temp, self._np_topk
             topp, rng = self._np_topp, self._np_rng
             for i in range(self.max_slots):
@@ -1103,20 +1281,22 @@ class ServingEngine:
                     # sampling row is greedy on the zero key
                     tokens[i, 0] = 0
                     lengths[i] = 1
-                    write_pos[i] = 0
+                    for wp in wps:
+                        wp[i] = 0
                     temps[i] = 0.0
                     topk[i] = 0
                     topp[i] = 1.0
                     rng[i] = 0
                     if self._bt_slot_version[i] is not None:
-                        bt[i] = 0
+                        for t in bts:
+                            t[i] = 0
                         self._bt_slot_version[i] = None
             for i, r in active:
                 tokens[i, 0] = r.next_token
                 key = (r.request_id, r.pages_version)
                 if self._bt_slot_version[i] != key:     # pages changed (rare)
-                    bt[i, :len(r.pages)] = r.pages
-                    bt[i, len(r.pages):] = 0
+                    for k, t in enumerate(bts):
+                        self._block_table(r, k, out=t[i])
                     self._bt_slot_version[i] = key
                 if r._replay:
                     # first-token replay: the fed token's K/V row already
@@ -1125,12 +1305,14 @@ class ServingEngine:
                     # the recomputed row is discarded on the scratch page —
                     # shared COW pages are never written
                     lengths[i] = r.length
-                    write_pos[i] = 0
+                    for wp in wps:
+                        wp[i] = 0
                 else:
                     lengths[i] = r.length + 1
-                    write_pos[i] = (
-                        r.pages[r.length // g.page_size] * g.page_size
-                        + r.length % g.page_size)
+                    page, off = divmod(r.length, g.page_size)
+                    for k, wp in enumerate(wps):
+                        wp[i] = r.kind_pages[k][page - r.page_base[k]] \
+                            * g.page_size + off
                 sp = r.sampling
                 temps[i] = sp.temperature
                 topk[i] = sp.top_k
@@ -1139,8 +1321,20 @@ class ServingEngine:
                 rng[i, 1] = len(r.generated)    # counter: tokens sampled so far
             # what the decode attention walks of what the block tables span
             # (an idle slot's one scratch page included: the kernel walks it)
-            walk = {"live_pages": int((-(-lengths // g.page_size)).sum()),
-                    "window_pages": bt.size}
+            top = -(-lengths // g.page_size)
+            walk = {"live_pages": int(top.sum()), "window_pages": bt.size}
+            if self._windowed:
+                # by kind: a window kind's walk starts at the first page
+                # its window still reaches
+                walk["live_pages_full"] = walk["live_pages_window"] = 0
+                for kind in self.kinds:
+                    if kind.window is None:
+                        walk["live_pages_full"] += int(top.sum())
+                    else:
+                        low = np.maximum(lengths - kind.window, 0) \
+                            // g.page_size
+                        walk["live_pages_window"] += int((top - low).sum())
+            bt_arg, wp_arg = self._per_kind(bts), self._per_kind(wps)
 
         def dispatch():
             # injected faults fire BEFORE the device dispatch, so a retried
@@ -1179,11 +1373,11 @@ class ServingEngine:
                                        _quarantine.get_quarantine().ids()))
                 self.obs.set_gauge("serving.quarantine_epoch", ep)
                 self._decode_bound = self.runner.bind_decode(
-                    self.params, tokens, bt, lengths, write_pos,
-                    self.cache.pools, temps, topk, topp, rng)
+                    self.params, tokens, bt_arg, lengths, wp_arg,
+                    self._pools(), temps, topk, topp, rng)
                 self._bound_epoch = ep
-            return self._decode_bound(self.params, tokens, bt, lengths,
-                                      write_pos, self.cache.pools,
+            return self._decode_bound(self.params, tokens, bt_arg, lengths,
+                                      wp_arg, self._pools(),
                                       temps, topk, topp, rng)
 
         # the dispatch half of the iteration, on the scheduler track, and its
@@ -1194,9 +1388,9 @@ class ServingEngine:
                             **walk}):
             with self.obs.span("decode_enqueue", "serving:sched",
                                self._step_args, ring=False):
-                tok_ids, self.last_decode_logits, pools = \
+                tok_ids, self.last_decode_logits, pools, *aux = \
                     self._dispatch_guarded(dispatch, "serving:decode")
-                self.cache.update_pools(pools)
+                self._store_pools(pools)
             # tokens were sampled IN-GRAPH; fetching the (S,) id vector is
             # the host sync that makes ``decode_wait`` an honest bound on
             # the device's part of the step (the (S, V) logits output stays
@@ -1207,6 +1401,12 @@ class ServingEngine:
                 toks = np.asarray(tok_ids)
         with self.obs.span("decode_deliver", "serving:sched",
                            self._step_args, ring=False):
+            if aux and _observe.is_enabled():
+                # what the step returned beside its tokens (small arrays:
+                # fetched only while someone is reading)
+                self.desc.on_decode_aux(
+                    self.obs, {k: np.asarray(v) for k, v in aux[0].items()},
+                    self._step_count)
             for i, r in active:
                 if r._replay:
                     r._replay = False   # context length unchanged; row existed
