@@ -129,3 +129,23 @@ def fsdp_overlap_step():
     jstep = fsdp(train_step, MeshSpec.make(fsdp=8), zero=2, comm_reorder=True)
     entry = jstep.compile(params, opt.init(params), tokens, targets)
     return jstep, entry
+
+
+@pytest.fixture
+def heads_a_copy(monkeypatch):
+    """The per-op kernel's walk at ``nh`` KV heads a copy: the VMEM it plans
+    against holds exactly that many heads' staging and blocks (the heads are
+    sized in bytes too: shrink the bytes, not an option)."""
+    from thunder_tpu.core import cost_model
+    from thunder_tpu.executors import pallasex as px
+
+    real = cost_model.decode_pages_per_block
+
+    def at(nh):
+        def sized(ps, hd, item, npg, kv_heads=1, head_bytes=0):
+            ppb, _ = real(ps, hd, item, npg)
+            return real(ps, hd, item, npg, kv_heads=kv_heads,
+                        head_bytes=head_bytes,
+                        vmem_left=nh * (4 * ppb * ps * hd * item + head_bytes))
+        monkeypatch.setattr(px, "decode_pages_per_block", sized)
+    return at

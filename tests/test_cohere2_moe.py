@@ -225,35 +225,58 @@ def _decomposed(op, *args, **kw):
             os.environ["THUNDER_TPU_PALLAS_INTERPRET"] = old
 
 
-def test_window_page_walk_matches_decomposition(interpret):
-    """Ring tables with the walk starting mid-ring: lengths below, at and
-    far above the window (so the ring has wrapped several times), a
-    length-1 slot, pages in scrambled pool order."""
+@pytest.mark.parametrize("nh", [1, 2, 4], ids=["1h", "2h", "4h"])
+@pytest.mark.parametrize("H,KV", [(8, 4), (4, 4)], ids=["gqa", "mha"])
+def test_window_page_walk_matches_decomposition(interpret, heads_a_copy, H,
+                                                KV, nh):
+    """Ring tables with the walk starting mid-ring, ``nh`` KV heads a copy:
+    lengths below, at and far above the window (so the ring has wrapped
+    several times), a page and a block less and more one, a length-1 slot,
+    an idle slot between live ones, pages in scrambled pool order, every
+    page no window reaches NaN."""
+    heads_a_copy(nh)
     rng = np.random.RandomState(2)
-    KV, H, hd, ps, W = 2, 8, 16, 4, 8
-    R, P = 3, 40
-    lengths = np.asarray([1, 5, 8, 9, 23, 40], np.int32)
+    hd, ps, W = 16, 4, 8
+    R, P = 3, 60
+    lengths = np.asarray([1, 3, 5, 0, 8, 9, 11, 13, 23, 40], np.int32)
     B = len(lengths)
     bt = np.zeros((B, R), np.int32)
     free = list(rng.permutation(np.arange(1, P)))
     for b, ln in enumerate(lengths):
         for page in range(max(ln - W, 0) // ps, -(-ln // ps)):
             bt[b, page % R] = free.pop()
+    dead = np.ones(P, bool)
+    dead[bt[bt > 0]] = False
     q = jnp.asarray(rng.randn(B, H, 1, hd), jnp.float32)
-    kp = jnp.asarray(rng.randn(KV, P, ps, hd), jnp.float32)
-    vp = jnp.asarray(rng.randn(KV, P, ps, hd), jnp.float32)
-    got = px.pallas_paged_decode_attention(q, kp, vp, jnp.asarray(bt),
-                                           jnp.asarray(lengths), window=W)
-    want = _decomposed(tnn.paged_decode_attention, q, kp, vp, bt, lengths,
-                       window=W)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    kp, vp = rng.randn(2, KV, P, ps, hd).astype(np.float32)
+    poisoned = [jnp.asarray(np.where(dead[None, :, None, None], np.nan, x))
+                for x in (kp, vp)]
+    kp, vp = (np.where(dead[None, :, None, None], 0.0, x) for x in (kp, vp))
+    observe.enable(clear=True)
+    try:
+        got = px.pallas_paged_decode_attention(
+            q, *poisoned, jnp.asarray(bt), jnp.asarray(lengths), window=W)
+        path, = [e for e in observe.get_registry().events
+                 if e["kind"] == "kernel_path"]
+    finally:
+        observe.disable()
+        observe.reset()
+    assert (path["rung"], path["heads_per_copy"]) == (f"ring_walk_3p_{nh}h", nh)
+    want = _decomposed(tnn.paged_decode_attention, q, jnp.asarray(kp),
+                       jnp.asarray(vp), bt, lengths, window=W)
+    live = lengths > 0
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got)[~live], 0.0)
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                atol=KERNEL_TOL, rtol=KERNEL_TOL)
     # and against the definition: the last W keys, by position
     for b, ln in enumerate(lengths):
+        if not ln:
+            continue
         pos = np.arange(max(ln - W, 0), ln)
         pages = bt[b, (pos // ps) % R]
-        k = np.asarray(kp)[:, pages, pos % ps]            # (KV, n, hd)
-        v = np.asarray(vp)[:, pages, pos % ps]
+        k = kp[:, pages, pos % ps]                        # (KV, n, hd)
+        v = vp[:, pages, pos % ps]
         qg = np.asarray(q)[b, :, 0].reshape(KV, H // KV, hd)
         s = np.einsum("kgd,knd->kgn", qg, k) / np.sqrt(hd)
         p = np.exp(s - s.max(-1, keepdims=True))
@@ -399,7 +422,7 @@ def test_records_of_the_two_kinds_and_the_routing(interpret):
         walks = [s["args"] for s in reg.spans if s["name"] == "decode_dispatch"]
         sched = [s["args"] for s in reg.spans if s["name"] == "schedule"]
         routes = [e for e in reg.events if e["kind"] == "moe_route"]
-        paths = {e["op"] for e in reg.events if e["kind"] == "kernel_path"}
+        paths = [e for e in reg.events if e["kind"] == "kernel_path"]
         snap = observe.snapshot()
         text = observe.explain(eng.runner.decode_jit)
         decisions = tt.compile_stats(eng.runner.decode_jit).last_decisions
@@ -425,8 +448,21 @@ def test_records_of_the_two_kinds_and_the_routing(interpret):
     assert snap["counters"]["moe.local_picks"] == sum(e["local_picks"]
                                                       for e in routes)
     assert snap["counters"]["moe.experts_hit"] == sum(e["hit"] for e in routes)
-    assert paths == {"nn.moe_experts", "nn.banded_attention",
-                     "nn.paged_decode_attention"}
+    assert {e["op"] for e in paths} == {"nn.moe_experts", "nn.banded_attention",
+                                        "nn.paged_decode_attention"}
+    # the walk of BOTH kinds says what a copy moves, once a call site (a
+    # layer of the one decode program): three rings to one whole table
+    walk_paths = [e for e in paths if e["op"] == "nn.paged_decode_attention"]
+    ring_pages, = [g.pages_per_request for g in eng.geoms if g.window]
+    ring = f"ring_walk_{ring_pages}p_{spec.KV}h"
+    assert [e["rung"] for e in walk_paths] == \
+        [ring, ring, ring, f"walk_{spec.KV}h"]
+    for e in walk_paths:
+        assert e["heads_per_copy"] == spec.KV and e["pages_per_block"] >= 1
+        assert e["staged_bytes"] == 4 * spec.KV * e["pages_per_block"] \
+            * eng.geoms[0].page_size * spec.hd * 4
+    assert f"kernel path: nn.paged_decode_attention -> walk_{spec.KV}h (" in text
+    assert f"-> {ring} (" in text and f"heads_per_copy={spec.KV}" in text
     assert "cache kinds:" in text and "expert routing:" in text
     blocks = [d for d in decisions if d["kind"] == "block"]
     assert [d["decision"] for d in blocks] == ["parallel-block"] * 4

@@ -331,33 +331,93 @@ def small_blocks(monkeypatch):
     return at
 
 
+# the megakernel's walk is one KV head a grid step; the per-op kernel's a
+# group of them: 1, 2 and every head
 @pytest.mark.parametrize("length", _walk_lengths())
-@pytest.mark.parametrize("H,KV", [(4, 2), (2, 2)], ids=["gqa", "mha"])
-def test_page_walk_parity_vs_decomposition(H, KV, length, small_blocks):
+@pytest.mark.parametrize("nh", [None, 1, 2, 4],
+                         ids=["megakernel", "per_op_1h", "per_op_2h",
+                              "per_op_4h"])
+@pytest.mark.parametrize("H,KV", [(8, 4), (4, 4)], ids=["gqa", "mha"])
+def test_page_walk_parity_vs_decomposition(H, KV, nh, length, small_blocks,
+                                           heads_a_copy):
     """The walk against the XLA decomposition: the case length first and
-    last of three slots (an idle slot before a live one and after one, a
-    first block started by the slot itself and by the slot before), the
-    fresh row on the first and on the last row of a page, dead pages NaN."""
+    last of four slots (an idle slot between live ones, before one and
+    after one, a first block started by the slot itself and by the slot
+    before), the fresh row on the first and on the last row of a page, dead
+    pages NaN, page ids out of order."""
     from thunder_tpu.executors import pallasex as px
 
     small_blocks(np.float32)
     head, clean, poisoned, tail, dead = _walk_case(
-        H, KV, [length, 2 * _WALK["ps"] + 3, length], seed=length)
+        H, KV, [length, 0, 2 * _WALK["ps"] + 3, length], seed=length)
     assert cost_model.decode_pages_per_block(
-        _WALK["ps"], _WALK["hd"], 4, _WALK["npg"]) == _WALK["ppb"]
-    ref = tt.jit(lambda *a: tnn.attn_subblock(*a), executors=["xla"])(
-        *head, *clean, *tail)
-    out = px.pallas_attn_subblock(*(jnp.asarray(a) for a in
-                                    (*head, *poisoned, *tail)))
+        _WALK["ps"], _WALK["hd"], 4, _WALK["npg"]) == (_WALK["ppb"], 1)
     live = np.asarray(tail[1]) > 0
-    o, o_ref = np.asarray(out[0]), np.asarray(ref[0])
+    xla = lambda f: tt.jit(f, executors=["xla"])
+    dev = lambda xs: (jnp.asarray(a) for a in xs)
+    if nh is None:
+        ref = xla(lambda *a: tnn.attn_subblock(*a))(*head, *clean, *tail)
+        out = px.pallas_attn_subblock(*dev((*head, *poisoned, *tail)))
+        for got, want in zip(out[1:], ref[1:]):     # the appended rows
+            np.testing.assert_allclose(np.asarray(got)[:, ~dead],
+                                       np.asarray(want)[:, ~dead],
+                                       atol=2e-5, rtol=2e-5)
+        o, o_ref = np.asarray(out[0]), np.asarray(ref[0])
+    else:                   # the pools already hold this token's row
+        heads_a_copy(nh)
+        q = np.random.RandomState(length).randn(
+            len(live), H, 1, _WALK["hd"]).astype(np.float32) * 0.3
+        grid, = _pallas_grids(px.pallas_paged_decode_attention,
+                              *dev((q, *poisoned, *tail[:2])))
+        assert grid == (KV // nh,)
+        o_ref = np.asarray(xla(lambda *a: tnn.paged_decode_attention(*a))(
+            q, *clean, *tail[:2]))
+        o = np.asarray(px.pallas_paged_decode_attention(
+            *dev((q, *poisoned, *tail[:2]))))
     assert np.isfinite(o).all()
     np.testing.assert_allclose(o[live], o_ref[live], atol=2e-5, rtol=2e-5)
     np.testing.assert_array_equal(o[~live], 0.0)    # an idle slot attends nothing
-    for got, want in zip(out[1:], ref[1:]):         # the appended rows
-        np.testing.assert_allclose(np.asarray(got)[:, ~dead],
-                                   np.asarray(want)[:, ~dead],
-                                   atol=2e-5, rtol=2e-5)
+
+
+_CELL_PAGE = dict(page_size=16, head_dim=128, dtype_bytes=2)
+
+
+@pytest.mark.parametrize("head_bytes", [0, 1 << 20])
+@pytest.mark.parametrize("vmem_left", [1 << 16, 1 << 20, 6 << 20, 16 << 20])
+@pytest.mark.parametrize("KV", [1, 2, 8, 32])
+def test_walk_block_is_a_divisor_of_the_heads_within_the_budget(KV, vmem_left,
+                                                                head_bytes):
+    """``decode_pages_per_block`` groups the largest divisor of the KV heads
+    whose staging (K and V, two buffers, ``heads`` x ``pages`` pages) and
+    per-head blocks fit the VMEM left; one head and one page at the least."""
+    page = 16 * 128 * 2
+    ppb, nh = cost_model.decode_pages_per_block(
+        **_CELL_PAGE, pages_per_request=1024, vmem_left=vmem_left,
+        kv_heads=KV, head_bytes=head_bytes)
+    need = lambda heads, pages: heads * (4 * pages * page + head_bytes)
+    assert KV % nh == 0 and 1 <= ppb <= 32
+    assert need(nh, ppb) <= vmem_left or (nh, ppb) == (1, 1)
+    assert all(need(d, ppb) > vmem_left
+               for d in range(nh + 1, KV + 1) if KV % d == 0)
+    # the pages of ONE head are what a caller without heads gets
+    assert ppb == cost_model.decode_pages_per_block(
+        **_CELL_PAGE, pages_per_request=1024, vmem_left=vmem_left,
+        head_bytes=head_bytes)[0]
+
+
+def test_walk_block_at_the_agent_cell_and_the_megakernel():
+    """``commandaplus_serve_agent_sat``'s per-op kernel stages 4 MB: every
+    one of the 8 KV heads, 32 pages, K and V, two buffers — a copy moves 32
+    KB; the megakernel's walk keeps one head a grid step."""
+    B, G, hd = 32, 16, 128
+    head_bytes = 4 * B * G * hd * 2 + G * (hd + 256) * 4
+    assert cost_model.decode_pages_per_block(
+        **_CELL_PAGE, pages_per_request=257, kv_heads=8,
+        head_bytes=head_bytes) == (32, 8)
+    assert 8 * (4 * 32 * 4096 + head_bytes) <= cost_model.VMEM_BUDGET_BYTES
+    # mistral7b_serve_decode_sat's attention sub-block: pages alone, as before
+    assert cost_model.decode_subblock_pages_per_block(
+        32, 4096, 32, 8, 128, 16, 0, 2, 128) == 32
 
 
 @pytest.mark.parametrize("kernel", ["attn_subblock", "decode_layer",
@@ -408,13 +468,15 @@ def _pallas_grids(fn, *args):
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub)
 
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    # a fresh callable: JAX caches a function's trace by its identity
+    walk(jax.make_jaxpr(lambda *a: fn(*a))(*args).jaxpr)
     return grids
 
 
 @pytest.mark.parametrize("kernel", ["attn_subblock", "paged_decode_attention"])
 def test_attention_grid_does_not_grow_with_the_window(kernel):
-    """A grid step a KV head, whatever ``npg``: the pages are walked by a
+    """A grid step a KV head (the megakernel) or a group of them (the per-op
+    kernel: both heads here), whatever ``npg``: the pages are walked by a
     loop whose trip count is the request's own."""
     from thunder_tpu.executors import pallasex as px
 
@@ -431,7 +493,7 @@ def test_attention_grid_does_not_grow_with_the_window(kernel):
         grids[npg] = _pallas_grids(fn, *(jnp.asarray(a) for a in args))
     assert grids[8] == grids[128]
     steps = {"attn_subblock": H + 2 * KV + KV,      # qkv heads + a walk a head
-             "paged_decode_attention": KV}[kernel]
+             "paged_decode_attention": 1}[kernel]
     assert grids[8] == [(steps,)]
 
 

@@ -156,6 +156,8 @@ _SAT = dict(S=32, D=4096, hd=128, ps=16, npg=128, F=14336)
 @pytest.mark.parametrize("H,KV,shape", [(2, 2, {}), (4, 2, {}), (32, 8, _SAT)],
                          ids=["mha", "gqa", "sat-cell"])
 def test_serving_kernels(v5e, H, KV, shape):
+    """The megakernels (their walk one KV head a grid step) and the per-op
+    kernel (a group of heads a step)."""
     attn, mlp = _decode_operands(H, KV, **shape)
     _compile(v5e, px.pallas_attn_subblock, *attn)
     _compile(v5e, px.pallas_decode_layer, *attn, *mlp)
@@ -228,12 +230,27 @@ def test_window_page_walk_at_the_agent_cell_widths(v5e, kind, pages, table,
                                                    window):
     """``commandaplus_serve_agent_sat``'s decode attention: 32 slots, 128
     query heads over 8 KV heads x 128, pages of 16; a window layer's ring of
-    257 pages and a global layer's 1,024-page table."""
+    257 pages and a global layer's 1,024-page table. A grid step walks all 8
+    KV heads: one copy moves a page of every head (a strided source over
+    the pool, Mosaic's to accept), 4 MB staged."""
+    from thunder_tpu.observe import registry as obs
+
     pool = sds((8, pages, 16, 128))
-    _compile(v5e, functools.partial(px.pallas_paged_decode_attention,
-                                    window=window),
-             sds((32, 128, 1, 128)), pool, pool, sds((32, table), i32),
-             sds((32,), i32))
+    obs.enable(clear=True)
+    try:
+        _compile(v5e, functools.partial(px.pallas_paged_decode_attention,
+                                        window=window),
+                 sds((32, 128, 1, 128)), pool, pool, sds((32, table), i32),
+                 sds((32,), i32))
+        path, = [e for e in obs.snapshot()["events"]
+                 if e["kind"] == "kernel_path"]
+    finally:
+        obs.disable()
+        obs.reset()
+    assert (path["heads_per_copy"], path["pages_per_block"],
+            path["staged_bytes"]) == (8, 32, 4 << 20)
+    assert path["rung"] == {"window": "ring_walk_257p_8h",
+                            "global": "walk_8h"}[kind]
 
 
 @pytest.mark.parametrize("chunk,keys,window", [

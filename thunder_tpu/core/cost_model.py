@@ -525,25 +525,39 @@ DECODE_GRID_STEP_US = 0.6
 
 # The decode attention walk (executors/pallasex.py::_walk_live_pages) stages
 # a request's live K/V pages a BLOCK at a time: ``pages_per_block`` pages of
-# each pool, one DMA descriptor a page, double-buffered. A block is sized in
-# bytes, not pages — tens of KB amortize the loop's fixed cost (descriptor
-# issue, the semaphore waits, two small matmuls) without staging much past a
-# short request's live rows — and shrinks when the kernel's other operands
-# leave less VMEM than two pools x two buffers of it.
+# each pool, double-buffered. A block is sized in bytes, not pages — tens of
+# KB a head amortize the loop's fixed cost (descriptor issue, the semaphore
+# waits, two small matmuls) without staging much past a short request's live
+# rows — and shrinks when the kernel's other operands leave less VMEM than
+# two pools x two buffers of it. A copy moves one page of ``heads`` KV heads
+# (one descriptor, ``heads`` tiles): the walk's time follows the COUNT of
+# copies until one carries 16-32 KB (ledger, PR 35; PERF.md PR 36).
 DECODE_KV_BLOCK_BYTES = 128 * 1024
 
 
 def decode_pages_per_block(page_size: int, head_dim: int, dtype_bytes: int,
                            pages_per_request: int,
-                           vmem_left: int | None = None) -> int:
-    """Pages of ONE pool the decode walk stages per buffer: from the page's
-    bytes (``page_size x head_dim x dtype_bytes``), the block-table window
-    and the VMEM the rest of the kernel leaves — nothing a caller tunes."""
+                           vmem_left: int = VMEM_BUDGET_BYTES,
+                           kv_heads: int = 1,
+                           head_bytes: int = 0) -> tuple[int, int]:
+    """``(pages, heads)`` of the decode walk's block: the pages of ONE pool
+    and ONE head a buffer stages — from the page's bytes (``page_size x
+    head_dim x dtype_bytes``), the block-table window and the VMEM the rest
+    of the kernel leaves — and the KV heads a grid step walks, so one copy
+    moves a page of: the largest divisor of ``kv_heads`` (the heads the
+    caller's grid may group: the LOCAL heads under a tensor-parallel plan,
+    1 where a head's step is tied to something else) whose staging, ``4 x
+    heads x pages`` pages, with ``head_bytes`` more a head (the caller's
+    query and output blocks, its softmax state), fits ``vmem_left``.
+    Nothing a caller tunes."""
     page = page_size * head_dim * dtype_bytes
     ppb = max(1, min(DECODE_KV_BLOCK_BYTES // page, int(pages_per_request)))
-    while vmem_left is not None and ppb > 1 and 4 * ppb * page > vmem_left:
+    while ppb > 1 and 4 * ppb * page + head_bytes > vmem_left:
         ppb //= 2
-    return ppb
+    heads = max((d for d in range(1, kv_heads + 1) if kv_heads % d == 0
+                 and d * (4 * ppb * page + head_bytes) <= vmem_left),
+                default=1)
+    return ppb, heads
 
 
 def _decode_fixed_vmem_bytes(n_slots: int, d_model: int, n_heads: int,
@@ -581,12 +595,13 @@ def decode_subblock_pages_per_block(n_slots: int, d_model: int, n_heads: int,
                                     dtype_bytes: int,
                                     pages_per_request: int) -> int:
     """``decode_pages_per_block`` for the decode megakernel: what its other
-    operands leave of the planning budget bounds the block."""
+    operands leave of the planning budget bounds the block. One KV head a
+    grid step: that head group's ``wo`` slice streams with it."""
     fixed = _decode_fixed_vmem_bytes(n_slots, d_model, n_heads, kv_heads,
                                      head_dim, d_ff, dtype_bytes)
     return decode_pages_per_block(page_size, head_dim, dtype_bytes,
                                   pages_per_request,
-                                  vmem_left=VMEM_BUDGET_BYTES - fixed)
+                                  vmem_left=VMEM_BUDGET_BYTES - fixed)[0]
 
 
 def decode_subblock_vmem_bytes(n_slots: int, d_model: int, n_heads: int,

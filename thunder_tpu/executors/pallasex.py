@@ -1351,19 +1351,32 @@ def _mlp_subblock_checker(residual, x, w_norm, w_gate, w_up, w_down,
 # shared by this kernel and by the attention phase of the decode megakernel
 # below — copies each request's LIVE pages into VMEM itself:
 #
-#   grid step = one KV head. Inside it a loop over the slots, and for each
-#   slot a loop whose trip count is the request's own
+#   grid step = a GROUP of ``nh`` KV heads (cost_model.decode_pages_per_block:
+#   every local head the VMEM holds for this kernel, whose grid is
+#   (KV // nh,); ONE for the megakernel, whose step streams that head
+#   group's wo slice). Inside it a loop over the slots, and for each slot a
+#   loop whose trip count is the request's own
 #   cdiv(length, page_size * pages_per_block): a page past a request's
 #   length costs nothing, an idle slot (length 0) an empty loop, and the
 #   grid does not grow with the block-table window. A block is
-#   ``pages_per_block`` pages of K and of V (cost_model.decode_pages_per_block:
-#   from the page's bytes, the window and the VMEM left), one async-copy
-#   descriptor a live page (k_pages.at[kvh, bt[b, p]]), started together into
-#   one half of a double buffer while the other half is computed — the next
-#   block of this request, or the first block of the next slot. The online
-#   softmax (f32 m / l / acc) runs over the whole (G, block) score tile.
-#   Rows past the length are masked in the scores and zeroed in V (the
-#   buffer's dead rows hold whatever an earlier block left there).
+#   ``pages_per_block`` pages of K and of V (from the page's bytes, the
+#   window and the VMEM left). ONE async copy moves a live page of all
+#   ``nh`` heads (k_pages.at[kvh0 : kvh0 + nh, bt[b, p]], a strided source
+#   over the (KV, P, ps, hd) pool: ``nh`` tiles, one descriptor); a block's
+#   copies are started together into one half of a double buffer while the
+#   other half is computed — the next block of this request, or the first
+#   block of the next slot — and a WHOLE block is waited for once (the
+#   semaphore counts bytes), a ragged one a page at a time. The walk's time
+#   followed the count of DMA operations, not the bytes: at 4 KB a copy
+#   (one head) 22.3 ms a step of commandaplus_serve_agent_sat, 18.0 of them
+#   with the arithmetic taken out; at 32 KB (eight heads) 3.8, where the
+#   copies alone take 3.7 and the bytes 3.1 (PERF.md, PR 36). The online
+#   softmax (f32 m / l / acc) runs over the whole (G, block) score tile of a
+#   head; a group's heads are a batch dimension of its two matmuls (the one
+#   head's arithmetic under jax.vmap; the megakernel's walk, one head and no
+#   head axis, lowers as it did). Rows past the length are masked in the
+#   scores and zeroed in V (the buffer's dead rows hold whatever an earlier
+#   block left there).
 #
 # Claims the T == 1 decode case only — prefill chunks (T > 1 rows over the
 # paged context) take the decomposition, whose gather XLA fuses into the
@@ -1371,24 +1384,28 @@ def _mlp_subblock_checker(residual, x, w_norm, w_gate, w_up, w_down,
 # ---------------------------------------------------------------------------
 
 
-def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
+def _walk_live_pages(kvh0, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
                      m_ref, l_ref, acc_ref, *, S: int, npg: int, ps: int,
                      ppb: int, scale: float, q_of, emit, fresh_of=None,
                      window: int | None = None):
-    """Online-softmax decode attention of KV head ``kvh`` over every slot's
-    live pages. ``q_of(b)`` gives slot b's (G, hd) grouped query rows,
+    """Online-softmax decode attention over every slot's live pages, of KV
+    head ``kvh0`` or, where the staging has a head axis, of the ``nh`` heads
+    from ``kvh0``. ``q_of(b)`` gives slot b's (G, hd) grouped query rows,
     ``emit(b, out)`` takes its normalized (G, hd) f32 result (zeros for a
     length-0 slot); ``fresh_of(b)``, when given, is THIS token's (1, hd) K
     and V rows, patched in at position length-1 because the pool still holds
-    the pre-append contents. ``bt_ref`` is the flattened (S * npg,) block
-    table; ``kbuf`` / ``vbuf`` are (2, ppb * ps, hd) VMEM buffers and
-    ``sem`` a (2, 2) DMA semaphore array (pool x buffer).
+    the pre-append contents — each with ``nh`` in front for a group.
+    ``bt_ref`` is the flattened (S * npg,) block table; ``kbuf`` / ``vbuf``
+    are (2, ppb * ps, hd) VMEM buffers, or (2, nh, ppb * ps, hd): the group
+    is theirs to say; ``sem`` is a (2, 2) DMA semaphore array (pool x
+    buffer).
 
     ``window=W``: the table is a ring (logical page ``p`` in column
     ``p % npg``) and the walk starts at the first page the window still
     reaches, ``max(length - W, 0) // ps``, so it covers at most
     ``cdiv(W, ps) + 1`` pages whatever the context; rows below
     ``length - W`` are masked like the rows past the length."""
+    nh = kbuf.shape[1] if len(kbuf.shape) == 4 else None
     bk = ppb * ps
 
     def first_page(b):
@@ -1397,11 +1414,16 @@ def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
             return 0
         return jnp.maximum(ln_ref[b] - window, 0) // ps
 
+    def live_pages(b, j):
+        """Live pages of block ``j`` of slot ``b``."""
+        return jnp.minimum(ppb, (ln_ref[b] + ps - 1) // ps - first_page(b)
+                           - j * ppb)
+
     def copies(b, j, buf, do):
         """``do`` (start or wait) on the copies of block ``j`` of slot
-        ``b``: its live pages only, into buffer ``buf``."""
+        ``b``: its live pages only, every head of a page at once, into
+        buffer ``buf``."""
         fp = first_page(b)
-        live = jnp.minimum(ppb, (ln_ref[b] + ps - 1) // ps - fp - j * ppb)
 
         def page(p, carry):
             col = fp + j * ppb + p
@@ -1409,16 +1431,35 @@ def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
                 col = col % npg
             pid = bt_ref[b * npg + col]
             rows = pl.ds(pl.multiple_of(p * ps, ps), ps)
-            do(pltpu.make_async_copy(kp_ref.at[kvh, pid],
-                                     kbuf.at[buf, rows, :], sem.at[0, buf]))
-            do(pltpu.make_async_copy(vp_ref.at[kvh, pid],
-                                     vbuf.at[buf, rows, :], sem.at[1, buf]))
+            for i, (pool, stage) in enumerate(((kp_ref, kbuf), (vp_ref, vbuf))):
+                if nh is None:
+                    src, dst = pool.at[kvh0, pid], stage.at[buf, rows, :]
+                else:       # a strided source: nh tiles, one descriptor
+                    src = pool.at[pl.ds(kvh0, nh), pid]
+                    dst = stage.at[buf, :, rows, :]
+                do(pltpu.make_async_copy(src, dst, sem.at[i, buf]))
             return carry
 
-        jax.lax.fori_loop(0, live, page, 0)
+        jax.lax.fori_loop(0, live_pages(b, j), page, 0)
 
     start = lambda c: c.start()
     wait = lambda c: c.wait()
+
+    def wait_block(b, j, buf):
+        """A whole block's copies fill the buffer: one wait a pool for its
+        bytes. A request's last block, and a window's when the ring cuts it
+        short, are waited for a page at a time."""
+        whole = live_pages(b, j) == ppb
+
+        @pl.when(whole)
+        def _whole():
+            for i, stage in enumerate((kbuf, vbuf)):
+                pltpu.make_async_copy(stage.at[buf], stage.at[buf],
+                                      sem.at[i, buf]).wait()
+
+        @pl.when(jnp.logical_not(whole))
+        def _ragged():
+            copies(b, j, buf, wait)
 
     def slot(b, buf0):
         ln = ln_ref[b]
@@ -1437,7 +1478,7 @@ def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         q = q_of(b)
-        fresh = fresh_of(b) if fresh_of is not None else None
+        fresh = fresh_of(b) if fresh_of is not None else ()
 
         def block(j, carry):
             buf = (buf0 + j) % 2
@@ -1450,35 +1491,41 @@ def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
             def _next_slot():
                 copies(nxt, 0, 1 - buf, start)
 
-            copies(b, j, buf, wait)
-            k = kbuf[buf]                              # (bk, hd)
-            v = vbuf[buf]
+            wait_block(b, j, buf)
             row = base + j * bk + jax.lax.broadcasted_iota(jnp.int32,
                                                            (bk, 1), 0)
             live_row = row < ln if window is None \
                 else (row < ln) & (row >= lo)
-            v = jnp.where(live_row, v, jnp.zeros_like(v))   # dead rows
-            if fresh is not None:
-                fk, fv = fresh
-                k = jnp.where(row == ln - 1, fk, k)
-                v = jnp.where(row == ln - 1, fv, v)
-            s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32) * scale
-            col = base + j * bk + jax.lax.broadcasted_iota(jnp.int32,
-                                                           s_.shape, 1)
+            col = base + j * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (acc_ref.shape[-2], bk), 1)
             live_col = col < ln if window is None \
                 else (col < ln) & (col >= lo)
-            s_ = jnp.where(live_col, s_, -jnp.inf)     # ragged tail mask
-            m = m_ref[...]
-            m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            pexp = jnp.exp(s_ - m_new)
-            l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=-1,
-                                                      keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
+
+            def attend(q, k, v, m, l, acc, *fresh):
+                """One head's block: (G, hd) rows against (bk, hd) K and V."""
+                v = jnp.where(live_row, v, jnp.zeros_like(v))   # dead rows
+                if fresh:
+                    fk, fv = fresh
+                    k = jnp.where(row == ln - 1, fk, k)
+                    v = jnp.where(row == ln - 1, fv, v)
+                s_ = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s_ = jnp.where(live_col, s_, -jnp.inf)  # ragged tail mask
+                m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                pexp = jnp.exp(s_ - m_new)
+                l_new = l * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+                acc_new = acc * alpha + jax.lax.dot_general(
+                    pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return m_new, l_new, acc_new
+
+            # a group's heads are a batch dimension of the two matmuls
+            m_ref[...], l_ref[...], acc_ref[...] = \
+                (attend if nh is None else jax.vmap(attend))(
+                    q, kbuf[buf], vbuf[buf], m_ref[...], l_ref[...],
+                    acc_ref[...], *fresh)
             return carry
 
         jax.lax.fori_loop(0, nblk, block, 0)
@@ -1490,35 +1537,44 @@ def _walk_live_pages(kvh, bt_ref, ln_ref, kp_ref, vp_ref, kbuf, vbuf, sem,
     jax.lax.fori_loop(0, S, slot, jnp.int32(0))
 
 
+def _tile_rows(dtype_bytes: int) -> int:
+    """Rows of one (8 x 32-bit) sublane tile of this dtype."""
+    return 8 * max(4 // dtype_bytes, 1)
+
+
 def _whole_tiles(rows: int, dtype_bytes: int) -> bool:
-    """``rows`` rows of this dtype are whole (8 x 32-bit) sublane tiles."""
-    return rows % (8 * max(4 // dtype_bytes, 1)) == 0
+    """``rows`` rows of this dtype are whole sublane tiles."""
+    return rows % _tile_rows(dtype_bytes) == 0
 
 
-def _walk_scratch(ppb: int, ps: int, hd: int, G: int, dtype):
+def _walk_scratch(ppb: int, ps: int, hd: int, G: int, dtype,
+                  nh: int | None = None):
     """The walk's VMEM: K and V double buffers, their DMA semaphores, and
-    the online-softmax m / l / acc of one (slot, head)."""
-    return [pltpu.VMEM((2, ppb * ps, hd), dtype),
-            pltpu.VMEM((2, ppb * ps, hd), dtype),
+    the online-softmax m / l / acc of one (slot, head) — each with ``nh``
+    heads in front for a walk of a group."""
+    heads = () if nh is None else (nh,)
+    return [pltpu.VMEM((2, *heads, ppb * ps, hd), dtype),
+            pltpu.VMEM((2, *heads, ppb * ps, hd), dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32)]
+            pltpu.VMEM((*heads, G, 1), jnp.float32),
+            pltpu.VMEM((*heads, G, 1), jnp.float32),
+            pltpu.VMEM((*heads, G, hd), jnp.float32)]
 
 
 def _paged_decode_kernel(bt_ref, ln_ref, q_ref, kp_ref, vp_ref, o_ref,
                          kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
                          scale: float, ps: int, npg: int, ppb: int,
                          window: int | None = None):
-    """One KV head of every request: q block (B, 1, G, hd) where G =
-    n_heads // kv_heads grouped rows of the single decode position."""
+    """A group of ``nh`` KV heads of every request: q block (B, nh, G, hd)
+    where G = n_heads // kv_heads grouped rows of the single decode
+    position."""
     def emit(b, out):
-        o_ref[b, 0] = out.astype(o_ref.dtype)
+        o_ref[b] = out.astype(o_ref.dtype)
 
-    _walk_live_pages(pl.program_id(0), bt_ref, ln_ref, kp_ref, vp_ref, kbuf,
-                     vbuf, sem, m_ref, l_ref, acc_ref, S=q_ref.shape[0],
-                     npg=npg, ps=ps, ppb=ppb, scale=scale,
-                     q_of=lambda b: q_ref[b, 0], emit=emit, window=window)
+    _walk_live_pages(pl.program_id(0) * q_ref.shape[1], bt_ref, ln_ref,
+                     kp_ref, vp_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref,
+                     S=q_ref.shape[0], npg=npg, ps=ps, ppb=ppb, scale=scale,
+                     q_of=lambda b: q_ref[b], emit=emit, window=window)
 
 
 def pallas_paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
@@ -1550,29 +1606,39 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, lengths, scale=None,
     npg = block_tables.shape[1]
     G = (H // KV) * T                                  # grouped decode rows
     scale_v = scale if scale is not None else 1.0 / math.sqrt(hd)
-    ppb = decode_pages_per_block(ps, hd, q.dtype.itemsize, npg)
-    if window is not None:
-        _observe.event("kernel_path", op="nn.paged_decode_attention",
-                       rung=f"ring_walk_{npg}p", T=window, hd=hd,
-                       staged_bytes=4 * ppb * ps * hd * q.dtype.itemsize)
+    item = q.dtype.itemsize
+    # a head's share of the kernel's VMEM besides the staging: its query
+    # and output blocks (double-buffered, G padded to whole sublane tiles)
+    # and its f32 m / l / acc
+    Gp = -(-G // _tile_rows(item)) * _tile_rows(item)
+    ppb, nh = decode_pages_per_block(
+        ps, hd, item, npg, kv_heads=KV,
+        head_bytes=4 * B * Gp * hd * item + Gp * (hd + 256) * 4)
+    # recorded at dispatch, which is trace time (see pallas_sdpa_bwd)
+    _observe.event("kernel_path", op="nn.paged_decode_attention",
+                   rung=(f"walk_{nh}h" if window is None
+                         else f"ring_walk_{npg}p_{nh}h"),
+                   T=npg * ps if window is None else window, hd=hd,
+                   staged_bytes=4 * nh * ppb * ps * hd * item,
+                   heads_per_copy=nh, pages_per_block=ppb)
     q4 = q.reshape(B, KV, G, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                         # block_tables, lengths
-        grid=(KV,),
+        grid=(KV // nh,),
         in_specs=[
-            pl.BlockSpec((B, 1, G, hd), lambda h, bt, ln: (0, h, 0, 0)),
+            pl.BlockSpec((B, nh, G, hd), lambda h, bt, ln: (0, h, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),         # k pages, in HBM
             pl.BlockSpec(memory_space=pl.ANY),         # v pages
         ],
-        out_specs=pl.BlockSpec((B, 1, G, hd), lambda h, bt, ln: (0, h, 0, 0)),
-        scratch_shapes=_walk_scratch(ppb, ps, hd, G, k_pages.dtype),
+        out_specs=pl.BlockSpec((B, nh, G, hd), lambda h, bt, ln: (0, h, 0, 0)),
+        scratch_shapes=_walk_scratch(ppb, ps, hd, G, k_pages.dtype, nh),
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale_v, ps=ps,
                           npg=npg, ppb=ppb, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        interpret=_interpret(),
+        interpret=_interpret(), **_grid_params(planned_vmem=True),
     )(block_tables.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
       q4, k_pages, v_pages)
     return out.reshape(B, H, T, hd)

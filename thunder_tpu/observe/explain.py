@@ -50,12 +50,17 @@ def _kernel_path_lines() -> list[str]:
 
     from thunder_tpu.observe import flight as _flight
 
-    seen = Counter((r["op"], r["rung"], r["T"], r["hd"], r["staged_bytes"])
+    # a kernel's own fields (T, hd, staged_bytes, the walk's heads_per_copy
+    # and pages_per_block, ...) print as they were recorded
+    meta = ("type", "kind", "ts_us", "labels", "op", "rung")
+    seen = Counter((r["op"], r["rung"],
+                    tuple((k, v) for k, v in r.items() if k not in meta))
                    for r in _flight.snapshot()
                    if r["type"] == "event" and r.get("kind") == "kernel_path")
-    return [f"  kernel path: {op} -> {rung} (T={T}, hd={hd}, "
-            f"staged_bytes={staged})" + (f"  x{n}" if n > 1 else "")
-            for (op, rung, T, hd, staged), n in seen.items()]
+    return [f"  kernel path: {op} -> {rung} ("
+            + ", ".join(f"{k}={v}" for k, v in fields) + ")"
+            + (f"  x{n}" if n > 1 else "")
+            for (op, rung, fields), n in seen.items()]
 
 
 _TIMELINE_MAX_REQUESTS = 16
