@@ -183,19 +183,21 @@ def test_step_metrics_recorded_per_call():
     for _ in range(3):
         jf(x)
     snap = observe.snapshot()
-    assert snap["counters"]["step.count"] == 3
-    # the first call pays lazy XLA compile and is kept OUT of the steady-state
-    # walltime histogram (recorded as step.first_call_ms instead)
-    assert snap["histograms"]["step.walltime_ms"]["count"] == 2
-    assert snap["histograms"]["step.first_call_ms"]["count"] == 1
+    # one span a call; the first pays lazy XLA compile and says so
+    step_spans = [s for s in snap["spans"] if s["name"].startswith("step:")]
+    assert [s["args"] for s in step_spans] == \
+        [{"first_call": True}, {"first_call": False}, {"first_call": False}]
+    assert all(s["cat"] == "step" and s["dur_us"] >= 0 for s in step_spans)
+    # registry-only, like the other hot-loop spans: the ring keeps none
+    assert not [r for r in flight.snapshot()
+                if str(r.get("name", "")).startswith("step:")]
+    # nothing else is recorded a call
+    assert not [k for k in snap["counters"] if k.startswith("step.")]
+    assert not [k for k in snap["histograms"] if k.startswith("step.")]
     # static per entry: published once, at compile, not on every step
     assert snap["gauges"]["step.est_live_bytes"] > 0
     assert len([r for r in flight.snapshot()
                 if r.get("name") == "step.est_live_bytes"]) == 1
-    step_spans = [s for s in snap["spans"] if s["name"].startswith("step:")]
-    assert len(step_spans) == 3
-    assert step_spans[0]["args"] == {"first_call": True}
-    assert step_spans[1]["args"] == {"first_call": False}
 
 
 def test_step_metrics_off_when_disabled():
@@ -205,7 +207,8 @@ def test_step_metrics_off_when_disabled():
     observe.enable()  # enable AFTER compile: the wrapper reads the live flag
     jf(x)
     snap = observe.snapshot()
-    assert snap["counters"].get("step.count", 0) == 1
+    assert [s["args"] for s in snap["spans"] if s["name"].startswith("step:")] \
+        == [{"first_call": False}]
 
 
 # ---------------------------------------------------------------------------
@@ -333,6 +336,7 @@ def test_chrome_trace_export_loads_structurally(tmp_path):
 def test_jsonl_export_roundtrips(tmp_path):
     observe.enable(clear=True)
     _compile_and_step_3x()
+    observe.observe_value("test.call_ms", 1.5)
     path = str(tmp_path / "events.jsonl")
     n = observe.export_jsonl(path)
     with open(path) as f:
@@ -341,20 +345,24 @@ def test_jsonl_export_roundtrips(tmp_path):
     types = {r["type"] for r in recs}
     assert {"counter", "gauge", "histogram", "span"} <= types
     counters = {r["name"]: r["value"] for r in recs if r["type"] == "counter"}
-    assert counters["cache.misses"] == 1 and counters["step.count"] == 3
+    assert counters["cache.misses"] == 1 and counters["cache.hits"] == 2
+    assert sum(1 for r in recs if r["type"] == "span"
+               and r["name"].startswith("step:")) == 3
 
 
 def test_prometheus_export_format(tmp_path):
     observe.enable(clear=True)
     _compile_and_step_3x()
+    for ms in (0.4, 2.0):
+        observe.observe_value("test.call_ms", ms)
     path = str(tmp_path / "metrics.prom")
     text = observe.export_prometheus(path)
     assert os.path.exists(path)
     assert "# TYPE thunder_tpu_cache_misses counter" in text
     assert "thunder_tpu_cache_misses 1" in text
-    assert "# TYPE thunder_tpu_step_walltime_ms histogram" in text
-    assert 'thunder_tpu_step_walltime_ms_bucket{le="+Inf"} 2' in text
-    assert "thunder_tpu_step_walltime_ms_count 2" in text
+    assert "# TYPE thunder_tpu_test_call_ms histogram" in text
+    assert 'thunder_tpu_test_call_ms_bucket{le="+Inf"} 2' in text
+    assert "thunder_tpu_test_call_ms_count 2" in text
     # every non-comment line is "<metric possibly with labels> <value>"
     for line in text.strip().splitlines():
         if line.startswith("#"):

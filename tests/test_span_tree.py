@@ -129,10 +129,9 @@ def test_cancelled_span_leaves_no_record():
     assert sp.dur_us >= 0
 
 
-def test_span_enters_a_trace_annotation_while_enabled(monkeypatch):
-    """An operator's profiler trace shows the program's spans: the
-    context-manager form enters a ``TraceAnnotation`` of the same name while
-    the registry is on, and none while it is off."""
+@pytest.fixture
+def annotations(monkeypatch):
+    """The ``TraceAnnotation``s entered and left, in order."""
     seen = []
 
     class Annotation:
@@ -146,6 +145,14 @@ def test_span_enters_a_trace_annotation_while_enabled(monkeypatch):
             seen.append(("exit", self.name))
 
     monkeypatch.setattr(reg, "_TraceAnnotation", Annotation)
+    return seen
+
+
+def test_span_enters_a_trace_annotation_while_enabled(annotations):
+    """An operator's profiler trace shows the program's spans: the
+    context-manager form enters a ``TraceAnnotation`` of the same name while
+    the registry is on, and none while it is off."""
+    seen = annotations
     with observe.span("off", "test"):
         pass
     assert seen == []
@@ -154,6 +161,26 @@ def test_span_enters_a_trace_annotation_while_enabled(monkeypatch):
         with observe.labeled(engine="e").span("b", "test", ring=False):
             pass
     assert seen == [("enter", "a"), ("enter", "b"), ("exit", "b"), ("exit", "a")]
+
+
+def test_step_span_enters_a_trace_annotation(annotations):
+    """``step:<fn>`` is a live span: the profiler's trace holds the
+    dispatch of a compiled entry on its own clock, inside ``jit_call``'s
+    annotation, while the registry is on."""
+    seen = annotations
+    jf = tt.jit(lambda a: ops.mul(a, 2.0).sum())
+    x = np.ones((8, 8), np.float32)
+    jf(x)
+    assert not [n for _, n in seen if n.startswith("step:")]
+    observe.enable(clear=True)
+    jf(x)
+    steps = [(e, n) for e, n in seen if n.startswith("step:")]
+    assert [e for e, _ in steps] == ["enter", "exit"]
+    names = [n for _, n in seen]
+    assert names.index("jit_call") < names.index(steps[0][1]) \
+        < len(names) - 1 - names[::-1].index("jit_call")
+    (span,) = [s for s in _spans() if s["name"].startswith("step:")]
+    assert span["name"] == steps[0][1] and span["args"] == {"first_call": False}
 
 
 def test_real_trace_annotation_is_entered():
@@ -255,6 +282,115 @@ def test_engine_step_leaves_cover_it(model):
             assert "step" in s["args"], s["name"]
         if s["cat"] == "serving:request" or s["name"].startswith("prefill_"):
             assert "request" in s["args"], s["name"]
+
+
+def _kids(span):
+    return [s for s in _spans() if s["parent"] == span["id"]]
+
+
+def _serve_decode_heavy(eng):
+    _warm(eng)
+    observe.enable(clear=True)
+    for i, n in enumerate((9, 20, 40, 5, 33, 17, 60, 12)):
+        eng.submit(_prompt(n, seed=i), 12)
+    eng.drain()
+    observe.disable()
+
+
+def test_decode_enqueue_and_wait_split_into_their_causes(model):
+    """The call into the bound program is the launch; the wait is the
+    device's run and the fetch. The children lie inside their parent in that
+    order, and the parents keep their extents: the children cover the wait,
+    and all but the bound call's own flatten of the enqueue (a step's share;
+    the median, so a step the OS preempted between two spans does not decide
+    it)."""
+    eng = _engine(model, max_slots=16, max_context=512, n_layers=None)
+    _serve_decode_heavy(eng)
+    enqueues, waits = _spans("decode_enqueue"), _spans("decode_wait")
+    assert len(enqueues) == len(waits) >= 12
+    for parent, names in ((enqueues, ["step:serving_decode"]),
+                          (waits, ["decode_ready", "decode_fetch"])):
+        shares = []
+        for p in parent:
+            kids = sorted(_kids(p), key=lambda s: s["ts_us"])
+            assert [k["name"] for k in kids] == names
+            end = p["ts_us"]
+            for k in kids:
+                assert k["ts_us"] >= end - 0.5
+                end = k["ts_us"] + k["dur_us"]
+                assert k["args"].get("step", p["args"].get("step")) \
+                    == p["args"]["step"]
+            assert end <= p["ts_us"] + p["dur_us"] + 0.5
+            shares.append(sum(k["dur_us"] for k in kids) / p["dur_us"])
+        # the enqueue's rest (the bound call's flatten, the fault and epoch
+        # checks) is a quarter of a tiny CPU launch
+        least = 0.5 if names[0] == "step:serving_decode" else 0.95
+        assert np.median(shares) >= least, (names, sorted(shares))
+    # the launch keeps its name and first_call, under decode_enqueue
+    launches = _spans("step:serving_decode")
+    assert len(launches) == len(enqueues)
+    assert not any(s["args"]["first_call"] for s in launches)
+
+
+@pytest.fixture(scope="module")
+def routed():
+    """The routed family at its rehearsal size: its decode program returns
+    the ``moe_route`` aux beside the tokens."""
+    import json
+
+    path = os.path.join(ROOT, "benchmark", "families", "cohere2_moe.py")
+    spec_ = importlib.util.spec_from_file_location("bench_cohere2_span", path)
+    fam = importlib.util.module_from_spec(spec_)
+    spec_.loader.exec_module(fam)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "command-a-plus-05-2026-l4e16.json")) as f:
+        spec = fam.spec_from_config(json.load(f), rehearse=True)
+    return fam.program_config(spec, max_seq_len=64), fam.init_params(spec, 2)
+
+
+@pytest.mark.parametrize("registry", ["on", "off"])
+def test_route_record_rides_the_token_fetch(routed, monkeypatch, registry):
+    """While the registry is on, the step's aux comes over with the token ids
+    in ONE fetch inside ``decode_fetch``, and ``decode_deliver`` hands the
+    record host arrays; while it is off, the fetch carries the ids alone and
+    nothing is recorded."""
+    import jax
+
+    monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+    cfg, params = routed
+    eng = ServingEngine(params, cfg, max_slots=3, page_size=4, max_context=64,
+                        prefill_chunk=16)
+    fetches, given = [], []
+    real_get = jax.device_get
+
+    def device_get(x):
+        fetches.append((reg._open_stack()[-1][0] if reg._open_stack()
+                        else None, x))
+        return real_get(x)
+
+    record = eng.desc.on_decode_aux
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(eng.desc, "on_decode_aux",
+                        lambda obs, aux, step: (given.append(aux),
+                                                record(obs, aux, step)))
+    if registry == "on":
+        observe.enable(clear=True)
+    eng.submit(np.arange(1, 12, dtype=np.int32), 6)
+    eng.drain()
+    observe.disable()
+    steps = len(eng.completed[0].generated)
+    assert len(fetches) == steps
+    routes = [e for e in observe.get_registry().events
+              if e["kind"] == "moe_route"]
+    if registry == "off":
+        assert all(aux is None for _, (_, aux) in fetches)
+        assert not given and not routes and not _spans()
+        return
+    by_id = {s["id"]: s["name"] for s in _spans()}
+    assert {by_id[sid] for sid, _ in fetches} == {"decode_fetch"}
+    assert all(set(aux) == {"moe_route"} for _, (_, aux) in fetches)
+    assert len(given) == steps and len(routes) == 4 * steps
+    assert all(isinstance(v, np.ndarray) for aux in given for v in aux.values())
 
 
 def test_busy_iteration_runs_schedule_prefill_decode(model):

@@ -10,8 +10,8 @@ makes the compiler's *decisions* inspectable too:
   claim/rejection, every fusion accept/reject with its cost-model inputs)
   threaded through ``_compile_inner``, ``executors/passes.py``,
   ``core/fusion_passes.py``, and ``core/rematerialization.py``,
-- runtime step metrics via a wrapper on ``CacheEntry.run_fn``
-  (``runtime.py``),
+- a ``step:<fn>`` span per dispatch via a wrapper on
+  ``CacheEntry.run_fn`` (``runtime.py``),
 - an ALWAYS-ON bounded flight recorder — events, gauge moves, and span
   edges land in a fixed-size ring even when the registry is disabled, so
   a serving fault leaves a black box to read back (``flight.py``),
@@ -82,16 +82,9 @@ from thunder_tpu.observe.registry import (  # noqa: F401
 )
 from thunder_tpu.observe.profile import profile_window  # noqa: F401
 from thunder_tpu.observe.registry import enable as _enable_registry
-from thunder_tpu.observe.runtime import instrument_entry, set_sync_steps  # noqa: F401
+from thunder_tpu.observe.runtime import instrument_entry  # noqa: F401
 
 
-def enable(*, clear: bool = False, sync_steps: bool | None = None) -> None:
-    """Enable instrumentation. ``clear=True`` resets prior metrics;
-    ``sync_steps=True`` blocks on step outputs so ``step.walltime_ms`` is
-    device walltime rather than dispatch time (measurement runs only).
-    ``sync_steps=None`` (default) leaves the current setting unchanged, so
-    re-enabling to clear counters never silently reverts a measurement-mode
-    choice; pass ``False`` explicitly to turn it off."""
-    if sync_steps is not None:
-        set_sync_steps(sync_steps)
+def enable(*, clear: bool = False) -> None:
+    """Enable instrumentation. ``clear=True`` resets prior metrics."""
     _enable_registry(clear=clear)

@@ -32,7 +32,7 @@ The instrumentation contract for the whole compiler/runtime stack:
   an operator shows the program's spans over the device ops.
 
 Metric names are dotted (``cache.hits``, ``fusion.horizontal_merges``,
-``step.walltime_ms``); exporters map them to their own conventions
+``serving.ttft_ms``); exporters map them to their own conventions
 (Prometheus flattens dots to underscores).
 
 **Labels.** ``labeled(engine="e0")`` returns a scoped handle whose

@@ -1,22 +1,16 @@
-"""Runtime step metrics: a lightweight wrapper over ``CacheEntry.run_fn``.
+"""Runtime step records: a lightweight wrapper over ``CacheEntry.run_fn``.
 
 Every compiled entry's ``run_fn`` is wrapped once at compile time; per call
 the wrapper costs one boolean check when the registry is disabled. When
-enabled it records:
-
-- ``step.count`` / ``step.walltime_ms`` — dispatch walltime per step. JAX
-  dispatch is asynchronous: by default this measures time-to-dispatch (plus
-  any synchronous work — prologue guards, host syncs). Pass
-  ``observe.enable(sync_steps=True)`` to block on the step's outputs and
-  record true device walltime (changes pipelining — use for measurement
-  runs, not production serving). The FIRST call of an entry triggers lazy
-  XLA compilation inside ``run_fn``; it is recorded separately as
-  ``step.first_call_ms`` (and its span carries ``first_call: True``) so the
-  walltime histogram reflects steady-state steps, not compiles.
-- a ``step:<fn>`` span per call (Perfetto/chrome exporter material). Its
-  parent is the span open around the call: ``jit_call`` on the guarded
-  path, the caller's own span (the serving engine's ``decode_enqueue``) on
-  the ``bind()`` path.
+enabled it opens a ``step:<fn>`` span around the dispatch (registry-only,
+like the other hot-loop spans), so it also enters a
+``jax.profiler.TraceAnnotation`` and a profiler trace shows it on its own
+clock. JAX dispatch is asynchronous: the span is time-to-dispatch (plus any
+synchronous work — prologue guards, host syncs). The FIRST call of an entry
+triggers lazy XLA compilation inside ``run_fn``; its span carries
+``first_call: True``. Its parent is the span open around the call:
+``jit_call`` on the guarded path, the caller's own span (the serving
+engine's ``decode_enqueue``) on the ``bind()`` path.
 
 What is static per entry is recorded ONCE, when an entry is compiled with
 the registry enabled, never per step: ``step.est_live_bytes`` (the
@@ -28,13 +22,6 @@ trace-liveness peak-memory estimate, ``examine.estimate_memory``) and
 from __future__ import annotations
 
 from thunder_tpu.observe import registry as _registry
-
-_sync_steps = False
-
-
-def set_sync_steps(value: bool) -> None:
-    global _sync_steps
-    _sync_steps = bool(value)
 
 
 def _publish_static_estimates(exec_trc) -> None:
@@ -70,24 +57,10 @@ def instrument_entry(entry, fn_name: str):
         n_call = next(call_counter)
         if not _registry.is_enabled():
             return inner(*inps)
-        first_call = n_call == 1  # lazy XLA compile happens inside this call
-        ts = _registry._now_us()
-        out = inner(*inps)
-        if _sync_steps:
-            try:
-                import jax
-
-                jax.block_until_ready(out)
-            except Exception:
-                pass
-        us = _registry._now_us() - ts
-        _registry.record_span(span_name, "step", ts, us,
-                              {"first_call": first_call})
-        _registry.inc("step.count")
-        _registry.observe_value(
-            "step.first_call_ms" if first_call else "step.walltime_ms",
-            us / 1e3)
-        return out
+        # lazy XLA compile happens inside the first call
+        with _registry.span(span_name, "step", {"first_call": n_call == 1},
+                            record_pass_time=False, ring=False):
+            return inner(*inps)
 
     run.__wrapped__ = inner
     return run

@@ -76,6 +76,9 @@ different sequence lengths served by ONE compiled decode program.
           decode_enqueue          the call into the bound program until it
                                   returns (``step:serving_decode`` inside)
           decode_wait             the device wait and the token fetch
+            decode_ready          until the step's token ids are ready
+            decode_fetch          until the ids (and, while the registry is
+                                  on, the step's aux) are on the host
         decode_deliver            tokens to requests, finishes, page release
 
   ``schedule``, ``decode_dispatch`` and ``prefill_chunk`` feed the flight
@@ -143,6 +146,7 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
 
 from thunder_tpu.observe import registry as _observe
@@ -1391,22 +1395,31 @@ class ServingEngine:
                 tok_ids, self.last_decode_logits, pools, *aux = \
                     self._dispatch_guarded(dispatch, "serving:decode")
                 self._store_pools(pools)
-            # tokens were sampled IN-GRAPH; fetching the (S,) id vector is
-            # the host sync that makes ``decode_wait`` an honest bound on
-            # the device's part of the step (the (S, V) logits output stays
-            # on device, unread — the handle is kept for parity checks:
-            # chip_smoke.py reads one slot's row)
+            # tokens were sampled IN-GRAPH; the wait is the device's part of
+            # the step (``decode_ready``) and then the (S,) ids' landing on
+            # the host (``decode_fetch``): device idle under the first is the
+            # program's, under the second the tail after it ended. The copy
+            # is asked for first, so it follows the program as a plain fetch
+            # would. What the step returned beside its tokens (small arrays,
+            # read only while the registry is on) rides the same fetch. The
+            # (S, V) logits output stays on device, unread (the handle is
+            # kept for parity checks: chip_smoke.py reads one slot's row)
+            fetched = (tok_ids, aux[0] if aux and _observe.is_enabled()
+                       else None)
             with self.obs.span("decode_wait", "serving:sched",
                                self._step_args, ring=False):
-                toks = np.asarray(tok_ids)
+                for x in jax.tree_util.tree_leaves(fetched):
+                    x.copy_to_host_async()
+                with self.obs.span("decode_ready", "serving:sched",
+                                   self._step_args, ring=False):
+                    jax.block_until_ready(tok_ids)
+                with self.obs.span("decode_fetch", "serving:sched",
+                                   self._step_args, ring=False):
+                    toks, aux = jax.device_get(fetched)
         with self.obs.span("decode_deliver", "serving:sched",
                            self._step_args, ring=False):
-            if aux and _observe.is_enabled():
-                # what the step returned beside its tokens (small arrays:
-                # fetched only while someone is reading)
-                self.desc.on_decode_aux(
-                    self.obs, {k: np.asarray(v) for k, v in aux[0].items()},
-                    self._step_count)
+            if aux is not None:
+                self.desc.on_decode_aux(self.obs, aux, self._step_count)
             for i, r in active:
                 if r._replay:
                     r._replay = False   # context length unchanged; row existed
