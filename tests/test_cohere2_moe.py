@@ -134,6 +134,20 @@ def test_window_pools_hold_the_ring_not_the_context(interpret):
     assert shapes == [(2, 13, 4, 16)] * 3 + [(2, 65, 4, 16)]
 
 
+def test_the_engine_holds_the_published_weights(interpret):
+    """The decode step splits the rotary pairs on its projections, so no
+    weight is re-laid out at load: the engine's leaves are the arrays it was
+    given, and it holds no second copy of any (a re-laid ``wq`` of the agent
+    cell is 134 MB a window layer beside the caller's, which stays alive
+    until the constructor returns)."""
+    spec = _spec()
+    params = fam.init_params(spec, 1)
+    eng = _engine(spec, params)
+    held, given = (jax.tree_util.tree_leaves(t) for t in (eng.params, params))
+    assert len(held) == len(given)
+    assert all(a is b for a, b in zip(held, given))
+
+
 # ---------------------------------------------------------------------------
 # the chip's share
 # ---------------------------------------------------------------------------

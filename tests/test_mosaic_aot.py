@@ -13,6 +13,7 @@ full Llama-2-7B widths.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -275,3 +276,91 @@ def test_grouped_expert_matmul_at_the_agent_cell_widths(v5e, rows):
     w = sds((20, 4096, 4096))
     _compile(v5e, px.pallas_moe_experts, sds((rows, 4096)), w, w, w,
              sds((rows, 13), i32), sds((rows, 13), f32))
+
+
+def _relayouts(hlo: str, shape: str) -> list[str]:
+    """Instructions of ``hlo``'s entry computation that re-lay out a
+    parameter of ``shape`` (``"bf16[16384,4096]"``), or what the program
+    staged of it: a bitcast, an async slice or copy into VMEM and the
+    concatenation of slices pass a weight on as it is."""
+    passing = {"bitcast", "slice-start", "slice-done", "copy-start",
+               "copy-done", "get-tuple-element"}
+    insts = []
+    for line in hlo[hlo.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)$", line)
+        if m:
+            op = re.search(r" ([a-z][a-z0-9\-]*)\(([^)]*)\)", m.group(2))
+            insts.append((m.group(1), op.group(1),
+                          set(re.findall(r"%([\w.\-]+)", op.group(2))),
+                          m.group(2)))
+    staged = {n for n, op, _, rest in insts
+              if op == "parameter" and rest.startswith(shape + "{")}
+    assert staged, f"no parameter {shape}"
+    found, grew = [], True
+    while grew:
+        grew = False
+        for n, op, args, rest in insts:
+            if n in staged or n in found or not staged & args:
+                continue
+            if op in passing or 'custom_call_target="ConcatBitcast"' in rest:
+                staged.add(n)
+                grew = True
+            elif op in ("reshape", "copy", "transpose"):
+                found.append(n)
+    return found
+
+
+def test_routed_decode_reads_the_rotary_weights_as_held(v5e):
+    """The decode program of ``commandaplus_serve_agent_sat``'s attention
+    widths (4096 wide, 128 query heads over 8 KV heads x 128, 32 slots; a
+    window layer and a global one, experts, vocabulary and context cut)
+    reads a layer's ``wq`` and ``wk`` as they are held. Without the barrier
+    in ``Cohere2MoeDescription._decode_attn`` the interleaved rotary's
+    stride-2 split moves into the projection's weight, and every step copies
+    the window layer's ``wq`` (134 MB) and ``wk`` to a tiling of row pairs
+    (at the agent cell, three ``reshape``s of 0.52 ms a step on a v5e)."""
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.models.cohere2_moe import Cohere2MoeConfig
+    from thunder_tpu.serving.description import describe
+    from thunder_tpu.serving.kv_cache import PageGeometry
+    from thunder_tpu.serving.runner import PagedRunner
+
+    S, D, H, KV, hd, ps, ctx = 32, 4096, 128, 8, 128, 16, 1024
+    cfg = Cohere2MoeConfig(vocab_size=1024, dim=D, n_heads=H, n_kv_heads=KV,
+                           head_dim=hd, window=256,
+                           layer_types=("window", "full"), n_layers=2,
+                           expert_dim=256, n_experts=8, top_k=2, n_held=2,
+                           n_shared=1, max_seq_len=ctx, dtype=dtypes.bfloat16)
+    desc = describe(cfg)
+    geoms = tuple(
+        PageGeometry(n_layers=desc.layer_kinds.count(k), kv_heads=KV,
+                     head_dim=hd, page_size=ps,
+                     num_pages=S * kind.pages_per_request(ctx, ps) + 1,
+                     pages_per_request=kind.pages_per_request(ctx, ps),
+                     window=kind.window)
+        for k, kind in enumerate(desc.cache_kinds))
+    n = cfg.n_held + cfg.n_shared
+    layer = {"norm": sds((D,)), "wq": sds((H * hd, D)),
+             "wk": sds((KV * hd, D)), "wv": sds((KV * hd, D)),
+             "wo": sds((D, H * hd)), "router": sds((cfg.n_experts, D)),
+             "w_gate": sds((n, cfg.expert_dim, D)),
+             "w_up": sds((n, cfg.expert_dim, D)),
+             "w_down": sds((n, D, cfg.expert_dim))}
+    params = {"tok_embedding": sds((cfg.vocab_size, D)), "norm_f": sds((D,)),
+              "layers": [layer, dict(layer)]}
+    pools = [{name: sds((KV, geoms[k].num_pages, ps, hd)) for name in "kv"}
+             for k in desc.layer_kinds]
+    zeros = lambda *shape, dt=np.int32: np.zeros(shape, dt)
+    args = (params, zeros(S, 1),
+            tuple(zeros(S, g.pages_per_request) for g in geoms),
+            np.ones((S,), np.int32), tuple(zeros(S) for _ in geoms), pools,
+            zeros(S, dt=np.float32), zeros(S), np.ones((S,), np.float32),
+            zeros(S, 2, dt=np.uint32))
+    one = SingleDeviceSharding(v5e[0])
+    with jax.default_matmul_precision("default"):
+        entry = PagedRunner(desc, geoms).decode_jit.compile(*args)
+        avals = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype, sharding=one), entry.input_avals)
+        hlo = entry.jit_obj.lower(*avals).compile().as_text()
+    assert _relayouts(hlo, f"bf16[{H * hd},{D}]") == []        # wq
+    assert _relayouts(hlo, f"bf16[{KV * hd},{D}]") == []       # wk, wv

@@ -254,6 +254,14 @@ class Cohere2MoeDescription(ModelDescription):
         k = _heads(x, layer["wk"], cfg.kv_heads, cfg.head_dim)
         v = _heads(x, layer["wv"], cfg.kv_heads, cfg.head_dim)
         if kind.window is not None:
+            # the pairs' stride-2 split is made on the projections. Without
+            # the barrier the TPU's layout assignment moves it into the
+            # dot's weight, and every step copies wq (134 MB at the published
+            # widths, 0.52 ms on a v5e) and wk into row pairs; the ops, and
+            # so the values, are the same either way. A prefill chunk's rows
+            # keep the split unaided. tests/test_mosaic_aot.py checks the
+            # compiled step.
+            q, k = prims.opt_barrier(q, k)
             q = _rope_interleaved(q, *rope)
             k = _rope_interleaved(k, *rope)
         kp = _write_rows(kv["k"], k, write_pos)
