@@ -95,6 +95,12 @@ _COMPOSITE_GRAD_EXEMPT_REASONED = {
     "nn.moe_experts": "inference-only: the serving expert layer told which "
                       "experts it holds (no backward, no load-balancing term; "
                       "ROADMAP R2 lists training through it as left)",
+    "nn.kda_chunk": "inference-only: a serving prefill chunk's delta rule from "
+                    "a slot's recurrent state (models/solar_open2.py); no "
+                    "VJP for the chunked scan yet (ROADMAP R5 (b))",
+    "nn.kda_decode": "inference-only: one decode token of the delta rule for "
+                     "every slot, the state updated in place "
+                     "(models/solar_open2.py)",
     "nn.sdpa_bwd": "backward half; differentiating it is second-order autodiff",
     "ops.fmod": "prim classified non-differentiable (matches reference: grads stop)",
     "ops.remainder": "prim classified non-differentiable (matches reference)",

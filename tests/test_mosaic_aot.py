@@ -364,3 +364,75 @@ def test_routed_decode_reads_the_rotary_weights_as_held(v5e):
         hlo = entry.jit_obj.lower(*avals).compile().as_text()
     assert _relayouts(hlo, f"bf16[{H * hd},{D}]") == []        # wq
     assert _relayouts(hlo, f"bf16[{KV * hd},{D}]") == []       # wk, wv
+
+
+@pytest.mark.parametrize("T", [512, 16])
+def test_kda_chunk_compiles_at_the_cell_widths(v5e, T):
+    """The delta rule's prefill chunk, 64 heads of 128, a full 512-token
+    chunk (eight inner chunks of 64) and the ladder's shortest rung."""
+    H, dk = 64, 128
+    x = sds((H, T, dk), f32)
+    _compile(v5e, lambda *a: px.pallas_kda_chunk(*a, chunk=64), x, x, x, x,
+             sds((H, T), f32), sds((H, dk, dk), f32), sds((), i32))
+
+
+def test_solar_decode_updates_the_state_pool_in_place(v5e):
+    """The decode program of ``solaropen2_serve_reason_sat``'s KDA widths (64
+    heads of 128, the state float32; 32 slots; a GQA layer and a KDA layer,
+    experts, vocabulary and context cut) runs ``pallas_kda_decode`` on the
+    donated state pool and holds no whole copy of it: the kernel's output is
+    aliased to its input, and a copy of the pool (134 MB here, 537 MB a
+    layer at the cell's 128 slots) would be a step's whole state traffic
+    again."""
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.models.solar_open2 import SolarOpen2Config
+    from thunder_tpu.serving.description import describe
+    from thunder_tpu.serving.kv_cache import PageGeometry, StateGeometry
+    from thunder_tpu.serving.runner import PagedRunner
+
+    S, D, H, KV, hd, Hk, ps, ctx = 32, 1024, 16, 2, 128, 64, 16, 256
+    cfg = SolarOpen2Config(vocab_size=1024, dim=D, n_layers=2,
+                           layer_types=("gqa", "kda"), n_heads=H,
+                           n_kv_heads=KV, head_dim=hd, kda_heads=Hk,
+                           kda_head_dim=128, kda_rank=128, expert_dim=256,
+                           n_experts=8, top_k=2, n_held=2, n_shared=1,
+                           max_seq_len=ctx, dtype=dtypes.bfloat16)
+    desc = describe(cfg)
+    w = desc.cache_kinds[0].pages_per_request(ctx, ps)
+    geoms = (PageGeometry(n_layers=1, kv_heads=KV, head_dim=hd, page_size=ps,
+                          num_pages=S * w + 1, pages_per_request=w),
+             StateGeometry.of(1, S, desc.state_shapes()))
+    c, n = Hk * 128, cfg.n_held + cfg.n_shared
+    moe = {"attn_norm": sds((D,)), "ffn_norm": sds((D,)),
+           "router": sds((8, D)), "router_bias": sds((8,), f32),
+           "w_gate": sds((n, 256, D)), "w_up": sds((n, 256, D)),
+           "w_down": sds((n, D, 256))}
+    gqa = dict(moe, wq=sds((H * hd, D)), wk=sds((KV * hd, D)),
+               wv=sds((KV * hd, D)), wo=sds((D, H * hd)), wg=sds((H * hd, D)))
+    kda = dict(moe, wq=sds((c, D)), wk=sds((c, D)), wv=sds((c, D)),
+               wo=sds((D, c)), conv=sds((3 * c, 4)), wb=sds((Hk, D)),
+               wf_a=sds((128, D)), wf_b=sds((c, 128)), wg_a=sds((128, D)),
+               wg_b=sds((c, 128)), a_log=sds((Hk,)), dt_bias=sds((c,)),
+               o_norm=sds((128,)))
+    params = {"tok_embedding": sds((1024, D)), "lm_head": sds((1024, D)),
+              "norm_f": sds((D,)), "layers": [gqa, kda]}
+    pools = [{name: sds((KV, S * w + 1, ps, hd)) for name in "kv"},
+             {"s": sds((S, Hk, 128, 128), f32), "conv": sds((S, 4, 3 * c))}]
+    zeros = lambda *shape, dt=np.int32: np.zeros(shape, dt)
+    args = (params, zeros(S, 1), (zeros(S, w), zeros(S, 1)),
+            np.ones((S,), np.int32), (zeros(S), zeros(S)), pools,
+            zeros(S, dt=np.float32), zeros(S), np.ones((S,), np.float32),
+            zeros(S, 2, dt=np.uint32))
+    one = SingleDeviceSharding(v5e[0])
+    with jax.default_matmul_precision("default"):
+        entry = PagedRunner(desc, geoms).decode_jit.compile(*args)
+        avals = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype, sharding=one), entry.input_avals)
+        hlo = entry.jit_obj.lower(*avals).compile().as_text()
+    entry_hlo = hlo[hlo.index("\nENTRY"):]
+    pool = f"f32[{S},{Hk},128,128]"
+    assert re.search(r"%pallas_kda_decode\S* = \(.*" + re.escape(pool),
+                     entry_hlo)
+    copies = [line for line in entry_hlo.splitlines()
+              if pool in line and re.search(r" (copy|copy-start)\(", line)]
+    assert copies == []

@@ -1960,6 +1960,191 @@ def _moe_experts_checker(x, w_gate, w_up, w_down, expert_ids, expert_weights,
 
 
 # ---------------------------------------------------------------------------
+# the delta rule with per-channel decay (nn.kda_chunk / nn.kda_decode): a
+# float32 state (dk x dv) a head, the serving engine's state kind.
+#
+# Decode: grid (slot, head group); a step holds ``hg`` heads of one slot's
+# state in VMEM, reads it once and writes it once into the same buffer (the
+# state is aliased input -> output, and the pool the engine donates is
+# updated in place). The vectors arrive as rows (hg, dk); one transpose a
+# step makes the columns the decay and the rank-one update scale the
+# state's rows by, and every product is a VPU multiply with a sublane sum.
+#
+# Prefill: grid (head, inner chunk), the chunks in order with the state in
+# a VMEM scratch. A step builds the chunk's pair matrices a row t at a time
+# from exponents <= 0 (G_t - G_s, s <= t), solves (I + A) by forward
+# substitution in the same sweep, then the WY form: four MXU products carry
+# the state to the chunk's end.
+# ---------------------------------------------------------------------------
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(a, b, ca: int = 1, cb: int = 0):
+    """a . b contracting a's dim ``ca`` with b's dim ``cb``, float32."""
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _is_f32(t) -> bool:
+    return t.dtype.is_float and t.dtype.bytes == 4
+
+
+def _kda_heads_per_step(H: int) -> int:
+    return next(n for n in (8, 4, 2, 1) if H % n == 0)
+
+
+def _kda_decode_kernel(up_ref, q_ref, k_ref, kb_ref, a_ref, v_ref, s_ref,
+                       o_ref, so_ref, *, hg: int):
+    live = up_ref[pl.program_id(0)] != 0
+    qT, kT = q_ref[0].T, k_ref[0].T                       # (dk, hg)
+    kbT, aT = kb_ref[0].T, a_ref[0].T
+    v = v_ref[0]                                           # (hg, dv)
+    for j in range(hg):
+        S = s_ref[0, j]                                    # (dk, dv)
+        Sd = S * aT[:, j:j + 1]
+        kv = jnp.sum(Sd * kT[:, j:j + 1], axis=0, keepdims=True)
+        S1 = jnp.where(live, Sd + kbT[:, j:j + 1] * (v[j:j + 1] - kv), S)
+        so_ref[0, j] = S1
+        o_ref[0, j:j + 1, :] = jnp.sum(S1 * qT[:, j:j + 1], axis=0,
+                                       keepdims=True)
+
+
+def pallas_kda_decode(q, k, v, g, beta, state, update):
+    Sl, H, dk = q.shape
+    dv = v.shape[2]
+    hg = _kda_heads_per_step(H)
+    _observe.event("kernel_path", op="nn.kda_decode", rung=f"heads_{hg}",
+                   heads_per_step=hg, chunk=1,
+                   state_dtype=str(jnp.dtype(state.dtype)),
+                   T=Sl, hd=dk, staged_bytes=4 * hg * dk * dv * 4)
+    f32 = jnp.float32
+    q, k, v = (a.astype(f32) for a in (q, k, v))
+    kb = k * beta.astype(f32)[:, :, None]
+    a = jnp.exp(g.astype(f32))
+    vec = lambda d: pl.BlockSpec((1, hg, d), lambda b, h, up: (b, h, 0))
+    st = pl.BlockSpec((1, hg, dk, dv), lambda b, h, up: (b, h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(Sl, H // hg),
+        in_specs=[vec(dk), vec(dk), vec(dk), vec(dk), vec(dv), st],
+        out_specs=[vec(dv), st])
+    o, s1 = pl.pallas_call(
+        functools.partial(_kda_decode_kernel, hg=hg),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((Sl, H, dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # operand 6 (the scalar-prefetched mask counts): the state
+        input_output_aliases={6: 1},
+        interpret=_interpret(),
+        **_grid_params("parallel", "parallel", planned_vmem=True),
+    )(update.astype(jnp.int32), q, k, kb, a, v, state)
+    return o, s1
+
+
+def _kda_decode_checker(q, k, v, g, beta, state, update):
+    if not _enabled():
+        return False
+    if q.ndim != 3 or state.ndim != 4 or not _is_f32(state):
+        return False
+    if not update.dtype.is_int:
+        return False
+    if _interpret():
+        return True
+    Sl, H, dk = q.shape
+    return dk % 128 == 0 and v.shape[2] % 128 == 0
+
+
+def _kda_chunk_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref, o_ref,
+                      s_ref, st, *, C: int, nc: int):
+    c = pl.program_id(1)
+
+    @pl.when(c == 0)
+    def _init():
+        st[...] = s0_ref[0]
+
+    q, k, kb, g = q_ref[0], k_ref[0], kb_ref[0], g_ref[0]   # (C, dk)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    G = _mm((rows >= cols).astype(jnp.float32), g)         # cumsum in chunk
+    # row t of A (s < t) and of P (s <= t) as columns over s; row t of
+    # (I + A)^-1 from the rows above it
+    Tm = (rows == cols).astype(jnp.float32)
+    PT = jnp.zeros((C, C), jnp.float32)
+    for t in range(C):
+        ks = k * jnp.exp(jnp.minimum(G[t:t + 1] - G, 0.0))   # (C_s, dk)
+        a_t = jnp.where(col < t, jnp.sum(ks * kb[t:t + 1], axis=1,
+                                         keepdims=True), 0.0)
+        p_t = jnp.where(col <= t, jnp.sum(ks * q[t:t + 1], axis=1,
+                                          keepdims=True), 0.0)
+        if t:
+            row = Tm[t:t + 1] - jnp.sum(a_t * Tm, axis=0, keepdims=True)
+            Tm = jnp.where(rows == t, row, Tm)
+        PT = jnp.where(cols == t, p_t, PT)
+    eG = jnp.exp(G)
+    ones = jnp.ones((C, 1), jnp.float32)
+    last_col = _mm(g, ones, 0, 0)                          # (dk, 1): G_C
+    last = G[C - 1:C]                                      # (1, dk)
+    S = st[...]
+    U = _mm(Tm, vb_ref[0]) - _mm(_mm(Tm, kb * eG), S)
+    o_ref[0] = _mm(q * eG, S) + _mm(PT, U, 0, 0)
+    S = jnp.exp(last_col) * S + _mm(k * jnp.exp(last - G), U, 0, 0)
+    st[...] = S
+
+    @pl.when(c == nc - 1)
+    def _done():
+        s_ref[0] = S
+
+
+def pallas_kda_chunk(q, k, v, g, beta, state, n_valid, chunk=64):
+    H, T, dk = q.shape
+    dv = v.shape[2]
+    C = min(int(chunk), T)
+    Tp = -(-T // C) * C
+    nc = Tp // C
+    _observe.event("kernel_path", op="nn.kda_chunk", rung=f"wy_{C}",
+                   heads_per_step=1, chunk=C,
+                   state_dtype=str(jnp.dtype(state.dtype)),
+                   T=T, hd=dk, staged_bytes=4 * (4 * C * dk + C * dv
+                                                 + 2 * dk * dv))
+    f32 = jnp.float32
+    pad = lambda a: jnp.pad(a.astype(f32), ((0, 0), (0, Tp - T))
+                            + ((0, 0),) * (a.ndim - 2))
+    q, k, v, g, beta = map(pad, (q, k, v, g, beta))
+    valid = (jnp.arange(Tp) < n_valid)[None, :]
+    g = jnp.where(valid[..., None], g, 0.0)
+    beta = jnp.where(valid, beta, 0.0)[..., None]
+    kb, vb = k * beta, v * beta
+    blk = lambda d: pl.BlockSpec((1, C, d), lambda h, c: (h, c, 0))
+    st = pl.BlockSpec((1, dk, dv), lambda h, c: (h, 0, 0))
+    o, s1 = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, C=C, nc=nc),
+        grid=(H, nc),
+        in_specs=[blk(dk), blk(dk), blk(dk), blk(dv), blk(dk), st],
+        out_specs=[blk(dv), st],
+        out_shape=[jax.ShapeDtypeStruct((H, Tp, dv), f32),
+                   jax.ShapeDtypeStruct((H, dk, dv), f32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), f32)],
+        interpret=_interpret(),
+        **_grid_params("parallel", "arbitrary"),
+    )(q, k, kb, vb, g, state.astype(f32))
+    return o[:, :T], s1
+
+
+def _kda_chunk_checker(q, k, v, g, beta, state, n_valid, chunk=64):
+    if not _enabled():
+        return False
+    if q.ndim != 3 or state.ndim != 3 or not _is_f32(state):
+        return False
+    if _interpret():
+        return True
+    H, T, dk = q.shape
+    C = min(int(chunk), T)
+    return dk % 128 == 0 and v.shape[2] % 128 == 0 and C % 8 == 0
+
+
+# ---------------------------------------------------------------------------
 # whole-decode-layer megakernel (serving T==1): ONE launch per transformer
 # layer per decoded token, claimed from the nn.decode_layer composite the
 # block planner's chaining stage builds (nn.attn_subblock alone gets the
@@ -2793,6 +2978,21 @@ moe_experts_op = ex.register_operator(
     "moe_experts", meta=_moe_sym.meta, fn=pallas_moe_experts)
 ex.register_implementation("nn.moe_experts", moe_experts_op,
                            checker=_moe_experts_checker)
+
+# serving, a state kind: the delta rule's decode step (the state aliased in
+# place) and its prefill chunk. No `profitable` hook: the decomposition of
+# either moves the state more than once (and the chunk's builds every
+# pair's decay at once).
+_kda_decode_sym = get_op("nn.kda_decode")
+kda_decode_op = ex.register_operator(
+    "kda_decode", meta=_kda_decode_sym.meta, fn=pallas_kda_decode)
+ex.register_implementation("nn.kda_decode", kda_decode_op,
+                           checker=_kda_decode_checker)
+_kda_chunk_sym = get_op("nn.kda_chunk")
+kda_chunk_op = ex.register_operator(
+    "kda_chunk", meta=_kda_chunk_sym.meta, fn=pallas_kda_chunk)
+ex.register_implementation("nn.kda_chunk", kda_chunk_op,
+                           checker=_kda_chunk_checker)
 
 # inference-path SDPA (no lse output needed)
 def pallas_sdpa(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False, scale=None):

@@ -148,7 +148,9 @@ def route(x2, layer, cfg):
     ids (N, top_k + n_shared + 1) — a pick held elsewhere keeps an id outside
     ``[0, n_held)`` and adds nothing — and combine weights, the shared
     experts' columns at ``1 / n_shared`` each, and a last column of weight
-    0 that gives every held expert a row (see below). Also the routing's
+    0 that gives every held expert a row (see below). A layer with a
+    ``router_bias`` (n_experts,) picks the top_k of ``scores + bias`` and
+    weighs them by their scores alone. Also the routing's
     counts (int32 scalars): held experts hit, local picks, the largest load
     of one held expert, and the held experts that STREAM (hit, or given a
     row by the last column: what the kernel's bytes follow)."""
@@ -157,7 +159,15 @@ def route(x2, layer, cfg):
     scores = ops.sigmoid(ops.linear(ops.convert_element_type(x2, f32),
                                     ops.convert_element_type(layer["router"],
                                                              f32)))
-    vals, idx = ops.topk(scores, cfg.top_k, -1)
+    bias = layer.get("router_bias")
+    if bias is None:
+        vals, idx = ops.topk(scores, cfg.top_k, -1)
+    else:
+        # a correction bias (DeepSeek-V3's) chooses the picks and weighs
+        # none of them: the weights are the picks' own scores
+        _, idx = ops.topk(ops.add(scores, ops.convert_element_type(bias, f32)),
+                          cfg.top_k, -1)
+        vals = ops.take_along_axis(scores, idx, 1)
     weights = ops.true_divide(vals, ops.sum(vals, -1, keepdim=True))
     local = ops.sub(ops.convert_element_type(idx, dtypes.int32),
                     cfg.held_start)

@@ -170,6 +170,16 @@ def _request_timeline_lines() -> list[str]:
                    f"{sum(a['live_pages_full'] for a in kinds)} pages, window "
                    f"{sum(a['live_pages_window'] for a in kinds)} over "
                    f"{len(kinds)} steps; {recycled} window pages recycled")
+    # an engine with a state kind: the rows whose recurrent state a decode
+    # step read and wrote, and the prefill chunks that started a slot's state anew
+    states = [a["state_rows"] for a in walks if "state_rows" in a]
+    if states:
+        resets = sum(1 for r in recs
+                     if r["type"] == "span" and r["name"] == "prefill_chunk"
+                     and r["args"].get("state_in") == 0)
+        out.append(f"  cache kinds: state read for {sum(states)} rows over "
+                   f"{len(states)} steps; {resets} slot states started "
+                   f"from zero")
     routes = [r for r in recs
               if r["type"] == "event" and r.get("kind") == "moe_route"]
     if routes:

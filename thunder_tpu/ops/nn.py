@@ -1417,3 +1417,154 @@ def moe_experts(x, w_gate, w_up, w_down, expert_ids, expert_weights, *,
     out = ops.sum(ops.mul(yf, ops.unsqueeze(ops.transpose(combine, (1, 0)),
                                             2)), 0)
     return ops.convert_element_type(out, x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# gated delta rule with per-channel decay (Kimi Delta Attention): one head's
+# state S (dk x dv, float32) per sequence, updated a token at a time as
+#
+#     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+#     o_t = S_t^T q_t
+#
+# (arXiv:2510.26692; the gated delta rule of arXiv:2412.06464 with a decay a
+# key channel). ``g`` is the log decay (<= 0). The serving engine keeps S
+# per slot beside the page pools (``serving/description.py``, kind "state").
+# ---------------------------------------------------------------------------
+
+KDA_CHUNK = 64
+
+
+@opsymbol(id="nn.kda_chunk")
+def kda_chunk(q, k, v, g, beta, state, n_valid, *, chunk: int = KDA_CHUNK):
+    """A prefill chunk of the delta rule above, in the chunked (WY / UT)
+    form: ``T`` tokens of one sequence from ``state`` on.
+
+    - ``q`` / ``k``: ``(H, T, dk)``, ``v``: ``(H, T, dv)``; ``g``:
+      ``(H, T, dk)`` float32 log decay; ``beta``: ``(H, T)`` float32;
+    - ``state``: ``(H, dk, dv)`` float32, what the tokens before the chunk
+      left;
+    - ``n_valid``: int32 scalar; the positions from it on are padding and
+      leave the state as it is (their decay is 1 and their beta 0).
+
+    Returns ``(o (H, T, dv) float32, state (H, dk, dv) float32)``.
+
+    Inner chunks of ``chunk`` tokens, with ``G`` the decay summed inside
+    one: the chunk's own pairs ``A[t, s] = beta_t sum_c k_t k_s
+    exp(G_t - G_s)`` (s < t) are read from exponents <= 0 only, so a decay
+    near 0 underflows instead of overflowing; ``(I + A)^-1`` is taken by
+    forward substitution; then ``U = (I + A)^-1 diag(beta) (V - (k exp G)
+    S)``, ``O = (q exp G) S + P U`` and ``S' = exp(G_C) S + (k exp(G_C -
+    G))^T U`` carry the state from one inner chunk to the next. The Pallas
+    executor claims it as one kernel a (head, inner chunk) that keeps the
+    state in VMEM."""
+    _tensor_like(q, "kda_chunk")
+    check(q.ndim == 3 and tuple(k.shape) == tuple(q.shape)
+          and tuple(g.shape) == tuple(q.shape) and v.ndim == 3
+          and tuple(v.shape[:2]) == tuple(q.shape[:2])
+          and tuple(beta.shape) == tuple(q.shape[:2])
+          and tuple(state.shape) == (q.shape[0], q.shape[2], v.shape[2]),
+          lambda: f"kda_chunk: q/k/g (H, T, dk) {tuple(q.shape)}, v "
+                  f"{tuple(v.shape)}, beta {tuple(beta.shape)}, state "
+                  f"{tuple(state.shape)}")
+    f32 = dtypes.float32
+    H, T, dk = q.shape
+    dv = v.shape[2]
+    C = min(int(chunk), T)
+    Tp = -(-T // C) * C
+    cast = lambda a: ops.convert_element_type(a, f32)
+    q, k, v, g, beta = map(cast, (q, k, v, g, beta))
+    if Tp != T:
+        pad3 = ((0, 0, 0), (0, Tp - T, 0), (0, 0, 0))
+        q, k, v, g = (ops.pad(a, pad3) for a in (q, k, v, g))
+        beta = ops.pad(beta, ((0, 0, 0), (0, Tp - T, 0)))
+    valid = ops.lt(ops.arange(Tp), n_valid)                          # (Tp,)
+    zero = ops.full((), 0.0, dtype=f32)
+    g = ops.where(ops.expand_to(ops.reshape(valid, (1, Tp, 1)), g.shape),
+                  g, zero)
+    beta = ops.where(ops.expand_to(ops.unsqueeze(valid, 0), beta.shape),
+                     beta, zero)
+    n = Tp // C
+    blk = lambda a: ops.reshape(a, (H, n, C, a.shape[-1]))
+    q, k, v, g = map(blk, (q, k, v, g))
+    beta = ops.reshape(beta, (H, n, C, 1))
+    G = ops.cumsum(g, 2)                                          # (H,n,C,dk)
+    # every pair's decay from exponents <= 0 (the masked half clamps to 0)
+    dec = ops.exp(ops.minimum(
+        ops.sub(ops.unsqueeze(G, 3), ops.unsqueeze(G, 2)), 0.0))  # (H,n,C,C,dk)
+    pair = lambda a: ops.sum(ops.mul(ops.mul(ops.unsqueeze(a, 3),
+                                             ops.unsqueeze(k, 2)), dec), -1)
+    rows = ops.unsqueeze(ops.arange(C), 1)
+    cols = ops.unsqueeze(ops.arange(C), 0)
+    bcast = lambda m: ops.expand_to(ops.reshape(m, (1, 1, C, C)), (H, n, C, C))
+    A = ops.where(bcast(ops.gt(rows, cols)), ops.mul(beta, pair(k)), zero)
+    P = ops.where(bcast(ops.ge(rows, cols)), pair(q), zero)
+    # (I + A)^-1, A strictly lower: row t = e_t - A[t] (rows above final)
+    eye = ops.convert_element_type(ops.eq(rows, cols), f32)
+    Tm = ops.expand_to(ops.reshape(eye, (1, 1, C, C)), (H, n, C, C))
+    for t in range(1, C):
+        row = ops.sub(ops.narrow(Tm, 2, t, 1),
+                      ops.matmul(ops.narrow(A, 2, t, 1), Tm))
+        at_t = ops.expand_to(ops.reshape(ops.eq(rows, t), (1, 1, C, 1)),
+                             Tm.shape)
+        Tm = ops.where(at_t, ops.expand_to(row, Tm.shape), Tm)
+    eG = ops.exp(G)
+    last = ops.narrow(G, 2, C - 1, 1)                              # (H,n,1,dk)
+    W = ops.matmul(Tm, ops.mul(beta, ops.mul(k, eG)))
+    Uv = ops.matmul(Tm, ops.mul(beta, v))
+    qg = ops.mul(q, eG)
+    kd = ops.mul(k, ops.exp(ops.sub(last, G)))
+    S = cast(state)
+    outs = []
+    for c in range(n):
+        at = lambda a: ops.squeeze(ops.narrow(a, 1, c, 1), 1)
+        U = ops.sub(at(Uv), ops.matmul(at(W), S))
+        outs.append(ops.add(ops.matmul(at(qg), S), ops.matmul(at(P), U)))
+        S = ops.add(ops.mul(ops.exp(ops.transpose(at(last), (0, 2, 1))), S),
+                    ops.matmul(ops.transpose(at(kd), (0, 2, 1)), U))
+    o = ops.cat(outs, 1) if n > 1 else outs[0]
+    if Tp != T:
+        o = ops.narrow(o, 1, 0, T)
+    return o, S
+
+
+@opsymbol(id="nn.kda_decode")
+def kda_decode(q, k, v, g, beta, state, update):
+    """One token of the delta rule above for every slot of a decode batch.
+
+    - ``q`` / ``k``: ``(S, H, dk)``, ``v``: ``(S, H, dv)``; ``g``:
+      ``(S, H, dk)`` float32 log decay; ``beta``: ``(S, H)`` float32;
+    - ``state``: ``(S, H, dk, dv)`` float32, a row a slot;
+    - ``update``: ``(S,)`` int32. A row with 1 takes the token into its
+      state and reads ``o = S'^T q``; a row with 0 keeps its state and reads
+      ``o = S^T q`` (a slot's first decode row re-feeds the last prompt
+      token, which prefill already took in; an idle row's output is
+      discarded).
+
+    Returns ``(o (S, H, dv) float32, state)``. The Pallas executor claims it
+    as one kernel that reads and writes each row's state once, the output
+    aliased to the input (the pool is donated, so the step copies none of
+    it)."""
+    _tensor_like(q, "kda_decode")
+    check(q.ndim == 3 and tuple(k.shape) == tuple(q.shape)
+          and tuple(g.shape) == tuple(q.shape) and v.ndim == 3
+          and tuple(v.shape[:2]) == tuple(q.shape[:2])
+          and tuple(beta.shape) == tuple(q.shape[:2])
+          and tuple(state.shape) == (*q.shape, v.shape[2])
+          and tuple(update.shape) == (q.shape[0],),
+          lambda: f"kda_decode: q/k/g (S, H, dk) {tuple(q.shape)}, v "
+                  f"{tuple(v.shape)}, beta {tuple(beta.shape)}, state "
+                  f"{tuple(state.shape)}, update {tuple(update.shape)}")
+    f32 = dtypes.float32
+    cast = lambda a: ops.convert_element_type(a, f32)
+    q, k, v, g, beta = map(cast, (q, k, v, g, beta))
+    col = lambda a: ops.unsqueeze(a, 3)                           # (S,H,dk,1)
+    S0 = state
+    Sd = ops.mul(S0, col(ops.exp(g)))
+    kv = ops.sum(ops.mul(Sd, col(k)), 2)                          # (S,H,dv)
+    u = ops.mul(ops.unsqueeze(beta, 2), ops.sub(v, kv))
+    S1 = ops.add(Sd, ops.mul(col(k), ops.unsqueeze(u, 2)))
+    live = ops.expand_to(ops.reshape(ops.ne(update, 0),
+                                     (update.shape[0], 1, 1, 1)), S1.shape)
+    S1 = ops.where(live, S1, S0)
+    o = ops.sum(ops.mul(S1, col(q)), 2)
+    return o, S1

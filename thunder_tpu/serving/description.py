@@ -6,8 +6,10 @@ contract:
 
 - the KV cache kind of every layer (:class:`CacheKind`: ``full`` keeps the
   whole context, ``window(W)`` the last ``W`` positions in a ring of pages
-  the host recycles) — the allocator gives each distinct kind its own pool
-  and block table;
+  the host recycles, ``state`` a fixed-size recurrent state a slot and no
+  pages at all) — the allocator gives each distinct paged kind its own pool
+  and block table, and the state kind one row a slot
+  (:meth:`ModelDescription.state_shapes`);
 - the two traced step functions, ``decode`` (one token for every slot,
   sampling in-graph) and ``prefill`` (one chunk of one prompt, K/V writes
   only), over the parameter tree the model's ``init_params`` lays out;
@@ -21,8 +23,18 @@ own (``models/cohere2_moe.py``); any other is a Llama-family config and gets
 Step-function arguments that exist once a cache kind — ``block_tables``,
 ``write_pos`` / ``page_writes`` — arrive as tuples in ``cache_kinds`` order
 (the runner wraps a bare array, which is how a direct caller of a one-kind
-model may still pass them); ``geoms`` likewise. ``pools`` stays one
-``{"k", "v"}`` dict a LAYER, each shaped by its layer's kind.
+model may still pass them); ``geoms`` likewise. ``pools`` stays one dict a
+LAYER, each shaped by its layer's kind: ``{"k", "v"}`` pages, or a state
+kind's arrays with the slot first.
+
+A state kind rides the same tuples: its block table is one unused column;
+at decode its ``write_pos`` is ``(S,)`` int32, 1 where the row takes its
+token into the state (0 for an idle row, and for a slot's first row, which
+re-feeds the last prompt token that prefill already took in); at prefill
+its ``page_writes`` is ``[slot, tokens, carried]`` int32: the slot whose
+row the chunk reads and writes, the chunk's prompt tokens (the rest is
+padding and leaves the state as it is), and 0 on a request's first chunk,
+which starts from a zero state.
 """
 
 from __future__ import annotations
@@ -39,19 +51,27 @@ from thunder_tpu.serving.sampling import sample_tokens
 class CacheKind:
     """How one kind of layer keeps its K/V."""
 
-    name: str                       # "full" | "window"
+    name: str                       # "full" | "window" | "state"
     window: int | None = None       # positions kept, the newest included
+
+    @property
+    def paged(self) -> bool:
+        """False for the state kind: a row a slot, no pages."""
+        return self.name != "state"
 
     def pages_per_request(self, max_context: int, page_size: int) -> int:
         """Block-table width: the whole context, or the ring
         ``ceil(W / page) + 1`` (a window that starts mid-page reaches one
-        page more than it fills)."""
+        page more than it fills); a state kind's one unused column."""
+        if not self.paged:
+            return 1
         if self.window is None:
             return -(-max_context // page_size)
         return -(-self.window // page_size) + 1
 
 
 FULL = CacheKind("full")
+STATE = CacheKind("state")
 
 
 def write_rows(pool, rows, flat_positions):
@@ -110,6 +130,12 @@ class ModelDescription:
     @property
     def max_seq_len(self) -> int:
         return self.cfg.max_seq_len
+
+    def state_shapes(self) -> dict:
+        """A state kind's arrays for ONE slot of one layer: ``{name:
+        (shape, dtype)}``; the engine keeps them with a slot axis in
+        front."""
+        return {}
 
     def tp_mesh(self, mesh):
         """``mesh`` (None, an int tp degree or a ``TensorParallelMesh``)

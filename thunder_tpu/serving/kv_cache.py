@@ -31,6 +31,13 @@ preserved for future prefix hits) instead of the free list, and
 ``alloc`` reclaims cached pages through the registered ``evict_cb``
 before ever raising ``OutOfPages`` — cached prefixes can never starve
 live traffic.
+
+A layer of the ``state`` kind keeps no pages: :class:`SlotStateCache`
+holds its recurrent state, one row a decode slot (``StateGeometry``), at a
+size fixed by the slot count and not by the context. A slot's row is
+zeroed by the program when a request's first prefill chunk reads it, so a
+freed slot needs no host work, and a preempted request re-prefills from
+zero like any other.
 """
 
 from __future__ import annotations
@@ -61,7 +68,101 @@ class PageGeometry:
         return -(-n_tokens // self.page_size)
 
 
-class PagedKVCache:
+class DevicePools:
+    """The device arrays of one cache kind, ``pools``: one dict a layer,
+    donated into the compiled steps and stored back after each."""
+
+    pools: list
+
+    def update_pools(self, new_pools) -> None:
+        """Store the updated pools returned by a compiled step (the step
+        donates the old buffers, so the engine must never reuse them)."""
+        self.pools = list(new_pools)
+
+    def pools_alive(self) -> bool:
+        """False when any pool buffer was deleted — a dispatch that donated
+        the pools and then failed consumed them mid-execution, so replaying
+        against this cache is impossible (the supervisor must rebuild)."""
+        for kv in self.pools:
+            for arr in kv.values():
+                if getattr(arr, "is_deleted", lambda: False)():
+                    return False
+        return True
+
+    def consume_pools(self) -> None:
+        """Delete every pool buffer — what a real accelerator fault does to
+        donated inputs mid-execution (the write-side dual of
+        :meth:`pools_alive`). Only the ``serving:engine`` fault-injection
+        path calls this; recovery is a supervisor pool rebuild."""
+        for kv in self.pools:
+            for arr in kv.values():
+                try:
+                    arr.delete()
+                except Exception:
+                    pass
+
+
+@dataclass(frozen=True)
+class StateGeometry:
+    """A state kind's static geometry: the layers of the kind, the slots,
+    and one slot's arrays a layer (``{name: (shape, dtype)}`` as a sorted
+    tuple, so the geometry hashes)."""
+
+    n_layers: int
+    slots: int
+    arrays: tuple           # ((name, shape, dtype name), ...)
+    pages_per_request: int = 1      # the block table's one unused column
+    num_pages: int = 0
+
+    @classmethod
+    def of(cls, n_layers: int, slots: int, shapes: dict) -> "StateGeometry":
+        import numpy as np
+
+        return cls(n_layers, slots, tuple(
+            (name, tuple(shape), np.dtype(dt).name)
+            for name, (shape, dt) in sorted(shapes.items())))
+
+    @property
+    def bytes_per_slot(self) -> int:
+        """One slot's state over every layer of the kind."""
+        import math
+
+        import numpy as np
+
+        return self.n_layers * sum(math.prod(shape) * np.dtype(dt).itemsize
+                                   for _, shape, dt in self.arrays)
+
+
+class SlotStateCache(DevicePools):
+    """The device rows of a state kind: ``pools`` is a list (per layer) of
+    ``{name: (slots, *shape) array}``, zero at construction, donated into
+    the compiled steps and stored back like the page pools. No page is ever
+    allocated or freed here: the engine's page loops skip the kind."""
+
+    def __init__(self, geometry: StateGeometry):
+        import jax.numpy as jnp
+
+        self.geometry = g = geometry
+        self.pools = [{name: jnp.zeros((g.slots, *shape), dt)
+                       for name, shape, dt in g.arrays}
+                      for _ in range(g.n_layers)]
+
+    @property
+    def nbytes(self) -> int:
+        return self.geometry.slots * self.geometry.bytes_per_slot
+
+    def assert_quiescent(self, block_tables=None) -> None:
+        """A state kind leaks nothing (a row a slot, always); its block
+        table's unused column must still be all scratch."""
+        if block_tables is not None:
+            import numpy as np
+
+            if np.asarray(block_tables).any():
+                raise AssertionError(
+                    "a state kind's block table holds a page id")
+
+
+class PagedKVCache(DevicePools):
     """Device page pools + host free list + per-page refcounts.
 
     ``pools`` is a list (per layer) of ``{"k": array, "v": array}`` with
@@ -298,33 +399,6 @@ class PagedKVCache:
         self._cached.pop(page, None)
         self._free.append(page)
         self._free_set.add(page)
-
-    def update_pools(self, new_pools) -> None:
-        """Store the updated pools returned by a compiled step (the step
-        donates the old buffers, so the engine must never reuse them)."""
-        self.pools = list(new_pools)
-
-    def pools_alive(self) -> bool:
-        """False when any pool buffer was deleted — a dispatch that donated
-        the pools and then failed consumed them mid-execution, so replaying
-        against this cache is impossible (the supervisor must rebuild)."""
-        for kv in self.pools:
-            for arr in kv.values():
-                if getattr(arr, "is_deleted", lambda: False)():
-                    return False
-        return True
-
-    def consume_pools(self) -> None:
-        """Delete every pool buffer — what a real accelerator fault does to
-        donated inputs mid-execution (the write-side dual of
-        :meth:`pools_alive`). Only the ``serving:engine`` fault-injection
-        path calls this; recovery is a supervisor pool rebuild."""
-        for kv in self.pools:
-            for arr in kv.values():
-                try:
-                    arr.delete()
-                except Exception:
-                    pass
 
     def assert_quiescent(self, block_tables=None) -> None:
         """Leak audit for an idle pool, refcount-aware: every allocatable

@@ -73,6 +73,9 @@ different sequence lengths served by ONE compiled decode program.
         decode_build              the batch arrays
         decode_dispatch           ``live_pages`` of ``window_pages``: what
                                   the decode attention walks of the tables
+                                  (``state_rows``: the rows whose state it
+                                  reads and writes, every slot's, on a
+                                  model with a state kind)
           decode_enqueue          the call into the bound program until it
                                   returns (``step:serving_decode`` inside)
           decode_wait             the device wait and the token fetch
@@ -137,6 +140,18 @@ different sequence lengths served by ONE compiled decode program.
   columns. Admission, preemption and ``assert_quiescent`` count every
   kind; best-of forks and the prefix cache are refused
   (``InfeasibleRequest``) on a model with a window kind.
+- **State kind**: a layer whose kind is ``state`` (a linear-attention
+  layer's recurrent state) keeps one row a slot
+  (:class:`~thunder_tpu.serving.kv_cache.SlotStateCache`) and no pages.
+  A request's first prefill chunk starts its row from zero (``carried`` 0
+  in the chunk's state control, counted in ``kv.state_resets``; the span
+  ``prefill_chunk`` carries ``state_in``), each later chunk from what the
+  one before left, and decode from the last chunk's. Decode takes the
+  token into the rows that decode (``decode_dispatch`` carries
+  ``state_rows``); a slot's replay row reads its state and leaves it. A
+  freed slot needs no host work, and a preempted request re-prefills from
+  zero. A snapshot of a state at a page boundary does not exist, so the
+  prefix cache and best-of forks are refused on such a model too.
 """
 
 from __future__ import annotations
@@ -163,7 +178,9 @@ from thunder_tpu.serving.errors import (
     ShardingGeometryError,
 )
 from thunder_tpu.serving.description import describe
-from thunder_tpu.serving.kv_cache import OutOfPages, PagedKVCache, PageGeometry
+from thunder_tpu.serving.kv_cache import (OutOfPages, PagedKVCache,
+                                          PageGeometry, SlotStateCache,
+                                          StateGeometry)
 from thunder_tpu.serving.prefix_cache import PrefixCache
 from thunder_tpu.serving.runner import PagedRunner
 from thunder_tpu.serving.sampling import GREEDY, SamplingParams
@@ -348,6 +365,11 @@ class ServingEngine:
             else {self.kinds[0].name: num_pages}
         geoms = []
         for k, kind in enumerate(self.kinds):
+            if not kind.paged:
+                geoms.append(StateGeometry.of(desc.layer_kinds.count(k),
+                                              int(max_slots),
+                                              desc.state_shapes()))
+                continue
             width = kind.pages_per_request(max_context, page_size)
             n = sized.get(kind.name)
             geoms.append(PageGeometry(
@@ -358,14 +380,14 @@ class ServingEngine:
         self.geoms = tuple(geoms)
         self.geom = geometry = geoms[0]
         self._windowed = any(kind.window is not None for kind in self.kinds)
+        self._stateful = not all(kind.paged for kind in self.kinds)
         # the typed restart state: everything a supervisor rebuild needs to
         # recreate the pool EXACTLY — geometry + dtype + mesh — carried on
         # every EngineFault so recovery is sharding-identical
         self._restart_state = RestartState(
             geometry=geometry if len(geoms) == 1 else self.geoms,
             dtype=desc.dtype, mesh=self.mesh)
-        self.caches = [PagedKVCache(g, desc.dtype, sharding=self.mesh)
-                       for g in geoms]
+        self.caches = self._new_caches(desc.dtype, self.mesh)
         self.cache = self.caches[0]
         # an engine of several kinds publishes its page gauge a kind too
         self._kind_obs = [
@@ -375,11 +397,14 @@ class ServingEngine:
         # their full pages into a token trie; admission probes it. A window
         # ring recycles the very pages a later prompt would want to reuse:
         # refused, typed, like a best-of fork of one (``submit``)
-        if prefix_cache and self._windowed:
+        if prefix_cache and (self._windowed or self._stateful):
+            why = ("the ring recycles the pages of a prompt's head"
+                   if self._windowed else "a recurrent state has no "
+                   "snapshot at a page boundary to resume from")
             raise InfeasibleRequest(
-                f"model {cfg.name}: prefix reuse over a window ring is not "
-                f"supported (the ring recycles the pages of a prompt's head)",
-                engine_id=self.engine_id)
+                f"model {cfg.name}: prefix reuse over a "
+                f"{'window ring' if self._windowed else 'state kind'} is "
+                f"not supported ({why})", engine_id=self.engine_id)
         self.prefix = PrefixCache(self.cache) if prefix_cache else None
         self.runner = PagedRunner(
             desc, self.geoms, executors=executors,
@@ -476,18 +501,23 @@ class ServingEngine:
         # a ladder size, which can transiently need more pages than the
         # final context — e.g. a 33-token prompt prefills as one 64 chunk)
         worst = max(total, self._padded_prefill_len(total))
-        for g, cache in zip(self.geoms, self.caches):
+        for kind, g, cache in zip(self.kinds, self.geoms, self.caches):
+            if not kind.paged:
+                continue                    # a state row a slot: no pages
             need = min(g.pages_for(worst), g.pages_per_request)
             if need > cache.pages_total:
                 raise InfeasibleRequest(
                     f"request needs up to {need} KV pages; the pool only "
                     f"has {cache.pages_total} — enlarge num_pages",
                     engine_id=self.engine_id)
-        if best_of > 1 and self._windowed:
+        if best_of > 1 and (self._windowed or self._stateful):
+            why = ("the clones' rings would recycle shared pages"
+                   if self._windowed else "a recurrent state cannot be "
+                   "shared copy-on-write")
             raise InfeasibleRequest(
-                f"best_of={best_of}: a copy-on-write fork of a window ring "
-                f"is not supported (the clones' rings would recycle shared "
-                f"pages)", engine_id=self.engine_id)
+                f"best_of={best_of}: a copy-on-write fork of a "
+                f"{'window ring' if self._windowed else 'state kind'} is "
+                f"not supported ({why})", engine_id=self.engine_id)
         now = time.perf_counter()
 
         def new_request(sp: SamplingParams, parent=None) -> Request:
@@ -690,8 +720,7 @@ class ServingEngine:
         # (geometry alone would rebuild an unsharded pool and the next
         # dispatch would recompile or crash)
         rs = self._restart_state
-        self.caches = [PagedKVCache(g, rs.dtype, sharding=rs.mesh)
-                       for g in self.geoms]
+        self.caches = self._new_caches(rs.dtype, rs.mesh)
         self.cache = self.caches[0]
         if self.mesh is not None:
             from thunder_tpu.distributed.gspmd import mesh_descriptor
@@ -737,6 +766,16 @@ class ServingEngine:
     def idle(self) -> bool:
         return not self.queue and not any(s is not None for s in self.slots)
 
+    def request_state(self, req: Request) -> list:
+        """The recurrent state a resident request's slot holds: one dict of
+        host arrays a layer of a state kind, in layer order. Between steps
+        it has taken in the prompt and every generated token but the last,
+        which the next decode step feeds."""
+        slot = self.slots.index(req)
+        return [{name: np.asarray(a[slot]) for name, a in pool.items()}
+                for cache, kind in zip(self.caches, self.kinds)
+                if not kind.paged for pool in cache.pools]
+
     def describe_state(self) -> dict:
         """Plain-dict engine/cache state summary — what a postmortem bundle
         embeds: slot occupancy, queue, page accounting, block-table
@@ -766,8 +805,9 @@ class ServingEngine:
             "pools_alive": self._pools_alive(),
             "cache_kinds": [
                 {"kind": kind.name, "window": kind.window,
-                 "layers": g.n_layers, "pages_free": c.pages_free,
-                 "pages_total": c.pages_total}
+                 "layers": g.n_layers,
+                 **({"pages_free": c.pages_free, "pages_total": c.pages_total}
+                    if kind.paged else {"state_bytes": c.nbytes})}
                 for kind, g, c in zip(self.kinds, self.geoms, self.caches)],
             "cached_pages": self.cache.cached_pages,
             "cow_copies": self.cache.cow_copies,
@@ -822,8 +862,11 @@ class ServingEngine:
         self.obs.set_gauge("serving.queue_depth", len(self.queue))
         self.obs.set_gauge("serving.active_requests", self.active_requests)
         self.obs.set_gauge("serving.kv_pages_free", self.cache.pages_free)
-        for obs, cache in zip(self._kind_obs, self.caches):
-            obs.set_gauge("serving.kv_pages_free", cache.pages_free)
+        for obs, cache, kind in zip(self._kind_obs, self.caches, self.kinds):
+            if kind.paged:
+                obs.set_gauge("serving.kv_pages_free", cache.pages_free)
+            else:
+                obs.set_gauge("serving.state_bytes", cache.nbytes)
         if self.prefix is not None:
             self.obs.set_gauge("serving.cached_pages", self.cache.cached_pages)
         if self._slo_total:
@@ -896,13 +939,19 @@ class ServingEngine:
         """Return a resident request's pages and zero its block-table row
         (the quiescence invariant: idle rows reference only page 0)."""
         slot = self.slots.index(req)
-        for cache, pages in zip(self.caches, req.kind_pages):
-            cache.free(pages)
+        for kind, cache, pages in zip(self.kinds, self.caches, req.kind_pages):
+            if kind.paged:
+                cache.free(pages)
         req.drop_pages(len(self.kinds))
         self.slots[slot] = None
         for bt in self._np_bts:
             bt[slot] = 0
         self._bt_slot_version[slot] = None
+
+    def _new_caches(self, dtype, mesh) -> list:
+        return [PagedKVCache(g, dtype, sharding=mesh) if kind.paged
+                else SlotStateCache(g)
+                for kind, g in zip(self.kinds, self.geoms)]
 
     def _admit(self) -> bool:
         admitted = False
@@ -933,7 +982,8 @@ class ServingEngine:
             # could cover the first chunk
             first = self._chunk_pages(0, min(len(wp), first_chunk),
                                       first_chunk)
-            if any(not self.caches[k].can_alloc(len(first[k]))
+            if any(self.kinds[k].paged
+                   and not self.caches[k].can_alloc(len(first[k]))
                    for k in range(1, len(self.kinds))):
                 break
             try:
@@ -993,7 +1043,9 @@ class ServingEngine:
         bt = np.zeros(width, np.int32) if out is None else out
         pages, base = req.kind_pages[k], req.page_base[k]
         bt[:] = 0
-        if self.kinds[k].window is None:
+        if not self.kinds[k].paged:
+            pass                            # the state kind's unused column
+        elif self.kinds[k].window is None:
             bt[:len(pages)] = pages
         elif pages:
             bt[(base + np.arange(len(pages))) % width] = pages
@@ -1015,7 +1067,9 @@ class ServingEngine:
         a, b = pos0 // ps, (pos0 + C) // ps
         out = []
         for k, kind in enumerate(self.kinds):
-            if kind.window is None:
+            if not kind.paged:
+                out.append(range(0))
+            elif kind.window is None:
                 out.append(range(a, b))
             else:
                 n = pos0 + real
@@ -1121,6 +1175,13 @@ class ServingEngine:
             first_page = pos0 // g.page_size
             block_tables, page_writes = [], []
             for k, keep in enumerate(self._chunk_pages(pos0, real, C)):
+                if not self.kinds[k].paged:
+                    # the state control: slot, prompt tokens, carried
+                    block_tables.append(np.zeros((1, 1), np.int32))
+                    page_writes.append(np.asarray(
+                        [self.slots.index(req), real, int(pos0 > 0)],
+                        np.int32))
+                    continue
                 pages = req.kind_pages[k]
                 ring = self.kinds[k].window is not None
                 need = keep.stop - len(pages)
@@ -1155,13 +1216,18 @@ class ServingEngine:
                 self.params, chunk, block_table, lengths, page_writes,
                 self._pools())
 
+        chunk_args = {"request": req.request_id, "chunk": C, "pos0": pos0,
+                      "step": self._step_count}
+        if self._stateful:
+            # 0: the chunk starts the slot's state from zero
+            chunk_args["state_in"] = int(pos0 > 0)
+            if not pos0:
+                self.obs.inc("kv.state_resets")
         # the chunk's dispatch on the request's own lifecycle track (the
         # device runs the chunk behind it: this iteration's ``decode_wait``
         # holds that time); per-chunk ``serving.prefill_ms`` is this span's
         # length
-        with self.obs.span("prefill_chunk", "serving:request",
-                           {"request": req.request_id, "chunk": C,
-                            "pos0": pos0, "step": self._step_count},
+        with self.obs.span("prefill_chunk", "serving:request", chunk_args,
                            histogram="serving.prefill_ms"):
             pools = self._dispatch_guarded(dispatch, "serving:prefill")
             self._store_pools(pools)
@@ -1248,6 +1314,8 @@ class ServingEngine:
             for k in range(len(self.kinds)):
                 if req.state != DECODE:
                     break                   # a grow below evicted it
+                if not self.kinds[k].paged:
+                    continue
                 if self.kinds[k].window is not None:
                     # the page that fell wholly out of the window goes back
                     # to the free list before the next one is taken
@@ -1315,8 +1383,9 @@ class ServingEngine:
                     lengths[i] = r.length + 1
                     page, off = divmod(r.length, g.page_size)
                     for k, wp in enumerate(wps):
+                        # a state kind's entry: 1, the row takes its token
                         wp[i] = r.kind_pages[k][page - r.page_base[k]] \
-                            * g.page_size + off
+                            * g.page_size + off if self.kinds[k].paged else 1
                 sp = r.sampling
                 temps[i] = sp.temperature
                 topk[i] = sp.top_k
@@ -1332,12 +1401,19 @@ class ServingEngine:
                 # its window still reaches
                 walk["live_pages_full"] = walk["live_pages_window"] = 0
                 for kind in self.kinds:
+                    if not kind.paged:
+                        continue
                     if kind.window is None:
                         walk["live_pages_full"] += int(top.sum())
                     else:
                         low = np.maximum(lengths - kind.window, 0) \
                             // g.page_size
                         walk["live_pages_window"] += int((top - low).sum())
+            if self._stateful:
+                # the rows whose state the step reads and writes: every
+                # slot's, idle ones too (the kernel walks the whole pool
+                # and writes an idle row back as it was)
+                walk["state_rows"] = self.max_slots
             bt_arg, wp_arg = self._per_kind(bts), self._per_kind(wps)
 
         def dispatch():
