@@ -376,6 +376,43 @@ def test_kda_chunk_compiles_at_the_cell_widths(v5e, T):
              sds((H, T), f32), sds((H, dk, dk), f32), sds((), i32))
 
 
+def test_kda_decode_compiles_at_the_cell_shape(v5e):
+    """``solaropen2_serve_reason_sat``'s decode kernel alone: 128 slots, 64
+    heads of 128, the float32 state donated. A grid step holds the head
+    group the rule picks, its staging within the VMEM the kernel is
+    compiled with, and the pool is updated in place: no copy of it."""
+    from thunder_tpu.core.cost_model import VMEM_LIMIT_BYTES
+    from thunder_tpu.observe import registry as obs
+
+    Sl, H, d = 128, 64, 128
+    one = SingleDeviceSharding(v5e[0])
+    vec = sds((Sl, H, d), f32, one)
+    pool = sds((Sl, H, d, d), f32, one)
+    obs.enable(clear=True)
+    try:
+        hlo = jax.jit(px.pallas_kda_decode, donate_argnums=(5,)).lower(
+            vec, vec, vec, vec, sds((Sl, H), f32, one), pool,
+            sds((Sl,), i32, one)).compile().as_text()
+        paths = [e for e in obs.get_registry().events
+                 if e["kind"] == "kernel_path"]
+    finally:
+        obs.disable()
+        obs.reset()
+    (e,) = paths
+    hg = px._kda_heads_per_step(H, d, d)
+    assert (e["op"], e["rung"], e["heads_per_step"]) == (
+        "nn.kda_decode", f"heads_{hg}", hg)
+    assert e["staged_bytes"] == px._kda_decode_staging(hg, d, d)
+    assert e["staged_bytes"] <= VMEM_LIMIT_BYTES
+    shape = f"f32[{Sl},{H},{d},{d}]"
+    entry_hlo = hlo[hlo.index("\nENTRY"):]
+    assert re.search(r"%pallas_kda_decode\S* = \(.*" + re.escape(shape),
+                     entry_hlo)
+    assert [line for line in entry_hlo.splitlines()
+            if shape in line and re.search(r" (copy|copy-start)\(", line)] \
+        == []
+
+
 def test_solar_decode_updates_the_state_pool_in_place(v5e):
     """The decode program of ``solaropen2_serve_reason_sat``'s KDA widths (64
     heads of 128, the state float32; 32 slots; a GQA layer and a KDA layer,

@@ -202,6 +202,40 @@ def test_decode_step_after_a_prefill_is_the_recurrence_next_step(kernels,
                                    rtol=SCAN_TOL)
 
 
+@pytest.mark.parametrize("H,hg", [(64, 8), (64, 16), (64, 32), (64, 64),
+                                  (24, None)])
+def test_decode_head_groups_are_bit_for_bit_the_groups_of_8(interpret,
+                                                            monkeypatch, H,
+                                                            hg):
+    """``pallas_kda_decode`` with ``hg`` heads a grid step (None: the
+    rule's own, 24 for 24 heads) gives the outputs and state of 8 heads a
+    step, bit for bit: each head's operations and their order do not
+    depend on the grouping. 4 slots of the cell's 128-wide heads, one row
+    that only reads."""
+    rng = np.random.default_rng(H)
+    Sl, dk = 4, 128
+    q, k, v, g = (rng.normal(size=(Sl, H, dk)).astype(np.float32)
+                  for _ in range(4))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = -np.exp(g)
+    beta = rng.uniform(0.01, 1.99, size=(Sl, H)).astype(np.float32)
+    S = rng.normal(size=(Sl, H, dk, dk)).astype(np.float32)
+    update = np.asarray([1, 1, 0, 1], np.int32)
+    rule = px._kda_heads_per_step
+
+    def run(n):
+        monkeypatch.setattr(px, "_kda_heads_per_step",
+                            lambda H, dk, dv: n or rule(H, dk, dv))
+        o, S1 = px.pallas_kda_decode(q, k, v, g, beta, S.copy(), update)
+        return np.asarray(o), np.asarray(S1)
+
+    if hg is None:
+        assert rule(H, dk, dk) == H
+    (o, S1), (o8, S8) = run(hg), run(8)
+    assert np.array_equal(o, o8) and np.array_equal(S1, S8)
+    assert np.array_equal(S1[2], S[2])
+
+
 # ---------------------------------------------------------------------------
 # prefill, then decode, through both cache kinds, against ref_logits
 # ---------------------------------------------------------------------------

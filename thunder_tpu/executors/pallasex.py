@@ -1964,11 +1964,13 @@ def _moe_experts_checker(x, w_gate, w_up, w_down, expert_ids, expert_weights,
 # float32 state (dk x dv) a head, the serving engine's state kind.
 #
 # Decode: grid (slot, head group); a step holds ``hg`` heads of one slot's
-# state in VMEM, reads it once and writes it once into the same buffer (the
-# state is aliased input -> output, and the pool the engine donates is
-# updated in place). The vectors arrive as rows (hg, dk); one transpose a
-# step makes the columns the decay and the rank-one update scale the
-# state's rows by, and every product is a VPU multiply with a sublane sum.
+# state in VMEM (as many as the VMEM holds: _kda_heads_per_step), reads it
+# once and writes it once into the same buffer (the state is aliased input
+# -> output, and the pool the engine donates is updated in place). A loop
+# walks the step's heads in groups of 8 (one sublane tile of the vectors);
+# the vectors arrive as rows (8, dk), one transpose a group makes the
+# columns the decay and the rank-one update scale the state's rows by, and
+# every product is a VPU multiply with a sublane sum.
 #
 # Prefill: grid (head, inner chunk), the chunks in order with the state in
 # a VMEM scratch. A step builds the chunk's pair matrices a row t at a time
@@ -1991,34 +1993,61 @@ def _is_f32(t) -> bool:
     return t.dtype.is_float and t.dtype.bytes == 4
 
 
-def _kda_heads_per_step(H: int) -> int:
-    return next(n for n in (8, 4, 2, 1) if H % n == 0)
+def _kda_decode_staging(hg: int, dk: int, dv: int) -> int:
+    """VMEM a decode grid step of ``hg`` heads stages: every block twice
+    (the pipeline's two buffers), the float32 state in and out, the four
+    dk-vectors and v in, o out."""
+    return 2 * 4 * hg * (2 * dk * dv + 4 * dk + 2 * dv)
+
+
+def _kda_heads_per_step(H: int, dk: int, dv: int) -> int:
+    """Heads of one slot a decode grid step holds: the most that divide
+    ``H`` and fill whole sublane groups (or are ``H``) whose staging fits
+    the VMEM the kernel is compiled with. A step's arithmetic and
+    bookkeeping hide under its copies only when the step is large: on a
+    v5e at 128 x 128 heads, 8 a step stream the state at 69% of HBM's
+    bandwidth, 32 or 64 at 80%, what copying it through reads."""
+    from thunder_tpu.core.cost_model import VMEM_LIMIT_BYTES
+
+    legal = [n for n in range(H, 0, -1)
+             if H % n == 0 and (n % 8 == 0 or n == H)]
+    return next((n for n in legal
+                 if _kda_decode_staging(n, dk, dv) <= VMEM_LIMIT_BYTES),
+                legal[-1])
 
 
 def _kda_decode_kernel(up_ref, q_ref, k_ref, kb_ref, a_ref, v_ref, s_ref,
                        o_ref, so_ref, *, hg: int):
     live = up_ref[pl.program_id(0)] != 0
-    qT, kT = q_ref[0].T, k_ref[0].T                       # (dk, hg)
-    kbT, aT = kb_ref[0].T, a_ref[0].T
-    v = v_ref[0]                                           # (hg, dv)
-    for j in range(hg):
-        S = s_ref[0, j]                                    # (dk, dv)
-        Sd = S * aT[:, j:j + 1]
-        kv = jnp.sum(Sd * kT[:, j:j + 1], axis=0, keepdims=True)
-        S1 = jnp.where(live, Sd + kbT[:, j:j + 1] * (v[j:j + 1] - kv), S)
-        so_ref[0, j] = S1
-        o_ref[0, j:j + 1, :] = jnp.sum(S1 * qT[:, j:j + 1], axis=0,
-                                       keepdims=True)
+    u = 8 if hg % 8 == 0 else hg                           # heads unrolled
+
+    def group(i, carry):
+        r = pl.ds(pl.multiple_of(i * u, u), u)
+        qT, kT = q_ref[0, r].T, k_ref[0, r].T              # (dk, u)
+        kbT, aT = kb_ref[0, r].T, a_ref[0, r].T
+        v = v_ref[0, r]                                    # (u, dv)
+        o = []
+        for j in range(u):
+            S = s_ref[0, i * u + j]                        # (dk, dv)
+            Sd = S * aT[:, j:j + 1]
+            kv = jnp.sum(Sd * kT[:, j:j + 1], axis=0, keepdims=True)
+            S1 = jnp.where(live, Sd + kbT[:, j:j + 1] * (v[j:j + 1] - kv), S)
+            so_ref[0, i * u + j] = S1
+            o.append(jnp.sum(S1 * qT[:, j:j + 1], axis=0, keepdims=True))
+        o_ref[0, r] = jnp.concatenate(o)
+        return carry
+
+    jax.lax.fori_loop(0, hg // u, group, 0)
 
 
 def pallas_kda_decode(q, k, v, g, beta, state, update):
     Sl, H, dk = q.shape
     dv = v.shape[2]
-    hg = _kda_heads_per_step(H)
+    hg = _kda_heads_per_step(H, dk, dv)
     _observe.event("kernel_path", op="nn.kda_decode", rung=f"heads_{hg}",
                    heads_per_step=hg, chunk=1,
                    state_dtype=str(jnp.dtype(state.dtype)),
-                   T=Sl, hd=dk, staged_bytes=4 * hg * dk * dv * 4)
+                   T=Sl, hd=dk, staged_bytes=_kda_decode_staging(hg, dk, dv))
     f32 = jnp.float32
     q, k, v = (a.astype(f32) for a in (q, k, v))
     kb = k * beta.astype(f32)[:, :, None]
@@ -2051,8 +2080,13 @@ def _kda_decode_checker(q, k, v, g, beta, state, update):
         return False
     if _interpret():
         return True
+    from thunder_tpu.core.cost_model import VMEM_LIMIT_BYTES
+
     Sl, H, dk = q.shape
-    return dk % 128 == 0 and v.shape[2] % 128 == 0
+    dv = v.shape[2]
+    hg = _kda_heads_per_step(H, dk, dv)
+    return (dk % 128 == 0 and dv % 128 == 0
+            and _kda_decode_staging(hg, dk, dv) <= VMEM_LIMIT_BYTES)
 
 
 def _kda_chunk_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref, o_ref,
